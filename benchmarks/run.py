@@ -100,6 +100,10 @@ def main() -> None:
     from repro.exp import ResultSet
     from . import common
     suite = common.suite(preset=preset, jobs=args.jobs, engine=args.engine)
+    try:
+        suite.plan.resolve()
+    except ValueError as e:  # e.g. --jobs > 1 on an accelerator
+        ap.error(str(e))
 
     mods = args.only.split(",") if args.only else MODULES
     print("name,us_per_call,derived")

@@ -41,8 +41,12 @@ def main():
                                  n_inputs=args.inputs)
     spec = exp.ExperimentSpec.grid(config=args.config, mix=args.mix,
                                    policy=pols, params=params)
-    rs = exp.run(spec, plan=exp.ExecPlan(engine=args.engine,
-                                         jobs=args.jobs))
+    plan = exp.ExecPlan(engine=args.engine, jobs=args.jobs)
+    try:
+        plan.resolve()
+    except ValueError as e:  # e.g. --jobs > 1 on an accelerator
+        ap.error(str(e))
+    rs = exp.run(spec, plan=plan)
     print("policy,ipc_speedup,dmr,core_bypass_rate,accel_bypass_rate,"
           "core_hit_rate,accel_hit_rate")
     base = None

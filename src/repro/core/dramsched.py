@@ -19,7 +19,7 @@ Everything is int64 until two final float64 divisions (exact — the
 numerators stay far below 2^53), so the *same* ``epoch_compute`` function
 body runs under numpy (host oracle) and jax.numpy (inside the fused epoch
 ``lax.scan``), giving bitwise host-vs-fused parity by construction.  The
-jnp twin must run under the scoped ``jax.experimental.enable_x64`` the
+jnp twin must run under the scoped ``jax.enable_x64()`` the
 fused engine already wraps every dispatch in (this module deliberately
 does NOT flip the global x64 flag — that would leak int64 promotion into
 the Pallas kernels).  The per-lane state is three fixed-shape arrays that

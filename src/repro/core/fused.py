@@ -19,13 +19,19 @@ Parity contract (tests/test_fused.py):
   composite (set, when) sort reproducing ``llc.build_rounds``'s
   per-set event order, and every round applies the very same shared
   ``llc.round_transition`` (on a depth-major prefix slice).
-* float timing metrics are bitwise-equal too in practice: the fluid
-  timing update (``sim._mg1_delay``, ``dram.queue_delay``,
-  ``cores.core_ipc``, ``apm.*``) is ported to jnp at float64
-  (``jax.experimental.enable_x64``) with the host's exact operation
-  order, including numpy's pairwise summation tree for the 8-core IPC
-  sum.  The public guarantee is rtol=1e-6 (the acceptance bar); bitwise
-  float equality is asserted opportunistically in tests.
+* float timing metrics are bitwise-equal too: the fluid timing update
+  (``sim._mg1_delay``, ``dram.queue_delay``, ``cores.core_ipc``,
+  ``apm.*``) runs on the host's own float64 — numpy, with the host's
+  exact operation order, including numpy's pairwise summation tree for
+  the 8-core IPC sum — through one ``jax.pure_callback`` per epoch half
+  (``_begin_host``/``_finish_host``), for the whole lane batch at once.
+  No device float64 is involved: a TPU has none (XLA emulates it with
+  f32 pairs and rounds even a plain copy), so every float64 leaf of the
+  consts, the carry and the step outputs holds IEEE bits as a trailing
+  pair of uint32 words (``f64b``), which only the host interprets.  The
+  device keeps the integer work: event build, round loop, LLC state,
+  scheduled-DRAM bank model.  The public guarantee is rtol=1e-6 (the
+  acceptance bar); tests assert bitwise float equality.
 
 Fallback contract: the per-epoch round matrix has a static round
 capacity (``max_rounds``).  A hot set overflowing it — or an
@@ -48,6 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import os
 import time
 from typing import List, NamedTuple, Optional, Tuple
@@ -55,7 +62,6 @@ from typing import List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from . import dram as dram_mod
 from . import dramsched
@@ -123,37 +129,39 @@ class FusedDims:
 
 
 class SharedConsts(NamedTuple):
-    """Device constants shared by every lane of the batch (traced)."""
+    """Device constants shared by every lane of the batch (traced).
+    ``f64b`` leaves hold float64 values as IEEE bits — a trailing pair of
+    uint32 words (``_bits``): only the host callbacks read them."""
     line: jnp.ndarray        # i32 [M] accel trace lines
     write: jnp.ndarray       # bool [M]
     layer: jnp.ndarray       # i32 [M]
     streams: jnp.ndarray     # i32 [C, WMAX] core address streams
-    nominal: jnp.ndarray     # f64 [C] apkc/1000*et (epoch demand at ipc0)
-    apkc1k: jnp.ndarray      # f64 [C] apkc/1000
-    ipc0: jnp.ndarray        # f64 [C]
-    inv_ipc0: jnp.ndarray    # f64 [C] 1/ipc0
-    et: jnp.ndarray          # f64 [] epoch_cycles
+    nominal: jnp.ndarray     # f64b [C] apkc/1000*et (epoch demand at ipc0)
+    apkc1k: jnp.ndarray      # f64b [C] apkc/1000
+    ipc0: jnp.ndarray        # f64b [C]
+    inv_ipc0: jnp.ndarray    # f64b [C] 1/ipc0
+    et: jnp.ndarray          # f64b [] epoch_cycles
     m_total: jnp.ndarray     # i64 []
     max_epochs: jnp.ndarray  # i64 []
-    deadline: jnp.ndarray    # f64 []
-    period: jnp.ndarray      # f64 []
-    ma_global: jnp.ndarray   # f64 []
-    llc_capacity: jnp.ndarray      # f64 []
+    deadline: jnp.ndarray    # f64b []
+    period: jnp.ndarray      # f64b []
+    ma_global: jnp.ndarray   # f64b []
+    llc_capacity: jnp.ndarray      # f64b []
     llc_capacity_int: jnp.ndarray  # i64 [] int(llc_capacity)
-    s_llc: jnp.ndarray       # f64 []
-    w_cap_s: jnp.ndarray     # f64 [] w_cap * s_llc
-    w_cap_s_prio: jnp.ndarray      # f64 [] w_cap * s_llc * prio_cap
-    prio_cap: jnp.ndarray    # f64 []
-    hit_lat: jnp.ndarray     # f64 [] llc_hit_lat
-    dram_lat: jnp.ndarray    # f64 []
-    dram_rate: jnp.ndarray   # f64 []
-    dram_cap: jnp.ndarray    # f64 [] rate * et
-    dram_cap01: jnp.ndarray  # f64 [] 0.1 * dram_cap
-    dram_denom: jnp.ndarray  # f64 [] max(rate * et, 1e-9)
-    w_cap_dram: jnp.ndarray        # f64 [] w_cap * dram_lat
-    w_cap_dram_prio: jnp.ndarray   # f64 [] (w_cap * dram_lat) * prio_cap
-    w_dram25: jnp.ndarray    # f64 [] 25 * dram_lat
-    mlp_et: jnp.ndarray      # f64 [] mlp_accel * et
+    s_llc: jnp.ndarray       # f64b []
+    w_cap_s: jnp.ndarray     # f64b [] w_cap * s_llc
+    w_cap_s_prio: jnp.ndarray      # f64b [] w_cap * s_llc * prio_cap
+    prio_cap: jnp.ndarray    # f64b []
+    hit_lat: jnp.ndarray     # f64b [] llc_hit_lat
+    dram_lat: jnp.ndarray    # f64b []
+    dram_rate: jnp.ndarray   # f64b []
+    dram_cap: jnp.ndarray    # f64b [] rate * et
+    dram_cap01: jnp.ndarray  # f64b [] 0.1 * dram_cap
+    dram_denom: jnp.ndarray  # f64b [] max(rate * et, 1e-9)
+    w_cap_dram: jnp.ndarray        # f64b [] w_cap * dram_lat
+    w_cap_dram_prio: jnp.ndarray   # f64b [] (w_cap * dram_lat) * prio_cap
+    w_dram25: jnp.ndarray    # f64b [] 25 * dram_lat
+    mlp_et: jnp.ndarray      # f64b [] mlp_accel * et
     # scheduled-DRAM data (i64 scalars; zeros when dims.sched is None —
     # the sched branch is static, so they are never read then)
     sd_tcas: jnp.ndarray     # i64 [] row-hit (CAS) cost
@@ -164,7 +172,6 @@ class SharedConsts(NamedTuple):
     sd_qcap: jnp.ndarray     # i64 [] per-bank backlog clamp (cycles)
     sd_kind: jnp.ndarray     # i64 [] 0 = frfcfs, 1 = squash
     sd_et: jnp.ndarray       # i64 [] epoch_cycles as an integer
-    zero: jnp.ndarray        # f64 [] runtime 0.0 — the FMA fence (_mulb)
 
 
 class LaneConsts(NamedTuple):
@@ -179,44 +186,44 @@ class LaneConsts(NamedTuple):
     knobs: llc_mod.LaneKnobs  # leaves [L, ...]
     rc: jnp.ndarray           # i8 [L, M] RC cluster per access
     ri: jnp.ndarray           # i8 [L, M]
-    cold: jnp.ndarray         # f64 [L, NL] per-layer cold-cluster center
+    cold_le2: jnp.ndarray     # bool [L, NL] per-layer cold center <= 2.0
     afr: jnp.ndarray          # bool [L, M] pre-drawn AFRp decisions
     writes: jnp.ndarray       # bool [L, C, WMAX] pre-drawn core write flags
     # APM per-lane constants (lane's APMParams x shared ma_global)
-    margin_high: jnp.ndarray  # f64 [L]
-    margin_low: jnp.ndarray   # f64 [L]
-    mr_th: jnp.ndarray        # f64 [L]
-    behind_th: jnp.ndarray    # f64 [L] (1+alpha)*ma_global
-    bands: jnp.ndarray        # f64 [L, 7] [ (1+b)mag, (1-b)mag .. (1-6b)mag ]
-    t_a: jnp.ndarray          # f64 [L, 4] base T_A1..T_A4
-    t_b: jnp.ndarray          # f64 [L]
-    delta_a: jnp.ndarray      # f64 [L]
-    delta_b: jnp.ndarray      # f64 [L]
+    margin_high: jnp.ndarray  # f64b [L]
+    margin_low: jnp.ndarray   # f64b [L]
+    mr_th: jnp.ndarray        # f64b [L]
+    behind_th: jnp.ndarray    # f64b [L] (1+alpha)*ma_global
+    bands: jnp.ndarray        # f64b [L, 7] [ (1+b)mag, (1-b)mag .. (1-6b)mag ]
+    t_a: jnp.ndarray          # f64b [L, 4] base T_A1..T_A4
+    t_b: jnp.ndarray          # f64b [L]
+    delta_a: jnp.ndarray      # f64b [L]
+    delta_b: jnp.ndarray      # f64b [L]
 
 
 class FusedCarry(NamedTuple):
     """Per-lane dynamic state carried across the epoch scan."""
     st: llc_mod.LLCState      # batched [L, ...]
     active: jnp.ndarray       # bool [L]
-    hr_core: jnp.ndarray      # f64 [L]
-    hr_accel: jnp.ndarray     # f64 [L]
-    amal: jnp.ndarray         # f64 [L]
-    ipc: jnp.ndarray          # f64 [L, C]
+    hr_core: jnp.ndarray      # f64b [L]
+    hr_accel: jnp.ndarray     # f64b [L]
+    amal: jnp.ndarray         # f64b [L]
+    ipc: jnp.ndarray          # f64b [L, C]
     stream_pos: jnp.ndarray   # i64 [L, C]
     pos: jnp.ndarray          # i64 [L]
     input_idx: jnp.ndarray    # i64 [L]
-    input_start: jnp.ndarray  # f64 [L]
-    now: jnp.ndarray          # f64 [L]
+    input_start: jnp.ndarray  # f64b [L]
+    now: jnp.ndarray          # f64b [L]
     ri_th: jnp.ndarray        # i64 [L]
     rc_th: jnp.ndarray        # i64 [L]
     special: jnp.ndarray      # bool [L]
-    cm_prev: jnp.ndarray      # f64 [L]
-    pf_prev: jnp.ndarray      # f64 [L]
+    cm_prev: jnp.ndarray      # f64b [L]
+    pf_prev: jnp.ndarray      # f64b [L]
     epoch: jnp.ndarray        # i64 [L]
-    completions: jnp.ndarray  # f64 [L, n_inputs]
+    completions: jnp.ndarray  # f64b [L, n_inputs]
     totals: jnp.ndarray       # i64 [L, 7] ch cm cb ah am ab n_acc
-    total_llc: jnp.ndarray    # f64 [L]
-    total_dram: jnp.ndarray   # f64 [L]
+    total_llc: jnp.ndarray    # f64b [L]
+    total_dram: jnp.ndarray   # f64b [L]
     overflow: jnp.ndarray     # bool [L] sticky round-capacity flag
     # scheduled-DRAM bank state ([L, 0] / zeros when dims.sched is None,
     # keeping the carry tree uniform for stacking and donation)
@@ -230,23 +237,23 @@ class StepOut(NamedTuple):
     active: jnp.ndarray       # bool — this step ran AND committed
     pos_before: jnp.ndarray   # i64  — accel window start (online-LERN)
     n_a: jnp.ndarray          # i64  — hist accel_rate
-    req: jnp.ndarray          # f64  — hist requirement
+    req: jnp.ndarray          # f64b  — hist requirement
     ri_th: jnp.ndarray        # i64
     rc_th: jnp.ndarray        # i64
-    core_ipc: jnp.ndarray     # f64
-    amal: jnp.ndarray         # f64
+    core_ipc: jnp.ndarray     # f64b
+    amal: jnp.ndarray         # f64b
     occ: jnp.ndarray          # int [2] core/accel occupancy (record_occ)
     alive: jnp.ndarray        # bool — lane still active after this step
     ovf: jnp.ndarray          # bool — sticky round-capacity flag after it
 
 
-def _np_sum_order(terms: List[jnp.ndarray]):
+def _np_sum_order(terms: List[np.ndarray]) -> np.ndarray:
     """Sum ``terms`` in numpy's pairwise-summation order for n <= 128 —
-    the host computes ``np.sum(ipc * shed)`` over the cores and the fused
-    engine must reproduce the same float64 result bitwise."""
+    the host computes ``np.sum(ipc * shed)`` over one lane's cores; this
+    sums the same terms, lane-batched, to the same float64 bits."""
     n = len(terms)
     if n < 8:
-        s = jnp.float64(0.0)
+        s = 0.0
         for t in terms:
             s = s + t
         return s
@@ -263,57 +270,91 @@ def _np_sum_order(terms: List[jnp.ndarray]):
     return res
 
 
-def _div(a, b, zero):
-    """IEEE float division pinned against XLA's algebraic simplifier.
-
-    XLA rewrites chained divisions ((a/b)/c -> a/(b*c), and
-    multiply-of-division shapes) even with fast-math off — each rewrite
-    moves the last ulp, which is enough to flip an ``int()`` truncation
-    at an admission boundary and break bitwise stat parity with the
-    host's numpy math.  Adding a *runtime* zero (an opaque jit
-    parameter, so nothing can fold it) makes every consumer see an fadd
-    instead of an fdiv — no rewrite pattern matches, the op sequence
-    stays exactly as written, and unlike an optimization barrier it
-    costs one fused add, not a fusion break.  Only used for
-    non-negative quotients (-0.0 + 0.0 would flip the zero's sign)."""
-    return a / b + zero
+def _mg1(rho, s_llc):
+    rho = np.minimum(rho, 0.98)
+    return rho * s_llc / np.maximum(2.0 * (1.0 - rho), 1e-2)
 
 
-def _mulb(a, b, zero):
-    """Product pinned against FMA contraction.
-
-    In ``x ± a*b`` shapes LLVM fuses multiply and add into one fma —
-    one rounding step instead of two, not what the host's numpy
-    computes — and HLO optimization barriers don't survive to the LLVM
-    level.  The runtime zero makes the outer add's operand an fadd
-    rather than an fmul, which is not contractible.  Even if the inner
-    ``a*b + zero`` itself contracts, fma(a, b, 0) rounds exactly like
-    the plain product, so the value is unchanged.  (Only used for
-    non-negative products: -0.0 + 0.0 would flip the sign of zero.)"""
-    return a * b + zero
-
-
-def _mg1(rho, s_llc, zero):
-    rho = jnp.minimum(rho, 0.98)
-    return _div(rho * s_llc, jnp.maximum(2.0 * (1.0 - rho), 1e-2), zero)
-
-
-def _queue_delay(sh: SharedConsts, traffic):
+def _queue_delay(f, traffic):
     # constants single-sourced from dram.py (dram.queue_delay_consts
     # stages dram_denom / w_dram25; the floors are the named module
     # constants) so host and fused fluid models cannot drift
-    z = sh.zero
-    rho = jnp.minimum(_div(traffic, sh.dram_denom, z),
-                      dram_mod.QUEUE_RHO_CAP)
-    w = _div(_div(rho, jnp.maximum(2.0 * (1.0 - rho),
-                                   dram_mod.QUEUE_STAB_FLOOR), z),
-             sh.dram_rate, z)
-    return jnp.minimum(w, sh.w_dram25)
+    rho = np.minimum(traffic / f("dram_denom"), dram_mod.QUEUE_RHO_CAP)
+    w = (rho / np.maximum(2.0 * (1.0 - rho), dram_mod.QUEUE_STAB_FLOOR)
+         / f("dram_rate"))
+    return np.minimum(w, f("w_dram25"))
 
 
-# ---------------------------------------------------------------------------
-# device round building (the on-device build_rounds)
-# ---------------------------------------------------------------------------
+def _bits(x) -> np.ndarray:
+    """float64 values -> their IEEE bits as uint32 pairs [..., 2] (low
+    word first).  As 32-bit words they cross neither the device's float64
+    (a TPU's is an f32-pair emulation that rounds) nor a host callback's
+    argument conversion (which narrows 64-bit types in threads where
+    64-bit mode is off)."""
+    return np.ascontiguousarray(
+        np.asarray(x, np.float64)[..., None]).view(np.uint32)
+
+
+def _floats(a: dict):
+    """Accessor for the float64 values of a callback argument's bits."""
+    return lambda k: np.ascontiguousarray(a[k]).view(np.float64)[..., 0]
+
+
+def _words(x) -> jnp.ndarray:
+    """Device non-negative int64 -> uint32 pairs [..., 2] (low word
+    first), the host view of ``_bits``' layout as int64."""
+    u = x.astype(jnp.uint64)
+    return jnp.stack([(u & np.uint64(0xFFFFFFFF)).astype(jnp.uint32),
+                      (u >> np.uint64(32)).astype(jnp.uint32)], axis=-1)
+
+
+def _host_call(fn, out: dict, args: dict) -> dict:
+    """Run ``fn`` — float64 math in numpy — on the host from inside the
+    traced epoch step.  Under the lane vmap the callback sees the whole
+    lane batch at once (leading axis; size 1 for unbatched inputs) and
+    returns every ``out`` leaf (bool, int32 or uint32) with the lane axis.
+
+    The results come back packed as one uint32 word row per lane: one
+    host transfer per call instead of one per leaf, and the only form a
+    callback inside ``shard_map`` lowers to on a TPU (jax 0.9 annotates
+    every result's receive with the first result's rank)."""
+    sizes = [math.prod(sd.shape) for sd in out.values()]
+
+    def call(a):
+        # the callback receives jax arrays: numpy from here on, so every
+        # operation is the host's float64 (not jnp under 32-bit mode)
+        a = {k: np.asarray(v) for k, v in a.items()}
+        with np.errstate(all="ignore"):  # unselected branches may divide by 0
+            res = fn(a)
+        n = a["lane"].shape[0]
+        words = []
+        for (k, sd), size in zip(out.items(), sizes):
+            v = np.broadcast_to(np.asarray(res[k], sd.dtype), (n,) + sd.shape)
+            v = v.astype(np.uint32) if sd.dtype == bool else v.view(np.uint32)
+            words.append(v.reshape(n, size))
+        return np.concatenate(words, axis=1)
+
+    words = jax.pure_callback(
+        call, jax.ShapeDtypeStruct((sum(sizes),), jnp.uint32), args,
+        vmap_method="expand_dims")
+    res, off = {}, 0
+    for (k, sd), size in zip(out.items(), sizes):
+        w = words[off:off + size].reshape(sd.shape)
+        res[k] = (w != 0 if sd.dtype == bool
+                  else jax.lax.bitcast_convert_type(w, sd.dtype))
+        off += size
+    return res
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _f64(shape=()):
+    """Result spec of float64 bits (``_bits`` layout)."""
+    return jax.ShapeDtypeStruct(tuple(shape) + (2,), jnp.uint32)
+
+
 def _pack_meta(is_accel, write, hint, prefetch, dlok, src):
     """jnp twin of llc.pack_meta (src may be a scalar segment id)."""
     return (llc_mod.M_VALID
@@ -367,11 +408,11 @@ def _build_rounds_device(dims: FusedDims, sh: SharedConsts, lc, n_a, n_c,
         write_a = sh.write[gid, idx_a]
         layer_now = sh.layer[gid, pos]
     # per-event bypass hint: LERN clusters x epoch thresholds, or AFRp
-    cold_now = jnp.take(lc.cold, layer_now)
+    cold_now = jnp.take(lc.cold_le2, layer_now)
     rc_a = jnp.take(lc.rc, idx_a)
     ri_a = jnp.take(lc.ri, idx_a)
     hint_lern = (ri_a > ri_th) | (rc_a < rc_th)
-    hint_lern = hint_lern | (special & (cold_now <= 2.0) & (rc_a == 0))
+    hint_lern = hint_lern | (special & cold_now & (rc_a == 0))
     hint_a = jnp.where(lc.accel_hint, hint_lern,
                        jnp.where(lc.accel_rand, jnp.take(lc.afr, idx_a),
                                  False))
@@ -577,13 +618,14 @@ class _Begin(NamedTuple):
     step_active: jnp.ndarray
     arrived: jnp.ndarray
     accel_prio: jnp.ndarray
+    urgent: jnp.ndarray       # SQUASH urgency (scheduled DRAM)
     n_a: jnp.ndarray
     n_c: jnp.ndarray
-    shed: jnp.ndarray
+    shed: jnp.ndarray         # f64b
     ri_th: jnp.ndarray
     rc_th: jnp.ndarray
     special: jnp.ndarray
-    req_out: jnp.ndarray
+    req_out: jnp.ndarray      # f64b
     line_m: jnp.ndarray       # [R, S] permuted (depth-major) columns
     meta_m: jnp.ndarray       # [R, S] permuted columns
     counts: jnp.ndarray       # [R] active sets per round
@@ -594,110 +636,142 @@ class _Begin(NamedTuple):
     samp: jnp.ndarray         # i64 [NS] sched-DRAM window samples ([0]=off)
 
 
-# ---------------------------------------------------------------------------
-# one fused epoch: vmapped begin half -> batch round loop -> vmapped finish
-# ---------------------------------------------------------------------------
+def _begin_host(accel_cap: int, a: dict) -> dict:
+    """Lane.begin_epoch's float64 decisions for a lane batch, in numpy with
+    the host's exact operation order: arbitration mode, accelerator
+    admission, core demand shedding, and the HyDRA/APM thresholds."""
+    f = _floats(a)
+    now, start, et = f("now"), f("input_start"), f("et")
+    ma_global, amal = f("ma_global"), f("amal")
+    pos, m_total = a["pos"], a["m_total"]
+
+    # ---- arbitration mode ---------------------------------------------
+    arrived = now >= start
+    remaining = m_total - pos
+    done_rate = np.where(
+        arrived, pos / np.maximum((now - start) / et, 1.0), ma_global)
+    accel_prio = a["arp"] | (a["flash"] & (done_rate < ma_global))
+
+    # ---- accelerator admission ----------------------------------------
+    can_issue = arrived & (remaining > 0)
+    miss_rate_a = np.maximum(1.0 - f("hr_accel"), 0.05)
+    dram_cap = f("dram_cap")
+    dram_share = np.where(
+        accel_prio, dram_cap,
+        np.maximum(dram_cap - f("cm_prev") - f("pf_prev"), f("dram_cap01")))
+    ma_hat = f("mlp_et") / np.maximum(amal, 1.0)
+    demand_a = np.minimum(
+        np.minimum(remaining, ma_hat.astype(np.int64)),
+        np.minimum((dram_share / miss_rate_a).astype(np.int64), accel_cap))
+    demand_a = np.where(can_issue, demand_a, 0)
+
+    # ---- core demand / LLC bandwidth shedding -------------------------
+    n_c_dem = (f("nominal") * f("ipc") / f("ipc0")).astype(np.int64)
+    core_sum = n_c_dem.sum(-1)
+    total_demand = demand_a + core_sum
+    llc_cap = f("llc_capacity")
+    over_cap = total_demand > llc_cap
+    n_a_p = np.minimum(demand_a, a["llc_capacity_int"])
+    shed_p = np.minimum((llc_cap - n_a_p) / np.maximum(core_sum, 1), 1.0)
+    f_f = llc_cap / total_demand
+    n_a_f = (demand_a * f_f).astype(np.int64)
+    n_a = np.where(over_cap, np.where(accel_prio, n_a_p, n_a_f), demand_a)
+    shed = np.where(over_cap, np.where(accel_prio, shed_p, f_f), 1.0)
+    n_c = (n_c_dem * shed[..., None]).astype(np.int64)
+
+    # ---- HyDRA / APM epoch decision -----------------------------------
+    hcond = a["hydra"] & can_issue
+    deadline = f("deadline")
+    rt = np.maximum((start + deadline) - now, et)
+    elapsed = np.maximum(deadline - rt, 0.0)
+    done = (m_total - remaining) * et
+    ma_past = np.where(elapsed >= et, done / elapsed, ma_global)
+    hc = (1.0 - f("hr_core")) > f("mr_th")
+    behind = ma_past < f("behind_th")
+    marg = np.where(hc & behind, f("margin_high"),
+                    np.where(hc | behind, f("margin_low"), 0.0))
+    eff_rt = np.maximum(rt - marg * deadline, et)
+    ma_i = remaining / eff_rt * et
+    # Algorithm 1 threshold scaling: band index d in {6, 5..1, 0}
+    bands = f("bands")
+    in_band = [(ma_i > bands[..., k + 1]) & (ma_i <= bands[..., k])
+               for k in range(1, 6)]
+    d = np.where(ma_i <= bands[..., 6], 6,
+                 sum(np.where(b, k, 0) for k, b in zip(range(1, 6), in_band)))
+    d_f = d.astype(np.float64)
+    plus = (d == 0) & (ma_i > bands[..., 0])
+    t_a0, delta_a = f("t_a"), f("delta_a")
+    t_a = np.where((d > 0)[..., None],
+                   np.maximum(t_a0 - (d_f * delta_a)[..., None], 1.0),
+                   np.where(plus[..., None], t_a0 + delta_a[..., None],
+                            t_a0))                                  # [4]
+    t_b = np.where(d > 0, f("t_b") - d_f * f("delta_b"), f("t_b"))
+    # Fig. 9 reuse-threshold selection
+    c4 = ma_hat > t_a[..., 3] * ma_i
+    c3 = ma_hat > t_a[..., 2] * ma_i
+    c2 = ma_hat > t_a[..., 1] * ma_i
+    c1 = ma_hat > t_a[..., 0] * ma_i
+    cb = ma_hat > t_b * ma_i
+    ri_sel = np.where(c4, -1, np.where(c3, 0, np.where(
+        c2, 1, np.where(c1, 2, 3))))
+    rc_sel = np.where(c4, 4, np.where(c3, 3, np.where(
+        c2, 2, np.where(c1, 1, np.where(cb, 0, -1)))))
+    sp_sel = (~c4) & (~c3) & (~c2) & (~c1) & cb
+    req_out = np.where(hcond, ma_i, np.where(arrived, ma_global, 0.0))
+    return {"arrived": arrived, "accel_prio": accel_prio,
+            # SQUASH urgency: explicit accel priority, or a hydra lane
+            # whose achievable rate falls short of this epoch's requirement
+            "urgent": accel_prio | (a["hydra"] & (ma_hat < req_out)),
+            "n_a": n_a, "n_c": n_c, "shed": _bits(shed),
+            "ri_th": np.where(hcond, ri_sel, a["ri_th"]),
+            "rc_th": np.where(hcond, rc_sel, a["rc_th"]),
+            "special": np.where(hcond, sp_sel, a["special"]),
+            "req_out": _bits(req_out)}
+
+
+_SH_BEGIN = ("et", "m_total", "ma_global", "dram_cap", "dram_cap01",
+             "mlp_et", "nominal", "ipc0", "llc_capacity", "llc_capacity_int",
+             "deadline")
+_LC_BEGIN = ("arp", "flash", "hydra", "mr_th", "behind_th", "margin_high",
+             "margin_low", "bands", "t_a", "t_b", "delta_a", "delta_b")
+_CY_BEGIN = ("now", "input_start", "pos", "hr_accel", "hr_core", "cm_prev",
+             "pf_prev", "amal", "ipc", "ri_th", "rc_th", "special")
+
+
 def _begin_lane(dims: FusedDims, sh: SharedConsts, stop_epoch, lc, cy,
                 gid=None) -> _Begin:
     """Port of Lane.begin_epoch for one lane (the caller vmaps): epoch
     arbitration, admission, APM thresholds, and the on-device round
-    build.  Integer results match the host's int() truncations exactly;
-    float intermediates replicate the host operation order at float64.
+    build.  The float64 decisions run on the host (``_begin_host``), so
+    the integer results are the host's own int() truncations.
     ``gid`` routes the flat-bucket variant's (group, element) trace
     gathers; see _build_rounds_device.
     """
     # ~overflow: an overflowed lane freezes in place (its last epoch
     # never committed) until the driver escalates capacity or demotes it
     step_active = cy.active & (cy.epoch < stop_epoch) & ~cy.overflow
-    f64 = jnp.float64
 
-    # ---- arbitration mode (begin_epoch) -------------------------------
-    arrived = cy.now >= cy.input_start
-    remaining = sh.m_total - cy.pos
-    req = sh.ma_global
-    done_rate = jnp.where(
-        arrived,
-        _div(cy.pos,
-             jnp.maximum(_div(cy.now - cy.input_start, sh.et, sh.zero),
-                         1.0), sh.zero), req)
-    flash_prio = lc.flash & (done_rate < req)
-    accel_prio = lc.arp | flash_prio
-
-    # ---- accelerator admission ----------------------------------------
-    can_issue = arrived & (remaining > 0)
-    miss_rate_a = jnp.maximum(1.0 - cy.hr_accel, 0.05)
-    dram_share = jnp.where(
-        accel_prio, sh.dram_cap,
-        jnp.maximum(sh.dram_cap - cy.cm_prev - cy.pf_prev, sh.dram_cap01))
-    demand_a = jnp.minimum(
-        jnp.minimum(remaining,
-                    _div(sh.mlp_et, jnp.maximum(cy.amal, 1.0), sh.zero)
-                    .astype(jnp.int64)),
-        jnp.minimum(_div(dram_share, miss_rate_a, sh.zero).astype(jnp.int64),
-                    jnp.int64(dims.accel_cap)))
-    demand_a = jnp.where(can_issue, demand_a, jnp.int64(0))
-
-    # ---- core demand / LLC bandwidth shedding -------------------------
-    n_c_dem = _div(sh.nominal * cy.ipc, sh.ipc0, sh.zero).astype(jnp.int64)  # [C]
-    core_sum = jnp.sum(n_c_dem)
-    total_demand = demand_a + core_sum
-    over_cap = total_demand > sh.llc_capacity
-    n_a_p = jnp.minimum(demand_a, sh.llc_capacity_int)
-    f_p = _div(sh.llc_capacity - n_a_p, jnp.maximum(core_sum, 1), sh.zero)
-    shed_p = jnp.minimum(f_p, 1.0)
-    f_f = _div(sh.llc_capacity, total_demand, sh.zero)
-    n_a_f = (demand_a * f_f).astype(jnp.int64)
-    n_a = jnp.where(over_cap,
-                    jnp.where(accel_prio, n_a_p, n_a_f), demand_a)
-    shed = jnp.where(over_cap,
-                     jnp.where(accel_prio, shed_p, f_f), f64(1.0))
-    n_c = (n_c_dem * shed).astype(jnp.int64)
-
-    # ---- HyDRA / APM epoch decision -----------------------------------
-    hcond = lc.hydra & can_issue
-    rt = jnp.maximum((cy.input_start + sh.deadline) - cy.now, sh.et)
-    elapsed = jnp.maximum(sh.deadline - rt, 0.0)
-    done = (sh.m_total - remaining) * sh.et
-    ma_past = jnp.where(elapsed >= sh.et, _div(done, elapsed, sh.zero),
-                        sh.ma_global)
-    mr_i = 1.0 - cy.hr_core
-    hc = mr_i > lc.mr_th
-    behind = ma_past < lc.behind_th
-    marg = jnp.where(hc & behind, lc.margin_high,
-                     jnp.where(hc | behind, lc.margin_low, f64(0.0)))
-    eff_rt = jnp.maximum(rt - _mulb(marg, sh.deadline, sh.zero), sh.et)
-    ma_i = _div(remaining, eff_rt, sh.zero) * sh.et
-    # Algorithm 1 threshold scaling: band index d in {6, 5..1, 0}
-    in_band = [(ma_i > lc.bands[k + 1]) & (ma_i <= lc.bands[k])
-               for k in range(1, 6)]
-    d = jnp.where(ma_i <= lc.bands[6], jnp.int64(6),
-                  sum(jnp.where(b, jnp.int64(k), jnp.int64(0))
-                      for k, b in zip(range(1, 6), in_band)))
-    d_f = d.astype(jnp.float64)
-    plus = (d == 0) & (ma_i > lc.bands[0])
-    t_a = jnp.where(d > 0,
-                    jnp.maximum(lc.t_a - _mulb(d_f, lc.delta_a, sh.zero),
-                                1.0),
-                    jnp.where(plus, lc.t_a + lc.delta_a, lc.t_a))   # [4]
-    t_b = jnp.where(d > 0, lc.t_b - _mulb(d_f, lc.delta_b, sh.zero), lc.t_b)
-    # Fig. 9 reuse-threshold selection
-    ma_hat = _div(sh.mlp_et, jnp.maximum(cy.amal, 1.0), sh.zero)
-    c4 = ma_hat > t_a[3] * ma_i
-    c3 = ma_hat > t_a[2] * ma_i
-    c2 = ma_hat > t_a[1] * ma_i
-    c1 = ma_hat > t_a[0] * ma_i
-    cb = ma_hat > t_b * ma_i
-    i64 = jnp.int64
-    ri_sel = jnp.where(c4, i64(-1), jnp.where(c3, i64(0), jnp.where(
-        c2, i64(1), jnp.where(c1, i64(2), i64(3)))))
-    rc_sel = jnp.where(c4, i64(4), jnp.where(c3, i64(3), jnp.where(
-        c2, i64(2), jnp.where(c1, i64(1), jnp.where(cb, i64(0), i64(-1))))))
-    sp_sel = (~c4) & (~c3) & (~c2) & (~c1) & cb
-    ri_th = jnp.where(hcond, ri_sel, cy.ri_th)
-    rc_th = jnp.where(hcond, rc_sel, cy.rc_th)
-    special = jnp.where(hcond, sp_sel, cy.special)
-    req_out = jnp.where(hcond, ma_i,
-                        jnp.where(arrived, sh.ma_global, f64(0.0)))
+    # every integer crossing the callback fits int32 (lane_supported
+    # bounds the trace; counts are per epoch)
+    i32 = jnp.int32
+    args = {k: getattr(sh, k) for k in _SH_BEGIN}
+    args.update({k: getattr(lc, k) for k in _LC_BEGIN})
+    args.update({k: getattr(cy, k) for k in _CY_BEGIN})
+    for k in ("m_total", "llc_capacity_int", "pos", "ri_th", "rc_th"):
+        args[k] = args[k].astype(i32)
+    fl = _host_call(
+        functools.partial(_begin_host, dims.accel_cap),
+        {"arrived": _sds((), bool), "accel_prio": _sds((), bool),
+         "urgent": _sds((), bool), "n_a": _sds((), i32),
+         "n_c": _sds((dims.n_cores,), i32), "shed": _f64(),
+         "ri_th": _sds((), i32), "rc_th": _sds((), i32),
+         "special": _sds((), bool), "req_out": _f64()},
+        dict(args, lane=args["pos"]))
+    n_a = fl["n_a"].astype(jnp.int64)
+    n_c = fl["n_c"].astype(jnp.int64)
+    ri_th = fl["ri_th"].astype(jnp.int64)
+    rc_th = fl["rc_th"].astype(jnp.int64)
+    special = fl["special"]
 
     # ---- build the epoch event list (static segment layout) -----------
     (line_m, meta_m, counts, perm, inv_perm, n_rounds,
@@ -720,131 +794,155 @@ def _begin_lane(dims: FusedDims, sh: SharedConsts, stop_epoch, lc, cy,
                 else sh.line[gid, s_idx]).astype(jnp.int64)
     else:
         samp = jnp.zeros(0, jnp.int64)
-    return _Begin(step_active=step_active, arrived=arrived,
-                  accel_prio=accel_prio, n_a=n_a, n_c=n_c, shed=shed,
+    return _Begin(step_active=step_active, arrived=fl["arrived"],
+                  accel_prio=fl["accel_prio"], urgent=fl["urgent"],
+                  n_a=n_a, n_c=n_c, shed=fl["shed"],
                   ri_th=ri_th, rc_th=rc_th, special=special,
-                  req_out=req_out, line_m=line_m, meta_m=meta_m,
+                  req_out=fl["req_out"], line_m=line_m, meta_m=meta_m,
                   counts=counts, perm=perm, inv_perm=inv_perm,
                   n_rounds=n_rounds, ovf=ovf, samp=samp)
+
+
+def _finish_host(n_cores: int, sched: bool, a: dict) -> dict:
+    """Lane.finish_epoch's float64 timing update for a lane batch, in
+    numpy with the host's exact operation order: hit rates, LLC and DRAM
+    queueing (fluid, or the scheduled bank model's exact num/den), core
+    IPC, AMAL, and the float accumulators."""
+    f = _floats(a)
+    st = a["stats"]
+    ch, cm, cb_ = st[..., 0], st[..., 1], st[..., 2]
+    ah, am, ab = st[..., 3], st[..., 4], st[..., 5]
+    awb, pf_fills = st[..., 6], st[..., 8]
+    accel_prio = a["accel_prio"]
+    hr_core = ch / np.maximum(ch + cm, 1)
+    hr_accel = ah / np.maximum(ah + am, 1)
+    llc_units = ((ch + cm + ah + am) - 0.7 * (cb_ + ab) - 0.3 * awb)
+    llc_cap = f("llc_capacity")
+    rho_llc = llc_units / llc_cap
+    rho_a_llc = (ah + am) / llc_cap
+    dram_traffic = cm + am + pf_fills
+    s_llc, w_cap_s, prio_cap = f("s_llc"), f("w_cap_s"), f("prio_cap")
+    # priority-arbitration branch (LLC-side waits stay fluid under the
+    # scheduled backend — only the DRAM waits come from the bank model)
+    w_llc_a_p = np.minimum(_mg1(rho_a_llc, s_llc), w_cap_s)
+    prio = np.minimum(1.0 / np.maximum(1.0 - rho_a_llc, 1e-3), prio_cap)
+    w_llc_c_p = np.minimum(_mg1(rho_llc, s_llc) * prio, f("w_cap_s_prio"))
+    # FIFO branch
+    w_fifo = np.minimum(_mg1(rho_llc, s_llc), w_cap_s)
+    w_llc_a = np.where(accel_prio, w_llc_a_p, w_fifo)
+    w_llc_c = np.where(accel_prio, w_llc_c_p, w_fifo)
+    if not sched:
+        w_dram_fifo = np.minimum(_queue_delay(f, dram_traffic),
+                                 f("w_cap_dram"))
+        rho_a_dram = np.minimum(am / f("dram_denom"), 1.0)
+        w_dram_a_p = np.minimum(_queue_delay(f, am), f("w_cap_dram"))
+        prio_d = np.minimum(1.0 / np.maximum(1.0 - rho_a_dram, 1e-3),
+                            prio_cap)
+        w_dram_c_p = np.minimum(w_dram_fifo * prio_d, f("w_cap_dram_prio"))
+        w_dram_a = np.where(accel_prio, w_dram_a_p, w_dram_fifo)
+        w_dram_c = np.where(accel_prio, w_dram_c_p, w_dram_fifo)
+    else:
+        # the bank model's waits are exact integer num/den pairs
+        nd = np.ascontiguousarray(a["sched_nd"]).view(np.int64)[..., 0]
+        nd = nd.astype(np.float64)
+        w_dram_a = np.minimum(nd[..., 0] / nd[..., 1], f("w_cap_dram"))
+        w_dram_c = np.minimum(nd[..., 2] / nd[..., 3], f("w_cap_dram_prio"))
+    hit_lat, dram_lat = f("hit_lat"), f("dram_lat")
+    miss_lat_c = hit_lat + w_llc_c + dram_lat + w_dram_c
+    miss_lat_a = hit_lat + w_llc_a + dram_lat + w_dram_a
+    pc = a["percore"][..., :n_cores, :]
+    hk = pc[..., 0] / np.maximum(pc[..., 0] + pc[..., 1], 1)
+    amat = (hk * (hit_lat + w_llc_c)[..., None]
+            + (1 - hk) * miss_lat_c[..., None])
+    ipc = 1.0 / (f("inv_ipc0") + f("apkc1k") * amat / 4.0)
+    amal = np.where(
+        a["n_a"] > 0,
+        hr_accel * (hit_lat + w_llc_a) + (1 - hr_accel) * miss_lat_a,
+        f("amal"))
+    # total_instr (sum * et accumulated) stays in the write-back, which
+    # accumulates it from the per-epoch core_ipc outputs op for op
+    ipc_shed = ipc * f("shed")[..., None]
+    core_ipc = _np_sum_order([ipc_shed[..., k] for k in range(n_cores)])
+    now = f("now") + f("et")
+    start = f("input_start")
+    return {"hr_core": _bits(hr_core), "hr_accel": _bits(hr_accel),
+            "amal": _bits(amal), "ipc": _bits(ipc),
+            "core_ipc": _bits(core_ipc), "now": _bits(now),
+            "comp_val": _bits(now - start),
+            "input_start": _bits(np.where(
+                a["completed"], np.maximum(start + f("period"), now), start)),
+            "cm_prev": _bits(cm), "pf_prev": _bits(pf_fills),
+            "total_llc": _bits(f("total_llc") + llc_units),
+            "total_dram": _bits(f("total_dram") + dram_traffic)}
+
+
+_SH_FINISH = ("llc_capacity", "s_llc", "w_cap_s", "prio_cap", "w_cap_s_prio",
+              "w_cap_dram", "w_cap_dram_prio", "dram_denom", "dram_rate",
+              "w_dram25", "hit_lat", "dram_lat", "inv_ipc0", "apkc1k", "et",
+              "period")
+_CY_FINISH = ("amal", "now", "input_start", "total_llc", "total_dram")
 
 
 def _finish_lane(dims: FusedDims, sh: SharedConsts, lc, cy, bg: _Begin,
                  new_st, stats, percore):
     """Port of Lane.finish_epoch for one lane (the caller vmaps): fluid
-    timing update, totals, progress bookkeeping — then a freeze select
-    so a frozen step is an identity on the carry."""
-    f64 = jnp.float64
+    timing update (``_finish_host``), totals, progress bookkeeping — then
+    a freeze select so a frozen step is an identity on the carry."""
     step_active = bg.step_active
-    accel_prio = bg.accel_prio
     n_a, n_c = bg.n_a, bg.n_c
-    shed = bg.shed
     ri_th, rc_th, special = bg.ri_th, bg.rc_th, bg.special
-
-    # ---- fluid timing update (finish_epoch) ---------------------------
     st64 = stats.astype(jnp.int64)
     ch, cm, cb_ = st64[0], st64[1], st64[2]
     ah, am, ab = st64[3], st64[4], st64[5]
-    awb, pf_fills = st64[6], st64[8]
-    hr_core = _div(ch, jnp.maximum(ch + cm, 1), sh.zero)
-    hr_accel = _div(ah, jnp.maximum(ah + am, 1), sh.zero)
-    llc_units = ((ch + cm + ah + am) - _mulb(0.7, cb_ + ab, sh.zero)
-                 - _mulb(0.3, awb, sh.zero))
-    rho_llc = _div(llc_units, sh.llc_capacity, sh.zero)
-    rho_a_llc = _div(ah + am, sh.llc_capacity, sh.zero)
-    dram_traffic = cm + am + pf_fills
-    # priority-arbitration branch (LLC-side waits stay fluid under the
-    # scheduled backend — only the DRAM waits come from the bank model)
-    w_llc_a_p = jnp.minimum(_mg1(rho_a_llc, sh.s_llc, sh.zero), sh.w_cap_s)
-    prio = jnp.minimum(_div(1.0, jnp.maximum(1.0 - rho_a_llc, 1e-3),
-                            sh.zero), sh.prio_cap)
-    w_llc_c_p = jnp.minimum(_mg1(rho_llc, sh.s_llc, sh.zero) * prio,
-                            sh.w_cap_s_prio)
-    # FIFO branch
-    w_fifo = jnp.minimum(_mg1(rho_llc, sh.s_llc, sh.zero), sh.w_cap_s)
-    w_llc_a = jnp.where(accel_prio, w_llc_a_p, w_fifo)
-    w_llc_c = jnp.where(accel_prio, w_llc_c_p, w_fifo)
+    pf_fills = st64[8]
     if dims.sched is None:
-        w_dram_fifo = jnp.minimum(_queue_delay(sh, dram_traffic),
-                                  sh.w_cap_dram)
-        rho_a_dram = jnp.minimum(_div(am, sh.dram_denom, sh.zero), 1.0)
-        w_dram_a_p = jnp.minimum(_queue_delay(sh, am), sh.w_cap_dram)
-        prio_d = jnp.minimum(_div(1.0, jnp.maximum(1.0 - rho_a_dram, 1e-3),
-                                  sh.zero), sh.prio_cap)
-        w_dram_c_p = jnp.minimum(w_dram_fifo * prio_d, sh.w_cap_dram_prio)
-        w_dram_a = jnp.where(accel_prio, w_dram_a_p, w_dram_fifo)
-        w_dram_c = jnp.where(accel_prio, w_dram_c_p, w_dram_fifo)
+        sched_nd = jnp.zeros((4, 2), jnp.uint32)
         bank_row2, bank_queue2 = cy.bank_row, cy.bank_queue
         bank_rr2 = cy.bank_rr
     else:
-        # SQUASH urgency: explicit accel priority, or a hydra lane whose
-        # achievable rate falls short of this epoch's requirement — both
-        # operands are the exact values the host computes (pre-update
-        # amal, the requirement just appended to history)
-        ma_hat_d = _div(sh.mlp_et, jnp.maximum(cy.amal, 1.0), sh.zero)
-        urgent = accel_prio | (lc.hydra & (ma_hat_d < bg.req_out))
         timing = (sh.sd_tcas, sh.sd_trcd, sh.sd_trp, sh.sd_tbus,
                   sh.sd_reset, sh.sd_qcap, sh.sd_kind)
         (num_a, den_a, num_c, den_c, bank_row2, bank_queue2,
          bank_rr2) = dramsched.epoch_compute(
             jnp, dims.sched, timing, cy.bank_row, cy.bank_queue,
-            cy.bank_rr, bg.samp, am, cm, pf_fills, urgent, cy.epoch,
+            cy.bank_rr, bg.samp, am, cm, pf_fills, bg.urgent, cy.epoch,
             sh.sd_et)
-        # num/den are exact in f64 (far below 2^53) so the division is
-        # bitwise-identical to the host's float(num)/float(den)
-        w_dram_a = jnp.minimum(
-            _div(num_a.astype(f64), den_a.astype(f64), sh.zero),
-            sh.w_cap_dram)
-        w_dram_c = jnp.minimum(
-            _div(num_c.astype(f64), den_c.astype(f64), sh.zero),
-            sh.w_cap_dram_prio)
-    miss_lat_c = sh.hit_lat + w_llc_c + sh.dram_lat + w_dram_c
-    miss_lat_a = sh.hit_lat + w_llc_a + sh.dram_lat + w_dram_a
-    pc = percore[:dims.n_cores].astype(jnp.int64)
-    hk = _div(pc[:, 0], jnp.maximum(pc[:, 0] + pc[:, 1], 1), sh.zero)
-    amat = (_mulb(hk, sh.hit_lat + w_llc_c, sh.zero)
-            + _mulb(1 - hk, miss_lat_c, sh.zero))
-    stall = _div(sh.apkc1k * amat, 4.0, sh.zero)
-    ipc = _div(1.0, sh.inv_ipc0 + stall, sh.zero)
-    amal = jnp.where(
-        n_a > 0,
-        _mulb(hr_accel, sh.hit_lat + w_llc_a, sh.zero)
-        + _mulb(1 - hr_accel, miss_lat_a, sh.zero),
-        cy.amal)
-
-    # total_instr (sum * et accumulated) stays host-side: the write-back
-    # accumulates it from the per-epoch core_ipc outputs with the host's
-    # exact ops, keeping one more add-of-product off the device.
-    ipc_shed = ipc * shed + sh.zero
-    core_ipc_sum = _np_sum_order([ipc_shed[k] for k in range(dims.n_cores)])
-    totals = cy.totals + jnp.stack([ch, cm, cb_, ah, am, ab, n_a])
-    total_llc = cy.total_llc + llc_units
-    total_dram = cy.total_dram + dram_traffic
-
-    # ---- progress bookkeeping -----------------------------------------
-    now = cy.now + sh.et
+        sched_nd = _words(jnp.stack([num_a, den_a, num_c, den_c]))
     pos2 = cy.pos + n_a
     completed = (n_a > 0) & (pos2 >= sh.m_total)
-    comp_val = now - cy.input_start
+    fo = _host_call(
+        functools.partial(_finish_host, dims.n_cores, dims.sched is not None),
+        {k: _f64() for k in ("hr_core", "hr_accel", "amal", "core_ipc",
+                             "now", "comp_val", "input_start", "cm_prev",
+                             "pf_prev", "total_llc", "total_dram")}
+        | {"ipc": _f64((dims.n_cores,))},
+        dict({k: getattr(sh, k) for k in _SH_FINISH},
+             **{k: getattr(cy, k) for k in _CY_FINISH},
+             stats=stats.astype(jnp.int32),
+             percore=percore.astype(jnp.int32),
+             accel_prio=bg.accel_prio, n_a=n_a.astype(jnp.int32),
+             shed=bg.shed, sched_nd=sched_nd, completed=completed,
+             lane=cy.pos))
+    totals = cy.totals + jnp.stack([ch, cm, cb_, ah, am, ab, n_a])
+
+    # ---- progress bookkeeping -----------------------------------------
     completions = cy.completions.at[
         jnp.where(completed, cy.input_idx, jnp.int64(dims.n_inputs))
-    ].set(comp_val, mode="drop")
+    ].set(fo["comp_val"], mode="drop")
     input_idx = cy.input_idx + completed.astype(jnp.int64)
     pos = jnp.where(completed, jnp.int64(0), pos2)
-    input_start = jnp.where(
-        completed, jnp.maximum(cy.input_start + sh.period, now),
-        cy.input_start)
     epoch = cy.epoch + 1
     active = (epoch < sh.max_epochs) & (input_idx < jnp.int64(dims.n_inputs))
 
     new = FusedCarry(
-        st=new_st, active=active, hr_core=hr_core, hr_accel=hr_accel,
-        amal=amal, ipc=ipc,
+        st=new_st, active=active, hr_core=fo["hr_core"],
+        hr_accel=fo["hr_accel"], amal=fo["amal"], ipc=fo["ipc"],
         stream_pos=cy.stream_pos + n_c, pos=pos, input_idx=input_idx,
-        input_start=input_start, now=now, ri_th=ri_th, rc_th=rc_th,
-        special=special, cm_prev=cm.astype(jnp.float64),
-        pf_prev=pf_fills.astype(jnp.float64), epoch=epoch,
+        input_start=fo["input_start"], now=fo["now"], ri_th=ri_th,
+        rc_th=rc_th, special=special, cm_prev=fo["cm_prev"],
+        pf_prev=fo["pf_prev"], epoch=epoch,
         completions=completions, totals=totals,
-        total_llc=total_llc, total_dram=total_dram,
+        total_llc=fo["total_llc"], total_dram=fo["total_dram"],
         overflow=cy.overflow,
         bank_row=bank_row2, bank_queue=bank_queue2, bank_rr=bank_rr2)
     # per-epoch occupancy readback, fused (llc.occupancy's counts on the
@@ -869,7 +967,7 @@ def _finish_lane(dims: FusedDims, sh: SharedConsts, lc, cy, bg: _Begin,
         overflow=cy.overflow | (step_active & bg.ovf))
     out = StepOut(active=commit, pos_before=cy.pos, n_a=n_a,
                   req=bg.req_out, ri_th=ri_th, rc_th=rc_th,
-                  core_ipc=core_ipc_sum, amal=out_cy.amal, occ=occ,
+                  core_ipc=fo["core_ipc"], amal=out_cy.amal, occ=occ,
                   alive=out_cy.active, ovf=out_cy.overflow)
     return out_cy, out
 
@@ -912,6 +1010,27 @@ def lane_supported(lane: Lane) -> bool:
                  or int(lane.tr.line.max()) < i32max - 1)
             and all(s.size == 0 or int(s.max()) < i32max
                     for s in lane.streams))
+
+
+def _fb(x) -> jnp.ndarray:
+    """Host float64 value(s) -> device IEEE bits: the values never pass
+    through the device's float64, which on a TPU would round them."""
+    return jnp.asarray(_bits(x))
+
+
+# FusedCarry / StepOut leaves holding float64 bits
+_CARRY_FLOATS = ("hr_core", "hr_accel", "amal", "ipc", "input_start", "now",
+                 "cm_prev", "pf_prev", "completions", "total_llc",
+                 "total_dram")
+_STEP_FLOATS = ("req", "core_ipc", "amal")
+
+
+def _to_host(nt, floats):
+    """Device NamedTuple -> numpy, with ``floats`` leaves decoded from
+    their bits."""
+    h = jax.tree.map(np.asarray, nt)
+    return h._replace(**{f: np.ascontiguousarray(getattr(h, f))
+                         .view(np.float64)[..., 0] for f in floats})
 
 
 def _i32(a: np.ndarray) -> np.ndarray:
@@ -981,41 +1100,36 @@ class _Staged:
             write=jnp.asarray(write),
             layer=jnp.asarray(layer),
             streams=jnp.asarray(streams),
-            nominal=jnp.asarray(np.array(
-                [pr.apkc / 1000.0 * et for pr in profiles])),
-            apkc1k=jnp.asarray(np.array(
-                [pr.apkc / 1000.0 for pr in profiles])),
-            ipc0=jnp.asarray(np.array([pr.ipc0 for pr in profiles])),
-            inv_ipc0=jnp.asarray(np.array(
-                [1.0 / pr.ipc0 for pr in profiles])),
-            et=jnp.float64(et), m_total=jnp.int64(lane0.m_total),
+            nominal=_fb([pr.apkc / 1000.0 * et for pr in profiles]),
+            apkc1k=_fb([pr.apkc / 1000.0 for pr in profiles]),
+            ipc0=_fb([pr.ipc0 for pr in profiles]),
+            inv_ipc0=_fb([1.0 / pr.ipc0 for pr in profiles]),
+            et=_fb(et), m_total=jnp.int64(lane0.m_total),
             max_epochs=jnp.int64(p.max_epochs),
-            deadline=jnp.float64(lane0.deadline),
-            period=jnp.float64(lane0.period),
-            ma_global=jnp.float64(lane0.apm.ma_global),
-            llc_capacity=jnp.float64(lane0.llc_capacity),
+            deadline=_fb(lane0.deadline),
+            period=_fb(lane0.period),
+            ma_global=_fb(lane0.apm.ma_global),
+            llc_capacity=_fb(lane0.llc_capacity),
             llc_capacity_int=jnp.int64(int(lane0.llc_capacity)),
-            s_llc=jnp.float64(lane0.s_llc),
-            w_cap_s=jnp.float64(p.w_cap * lane0.s_llc),
-            w_cap_s_prio=jnp.float64(p.w_cap * lane0.s_llc * p.prio_cap),
-            prio_cap=jnp.float64(p.prio_cap),
-            hit_lat=jnp.float64(p.llc_hit_lat),
-            dram_lat=jnp.float64(dram.latency_cycles),
-            dram_rate=jnp.float64(dram.rate),
-            dram_cap=jnp.float64(lane0.dram_cap),
-            dram_cap01=jnp.float64(0.1 * lane0.dram_cap),
-            dram_denom=jnp.float64(dram_denom),
-            w_cap_dram=jnp.float64(p.w_cap * dram.latency_cycles),
-            w_cap_dram_prio=jnp.float64(
-                p.w_cap * dram.latency_cycles * p.prio_cap),
-            w_dram25=jnp.float64(w_dram25),
-            mlp_et=jnp.float64(p.mlp_accel * et),
+            s_llc=_fb(lane0.s_llc),
+            w_cap_s=_fb(p.w_cap * lane0.s_llc),
+            w_cap_s_prio=_fb(p.w_cap * lane0.s_llc * p.prio_cap),
+            prio_cap=_fb(p.prio_cap),
+            hit_lat=_fb(p.llc_hit_lat),
+            dram_lat=_fb(dram.latency_cycles),
+            dram_rate=_fb(dram.rate),
+            dram_cap=_fb(lane0.dram_cap),
+            dram_cap01=_fb(0.1 * lane0.dram_cap),
+            dram_denom=_fb(dram_denom),
+            w_cap_dram=_fb(p.w_cap * dram.latency_cycles),
+            w_cap_dram_prio=_fb(p.w_cap * dram.latency_cycles * p.prio_cap),
+            w_dram25=_fb(w_dram25),
+            mlp_et=_fb(p.mlp_accel * et),
             sd_tcas=jnp.int64(sd[0]), sd_trcd=jnp.int64(sd[1]),
             sd_trp=jnp.int64(sd[2]), sd_tbus=jnp.int64(sd[3]),
             sd_reset=jnp.int64(sd[4]), sd_qcap=jnp.int64(sd[5]),
             sd_kind=jnp.int64(sd[6]),
-            sd_et=jnp.int64(int(p.epoch_cycles)),
-            zero=jnp.float64(0.0))
+            sd_et=jnp.int64(int(p.epoch_cycles)))
 
         self._wmax = wmax
         self._m = m
@@ -1031,7 +1145,7 @@ class _Staged:
         n_l, m, n_c = len(lanes), self._m, len(lanes[0].profiles)
         rc = np.zeros((n_l, self._m_pad), np.int8)
         ri = np.zeros((n_l, self._m_pad), np.int8)
-        cold = np.zeros((n_l, max(self._n_layers, 1)))
+        cold = np.zeros((n_l, max(self._n_layers, 1)))      # float64
         afr = np.zeros((n_l, self._m_pad), bool)
         writes = np.zeros((n_l, n_c, self._wmax), bool)
         mag = lanes[0].apm.ma_global
@@ -1079,16 +1193,11 @@ class _Staged:
                 [p.accel_mode == llc_mod.A_RAND for p in pols]),
             switch_point=jnp.asarray(switch),
             knobs=llc_mod.lane_knobs([lane.llc_cfg for lane in lanes]),
-            rc=jnp.asarray(rc), ri=jnp.asarray(ri), cold=jnp.asarray(cold),
+            rc=jnp.asarray(rc), ri=jnp.asarray(ri),
+            cold_le2=jnp.asarray(cold <= 2.0),
             afr=jnp.asarray(afr), writes=jnp.asarray(writes),
-            margin_high=jnp.asarray(apm_cols["margin_high"]),
-            margin_low=jnp.asarray(apm_cols["margin_low"]),
-            mr_th=jnp.asarray(apm_cols["mr_th"]),
-            behind_th=jnp.asarray(apm_cols["behind_th"]),
-            bands=jnp.asarray(bands), t_a=jnp.asarray(t_a),
-            t_b=jnp.asarray(apm_cols["t_b"]),
-            delta_a=jnp.asarray(apm_cols["delta_a"]),
-            delta_b=jnp.asarray(apm_cols["delta_b"]))
+            bands=_fb(bands), t_a=_fb(t_a),
+            **{k: _fb(v) for k, v in apm_cols.items()})
 
     def refresh_clusters(self, lanes: List[Lane]) -> None:
         """Re-upload per-lane cluster tables (after an online retrain)."""
@@ -1112,7 +1221,7 @@ def stage_group(lanes: List[Lane], k_epochs: int = DEFAULT_SUPERSTEP,
     """Build one group's staged device constants (the unit sweep's
     staging cache holds); time lands in the stage_s phase bucket."""
     t0 = time.perf_counter()
-    with enable_x64():
+    with jax.enable_x64():
         staged = _Staged(lanes, k_epochs, max_rounds, pads=pads)
     _PHASES["stage_s"] += time.perf_counter() - t0
     return staged
@@ -1140,32 +1249,32 @@ def _init_carry(lanes: List[Lane], states: llc_mod.LLCState,
     return FusedCarry(
         st=states,
         active=jnp.asarray(col([lane.active for lane in lanes])),
-        hr_core=jnp.asarray(col([lane.hr_core for lane in lanes])),
-        hr_accel=jnp.asarray(col([lane.hr_accel for lane in lanes])),
-        amal=jnp.asarray(col([lane.amal for lane in lanes])),
-        ipc=jnp.asarray(np.stack(
-            [np.asarray(lane.ipc, np.float64) for lane in lanes])),
+        hr_core=_fb([lane.hr_core for lane in lanes]),
+        hr_accel=_fb([lane.hr_accel for lane in lanes]),
+        amal=_fb([lane.amal for lane in lanes]),
+        ipc=_fb(np.stack([np.asarray(lane.ipc, np.float64)
+                          for lane in lanes])),
         stream_pos=jnp.asarray(np.stack(
             [np.asarray(lane.stream_pos, np.int64) for lane in lanes])),
         pos=jnp.asarray(col([lane.pos for lane in lanes], np.int64)),
         input_idx=jnp.asarray(col([lane.input_idx for lane in lanes],
                                   np.int64)),
-        input_start=jnp.asarray(col([lane.input_start for lane in lanes])),
-        now=jnp.asarray(col([lane.now for lane in lanes])),
+        input_start=_fb([lane.input_start for lane in lanes]),
+        now=_fb([lane.now for lane in lanes]),
         ri_th=jnp.asarray(col([lane.ri_th for lane in lanes], np.int64)),
         rc_th=jnp.asarray(col([lane.rc_th for lane in lanes], np.int64)),
         special=jnp.asarray(col([lane.special for lane in lanes], bool)),
-        cm_prev=jnp.asarray(col([lane.cm_prev for lane in lanes])),
-        pf_prev=jnp.asarray(col([lane.pf_prev for lane in lanes])),
+        cm_prev=_fb([lane.cm_prev for lane in lanes]),
+        pf_prev=_fb([lane.pf_prev for lane in lanes]),
         epoch=jnp.asarray(col([lane.epoch for lane in lanes], np.int64)),
-        completions=jnp.asarray(comp),
+        completions=_fb(comp),
         totals=jnp.asarray(np.stack([np.array(
             [lane.total_core_hits, lane.total_core_miss, lane.total_core_byp,
              lane.total_accel_hits, lane.total_accel_miss,
              lane.total_accel_byp, lane.total_accel_acc], np.int64)
             for lane in lanes])),
-        total_llc=jnp.asarray(col([lane.total_llc for lane in lanes])),
-        total_dram=jnp.asarray(col([lane.total_dram for lane in lanes])),
+        total_llc=_fb([lane.total_llc for lane in lanes]),
+        total_dram=_fb([lane.total_dram for lane in lanes]),
         overflow=jnp.zeros(n_l, bool),
         bank_row=jnp.asarray(b_row), bank_queue=jnp.asarray(b_queue),
         bank_rr=jnp.asarray(b_rr))
@@ -1247,8 +1356,8 @@ def _write_back_steps(lanes: List[Lane], y: StepOut) -> None:
 def _write_back(lanes: List[Lane], carry: FusedCarry, ys: StepOut) -> None:
     """Sync an accepted super-step's results into the host Lane objects
     (per-group driver: carry scalars + history rows in one call)."""
-    c = jax.tree.map(np.asarray, carry._replace(st=None))
-    y = jax.tree.map(np.asarray, ys)
+    c = _to_host(carry._replace(st=None), _CARRY_FLOATS)
+    y = _to_host(ys, _STEP_FLOATS)
     _write_back_carry(
         lanes, c, skip=[int(y.active[:, i].sum()) == 0
                         for i in range(len(lanes))])
@@ -1312,7 +1421,7 @@ def drive_lanes_fused(lanes: List[Lane], states=None,
     """
     assert all(lane_supported(lane) for lane in lanes)
     max_epochs = int(lanes[0].p.max_epochs)
-    with enable_x64():
+    with jax.enable_x64():
         staged = _Staged(lanes, k_epochs, max_rounds)
         if states is None:
             states = llc_mod.stack_states(staged.dims.cfg, len(lanes))
@@ -1321,7 +1430,7 @@ def drive_lanes_fused(lanes: List[Lane], states=None,
     while any(lane.active for lane in lanes):
         stop = _next_stop(lanes, max_epochs)
         epochs_before = [lane.epoch for lane in lanes]
-        with enable_x64():
+        with jax.enable_x64():
             new_carry, ys = _superstep(staged.dims, staged.sh, staged.lc,
                                        carry, jnp.int64(stop))
             overflowed = bool(np.asarray(new_carry.overflow).any())
@@ -1345,7 +1454,7 @@ def drive_lanes_fused(lanes: List[Lane], states=None,
             states = _host_stretch(lanes, carry.st, n_host)
             if not any(lane.active for lane in lanes):
                 return
-            with enable_x64():
+            with jax.enable_x64():
                 staged.refresh_clusters(lanes)
                 carry = _init_carry(lanes, states, staged.dims.n_inputs)
             continue
@@ -1363,7 +1472,7 @@ def drive_lanes_fused(lanes: List[Lane], states=None,
                 lane._online_retrain()
                 retrained = True
         if retrained:
-            with enable_x64():
+            with jax.enable_x64():
                 staged.refresh_clusters(lanes)
 
 
@@ -1474,15 +1583,21 @@ def _bucket_run(dims: FusedDims, n_shards: int):
                                         + y.shape[2:]), ys))
 
     if n_shards > 1:
-        from jax.sharding import Mesh, PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
 
-        from repro.sharding.compat import shard_map
-        mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("g",))
-        run = shard_map(run, mesh=mesh,
-                        in_specs=(P("g"), P("g"), P("g"), P("g")),
-                        out_specs=(P("g"), P(None, "g")),
-                        check_rep=False)
+        run = jax.shard_map(run, mesh=_group_sharding(n_shards).mesh,
+                            in_specs=(P("g"), P("g"), P("g"), P("g")),
+                            out_specs=(P("g"), P(None, "g")),
+                            check_vma=False)
     return run
+
+
+def _group_sharding(n_shards: int):
+    """The bucket's group axis split over the first ``n_shards`` devices."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("g",))
+    return NamedSharding(mesh, P("g"))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -1492,38 +1607,13 @@ def _superstep_bucket(dims: FusedDims, n_shards: int, sh_g, lc_g, carry_g,
     return _bucket_run(dims, n_shards)(sh_g, lc_g, carry_g, stop_g)
 
 
-# AOT-compiled donating executables, keyed on static dims + arg avals.
-_DONATED_EXECS: dict = {}
-
-
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(4,))
 def _superstep_bucket_donated(dims: FusedDims, n_shards: int, sh_g, lc_g,
                               carry_g, stop_g):
     """Donating twin of ``_superstep_bucket``: the carry buffers are
     donated to the next super-step (the driver never reads a dispatched
-    carry again — StepOut carries everything the host needs).
-
-    Compiled ahead-of-time with the persistent compilation cache
-    bypassed: executing a *deserialized* executable with donated buffers
-    corrupts the heap on jax 0.4.x CPU — the same bug
-    ``Trainer._compile_step`` works around, see docs/tpu_runbook.md."""
-    args = (sh_g, lc_g, carry_g, stop_g)
-    key = (dims, n_shards) + tuple(
-        (leaf.shape, str(leaf.dtype)) for leaf in jax.tree.leaves(args))
-    exe = _DONATED_EXECS.get(key)
-    if exe is None:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc)
-        prev = jax.config.jax_compilation_cache_dir
-        jax.config.update("jax_compilation_cache_dir", None)
-        cc.reset_cache()
-        try:
-            fn = jax.jit(_bucket_run(dims, n_shards), donate_argnums=(2,))
-            exe = fn.lower(*args).compile()
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-            cc.reset_cache()
-        _DONATED_EXECS[key] = exe
-    return exe(*args)
+    carry again — StepOut carries everything the host needs)."""
+    return _bucket_run(dims, n_shards)(sh_g, lc_g, carry_g, stop_g)
 
 
 def _stack_trees(trees):
@@ -1587,7 +1677,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
                   for g in groups]
     t0 = time.perf_counter()
     dims = staged[0].dims
-    with enable_x64():
+    with jax.enable_x64():
         # Groups in one bucket agree on every static field except the
         # incidental choice of lane0's LLCConfig for ``cfg`` — behaviour
         # knobs ride as LaneKnobs data, so only geometry_key must match
@@ -1608,6 +1698,12 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
     _PHASES["stage_s"] += time.perf_counter() - t0
     n_dev = devices if devices else len(jax.devices())
     n_shards = n_dev if (n_dev > 1 and n_groups % n_dev == 0) else 1
+    if n_shards > 1:
+        # the group axis lives on the mesh: constants left on one device
+        # would be resharded by every super-step
+        g_sharding = _group_sharding(n_shards)
+        with jax.enable_x64():
+            sh_g, lc_g = jax.device_put((sh_g, lc_g), g_sharding)
     # donation needs the one-device path: under shard_map the stacked
     # inputs are resharded on the way in, and donating a buffer that is
     # about to be resharded is not aliasing-safe on every backend
@@ -1649,10 +1745,15 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
         stops = [next_stop(i) for i in range(n_groups)]
         before = [list(e) for e in epochs]
         t = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64():
             step = _superstep_bucket_donated if donate else _superstep_bucket
-            carry, ys = step(dims, n_shards, sh_g, lc_g, carry,
-                             jnp.asarray(stops, jnp.int64))
+            stop_g = jnp.asarray(stops, jnp.int64)
+            if n_shards > 1:
+                # the same input shardings on every call, or it compiles
+                # again: the first carry sits on one device, and a
+                # returned carry's empty leaves come back replicated
+                carry, stop_g = jax.device_put((carry, stop_g), g_sharding)
+            carry, ys = step(dims, n_shards, sh_g, lc_g, carry, stop_g)
             for leaf in jax.tree.leaves(ys):
                 leaf.copy_to_host_async()
         _PHASES["dispatch_s"] += time.perf_counter() - t
@@ -1690,7 +1791,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
                 dims = dataclasses.replace(
                     dims, max_rounds=min(dims.max_rounds * 2,
                                          MAX_ROUNDS_CAP))
-                with enable_x64():
+                with jax.enable_x64():
                     carry = carry._replace(
                         overflow=jnp.zeros_like(carry.overflow))
                 overflow_pending.clear()
@@ -1698,7 +1799,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
             # ... and past the cap, demote only the offending groups:
             # sync their carry scalars and hand them to the per-group
             # driver (host fallback and all) from their frozen state
-            host_c = jax.tree.map(np.asarray, carry._replace(st=None))
+            host_c = _to_host(carry._replace(st=None), _CARRY_FLOATS)
             for i in sorted(overflow_pending):
                 if not live[i]:
                     continue
@@ -1711,12 +1812,12 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
                 for j in sorted(due[i]):
                     groups[i][j]._online_retrain()
                 due[i].clear()
-                with enable_x64():     # f64 leaves: slice under x64
+                with jax.enable_x64():     # f64 leaves: slice under x64
                     st_i = jax.tree.map(lambda x: x[i], carry.st)
                 drive_lanes_fused(groups[i], states=st_i,
                                   k_epochs=dims.k_epochs,
                                   max_rounds=dims.max_rounds)
-            with enable_x64():
+            with jax.enable_x64():
                 dead = jnp.asarray(np.asarray([not a for a in live]))
                 carry = carry._replace(
                     active=jnp.where(dead[:, None], False, carry.active),
@@ -1725,7 +1826,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
             continue
         ys, before = inflight.pop(0)
         t = time.perf_counter()
-        host_ys = jax.tree.map(np.asarray, ys)
+        host_ys = _to_host(ys, _STEP_FLOATS)
         _PHASES["device_s"] += time.perf_counter() - t
         t = time.perf_counter()
         for i in range(n_groups):
@@ -1755,7 +1856,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
                 groups[i][j]._online_retrain()
             due[i].clear()
             t = time.perf_counter()
-            with enable_x64():
+            with jax.enable_x64():
                 staged[i].refresh_clusters(groups[i])
                 lc_g = jax.tree.map(
                     lambda full, new: full.at[i].set(new),
@@ -1765,7 +1866,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
     # landed via _write_back_steps, and demoted groups were synced at
     # demotion (then driven to completion by the per-group driver)
     t = time.perf_counter()
-    host_c = jax.tree.map(np.asarray, carry._replace(st=None))
+    host_c = _to_host(carry._replace(st=None), _CARRY_FLOATS)
     for i in range(n_groups):
         if live[i]:
             _write_back_carry(groups[i],

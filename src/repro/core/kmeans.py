@@ -45,13 +45,18 @@ class SegmentedKMeansResult(NamedTuple):
 # kernel that depends on it: repro.kernels.common.SEG_BLOCK).
 from repro.kernels.common import SEG_BLOCK  # noqa: E402
 
+# Full-f32 contraction for every k-means dot: a TPU's default precision
+# rounds f32 operands to bf16, which moves centres and flips labels (RC
+# counts above 256 are not bf16-exact).  The CPU computes f32 either way.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def assign_jnp(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
     """Nearest-center assignment via the -2 x.c + ||c||^2 expansion (the
     row-constant ||x||^2 term is dropped from the argmin — exactly the
     decomposition the Pallas kernel computes, so both paths agree)."""
     c2 = jnp.sum(centers * centers, -1)
-    d2 = c2[None, :] - 2.0 * (x @ centers.T)
+    d2 = c2[None, :] - 2.0 * jnp.dot(x, centers.T, precision=_HIGHEST)
     return jnp.argmin(d2, axis=1).astype(jnp.int32)
 
 
@@ -128,14 +133,15 @@ def kmeans_fit_masked(x: jnp.ndarray, mask: jnp.ndarray, key: jnp.ndarray,
         # d2 = ||x||^2 - 2 x.c + ||c||^2; the ||x||^2 term only matters for
         # the farthest-point reseed, not the argmin
         c2 = jnp.sum(centers * centers, -1)
-        sc = c2[None, :] - 2.0 * (x @ centers.T)            # [N, K]
+        sc = c2[None, :] - 2.0 * jnp.dot(x, centers.T,
+                                         precision=_HIGHEST)  # [N, K]
         if use_kernel:
             a = assign_fn(x, centers)
         else:
             a = jnp.argmin(sc, axis=1).astype(jnp.int32)
         one_hot = jax.nn.one_hot(a, k, dtype=x.dtype) * fmask[:, None]
         counts = jnp.sum(one_hot, 0)                        # [K]
-        sums = one_hot.T @ x                                # [K, D]
+        sums = jnp.dot(one_hot.T, x, precision=_HIGHEST)    # [K, D]
         new = sums / jnp.maximum(counts, 1.0)[:, None]
         # re-seed empty clusters at the farthest valid point
         far_score = jnp.where(mask, x2 + jnp.min(sc, 1), -jnp.inf)
@@ -231,7 +237,7 @@ def assign_segmented_jnp(x: jnp.ndarray, centers: jnp.ndarray,
     segc = jnp.minimum(seg, centers.shape[0] - 1)
     cg = centers[segc]                              # [P, K, D]
     c2 = jnp.sum(cg * cg, -1)                       # [P, K]
-    d2 = c2 - 2.0 * jnp.einsum("pd,pkd->pk", x, cg)
+    d2 = c2 - 2.0 * jnp.einsum("pd,pkd->pk", x, cg, precision=_HIGHEST)
     return jnp.argmin(d2, axis=1).astype(jnp.int32)
 
 
@@ -273,7 +279,8 @@ def _lloyd_segmented(x: jnp.ndarray, seg: jnp.ndarray,
         else:
             cg = centers[segc]                       # [P, K, D]
             c2 = jnp.sum(cg * cg, -1)
-            sc = c2 - 2.0 * jnp.einsum("pd,pkd->pk", x, cg)
+            sc = c2 - 2.0 * jnp.einsum("pd,pkd->pk", x, cg,
+                                       precision=_HIGHEST)
             a = jnp.argmin(sc, axis=1).astype(jnp.int32)
             min_sc = jnp.min(sc, 1)
         # two-stage segment reduction: dense per-block partial sums (the
@@ -400,9 +407,10 @@ def kmeans_fit_batched(x: jnp.ndarray, mask: jnp.ndarray, keys: jnp.ndarray,
     """vmap of ``kmeans_fit_masked`` over a leading batch axis.
 
     x [B, N, D], mask [B, N], keys [B, 2] -> KMeansResult with a leading
-    B axis on every field.  Each batch row is bitwise-identical to the
-    single-problem fit at the same padded shape — this is what lets the
-    batched LERN trainer reproduce the per-layer pipeline exactly.
+    B axis on every field.  Each batch row assigns exactly as the
+    single-problem fit at the same padded shape (centres equal up to the
+    float reassociation vmap may introduce) — this is what lets the
+    batched LERN trainer reproduce the per-layer pipeline's labels.
     """
     fit = functools.partial(kmeans_fit_masked, k=k, iters=iters,
                             use_kernel=use_kernel)

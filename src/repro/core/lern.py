@@ -18,7 +18,9 @@ Three training entry points:
 * ``train`` — the host-reference path: per-layer numpy feature oracle +
   the same shared jitted fit at the same bucket shapes.  Because every
   floating-point step lives in ``_fit_layer`` (shared) and the feature
-  tables are integers, the two paths agree bitwise (tests/test_lern_batched).
+  tables are integers, the two paths produce the same cluster tables
+  (centres equal up to float reassociation under vmap;
+  tests/test_lern_batched).
 * ``train_host_numpy`` — the seed-era per-layer pipeline, kept only as
   the bench_lern.json perf baseline.
 
@@ -53,8 +55,8 @@ MIN_MULTI = 8  # need enough multi-occurrence lines for 4 clusters
 
 # How the batched trainers run their k-means fits:
 #   "bucketed"  — layers padded into power-of-two capacity buckets, each
-#                 bucket vmapped over `_fit_layer` (the oracle path: bitwise
-#                 equal to the per-layer host reference `train`).
+#                 bucket vmapped over `_fit_layer` (the oracle path: same
+#                 cluster tables as the per-layer host reference `train`).
 #   "segmented" — all layers' points concatenated into ONE flat array with a
 #                 segment-id column; seeding and the Lloyd loop run as
 #                 segment-wise reductions (`kmeans.kmeans_fit_segmented`) —
@@ -232,7 +234,7 @@ def _fit_layer(f_ri: jnp.ndarray, f_rc: jnp.ndarray, n_multi: jnp.ndarray,
     first ``n_multi`` rows (uniq order), zero-padded to the fixed capacity
     N.  Fixed-shape and mask-driven, so ``train_model_batched`` vmaps it
     over layers while ``train_layer`` calls it per layer at the same
-    padded shape — both bitwise-identical.
+    padded shape — both give the same cluster tables.
     """
     n = f_rc.shape[0]
     cmask = jnp.arange(n, dtype=jnp.int32) < n_multi
@@ -255,7 +257,8 @@ def _fit_layer(f_ri: jnp.ndarray, f_rc: jnp.ndarray, n_multi: jnp.ndarray,
     oh = jax.nn.one_hot(ri_res.assign, 4, dtype=jnp.float32) \
         * cmask[:, None].astype(jnp.float32)
     cnt = jnp.sum(oh, 0)
-    ri_centers = (oh.T @ raw) / jnp.maximum(cnt, 1.0)[:, None]
+    ri_centers = (jnp.dot(oh.T, raw, precision=jax.lax.Precision.HIGHEST)
+                  / jnp.maximum(cnt, 1.0)[:, None])
     return {"rc_assign": rc_res.assign, "rc_centers": rc_centers,
             "rc_centers_norm": rc_res.centers.reshape(-1),
             "ri_assign": ri_res.assign, "ri_centers": ri_centers}
@@ -418,7 +421,7 @@ def train_layer(lines: np.ndarray, seed: int = 0,
     same jitted ``_fit_layer`` program as the batched trainer, padded to
     ``cap`` points.  The default — this layer's own power-of-two bucket —
     is exactly the capacity its row gets in ``train_model_batched``'s
-    bucket groups, which is what makes the two paths bitwise-equal."""
+    bucket groups, which is what makes the two paths label-equal."""
     sig = reuse_signature_np(lines)
     f_ri, f_rc = ri_histogram_np(lines, sig)
     return _fit_host_features(sig["uniq"], f_ri, f_rc, seed, cap)
@@ -636,7 +639,7 @@ def train_model_batched(trace: Trace, hash_fn: Optional[Callable] = None,
     capacity buckets (``use_kernel``: None = Pallas assignment where it
     compiles).  No per-layer Python k-means loop; only the O(k)-sized
     semantic annotation runs on the host.  With ``fit_engine="bucketed"``
-    it is bitwise-equal to ``train`` (the float pipeline is the shared
+    it is label-equal to ``train`` (the float pipeline is the shared
     ``_fit_layer`` at identical padded shapes); the default segmented
     engine is cluster-assignment-equal to that oracle (same label tables,
     centers to FP reassociation) with no capacity padding."""

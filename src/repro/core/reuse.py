@@ -134,14 +134,9 @@ def ri_bin(ri: jnp.ndarray) -> jnp.ndarray:
 
 def _ri_bins_kernel(ri: jnp.ndarray) -> jnp.ndarray:
     """Per-access RI bin (-1 for no-reuse) through the Pallas kernel."""
-    from repro.kernels.common import INTERPRET, block_and_pad, pad_rows
-    from repro.kernels.ri_histogram.kernel import ri_histogram
+    from repro.kernels.ri_histogram.ops import histogram
 
-    n = ri.shape[0]
-    block, npad = block_and_pad(n, 4096)
-    bins, _ = ri_histogram(pad_rows(ri, npad, -1), block_n=block,
-                           interpret=INTERPRET)
-    return bins[:n]
+    return histogram(ri)[0]
 
 
 def reuse_features_jax(lines: jnp.ndarray, n_valid: jnp.ndarray,

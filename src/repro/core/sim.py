@@ -44,15 +44,20 @@ from .policies import Policy
 from .tracegen import Trace, generate_trace
 from .workloads import CONFIGS, AccelConfig
 
-CACHE_DIR = os.environ.get("REPRO_CACHE", os.path.join(
+_CHECKOUT_CACHE = os.path.abspath(os.path.join(
     os.path.dirname(__file__), "..", "..", "..", ".cache"))
+CACHE_DIR = os.environ.get("REPRO_CACHE", _CHECKOUT_CACHE)
 
 # Persistent XLA compilation cache: the round-engine compiles once per
-# round-bucket; share them across benchmark processes.
+# round-bucket; share them across benchmark processes.  An outside
+# JAX_COMPILATION_CACHE_DIR (which JAX reads itself) places it; otherwise
+# it sits at a fixed path in the checkout.  The directory is part of
+# every entry's key, so it deliberately does not follow REPRO_CACHE.
+XLA_CACHE_DIR = os.path.join(_CHECKOUT_CACHE, "xla")
 if os.environ.get("REPRO_JIT_CACHE", "1") == "1":
     import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(CACHE_DIR, "xla"))
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", XLA_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 

@@ -423,9 +423,9 @@ def _params_key(p: sim.SimParams, dram: DramModel) -> str:
 def _worker_init(cache_dir: str, extra_configs: Optional[Dict] = None,
                  fit_engine: Optional[str] = None) -> None:
     # sim is already imported (unpickling this initializer imports sweep),
-    # so its import-time XLA-cache config came from the inherited env;
-    # propagate a programmatic CACHE_DIR override (e.g. test monkeypatch)
-    # to the artifact caches here, and to the persistent XLA cache too.
+    # so its import-time config came from the inherited env; propagate a
+    # programmatic CACHE_DIR override (e.g. test monkeypatch) to the
+    # artifact caches here.  The XLA cache stays where sim put it.
     sim.CACHE_DIR = cache_dir
     if fit_engine is not None:
         # ExecPlan.fit_engine: spawn workers don't see the parent's
@@ -439,10 +439,6 @@ def _worker_init(cache_dir: str, extra_configs: Optional[Dict] = None,
         from .workloads import CONFIGS
         for name, cfg in extra_configs.items():
             CONFIGS.setdefault(name, cfg)
-    if os.environ.get("REPRO_JIT_CACHE", "1") == "1":
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(cache_dir, "xla"))
 
 
 def _calibrate_task(task) -> float:
@@ -578,9 +574,11 @@ def _fill_twins(results, seen_paths) -> None:
 
 
 def _run_task_inline(task, engine: str, retries: int) -> Tuple:
-    """Inline (jobs<=1) resilient execution of one group task: retry
-    with exponential backoff on the requested engine, then a final
-    attempt on the host engine.  Returns (results, attempts, engine)."""
+    """Inline (jobs<=1) resilient execution of one group task: a
+    degradable failure (``faults.degradable``) retries with exponential
+    backoff on the requested engine, then makes a final attempt on the
+    host engine; any other error propagates at once.  Returns (results,
+    attempts, engine)."""
     flt = _faults()
     attempts = 0
     while True:
@@ -589,7 +587,7 @@ def _run_task_inline(task, engine: str, retries: int) -> Tuple:
         try:
             return _group_task(task, engine=eng), attempts, eng
         except Exception as e:
-            if attempts > retries:
+            if attempts > retries or not flt.degradable(e):
                 raise
             flt.log_event("task_retry", task=f"{task[0]}|{task[1]}",
                           attempt=attempts, error=str(e)[:200])

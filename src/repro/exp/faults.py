@@ -297,16 +297,14 @@ def fire(site: str, key: str = "") -> Optional[FaultSpec]:
 
 def degradable(exc: BaseException) -> bool:
     """Is this the class of failure the engine ladder may absorb by
-    demoting bucket→fused→host (XLA compile / RESOURCE_EXHAUSTED /
-    injected), as opposed to a logic error that must propagate?"""
+    demoting bucket→fused→host (device memory exhausted, or an injected
+    fault), as opposed to a bug that must propagate?  A compile refusal
+    or any other runtime error is a bug: demoting it would finish the
+    run on the host and hide that the device path never ran."""
     if isinstance(exc, InjectedFault):
         return True
-    name = type(exc).__name__
-    if name in ("XlaRuntimeError", "JaxRuntimeError"):
-        return True
     msg = str(exc)
-    return ("RESOURCE_EXHAUSTED" in msg or "out of memory" in msg
-            or "Compilation failure" in msg)
+    return "RESOURCE_EXHAUSTED" in msg or "out of memory" in msg
 
 
 # ---------------------------------------------------------------------------

@@ -37,6 +37,20 @@ _ENGINES = ("auto", "host", "fused", "bucketed")
 _FIT_ENGINES = ("auto", "bucketed", "segmented")
 
 
+def _check_pool_backend(jobs: int) -> None:
+    """A process pool is a CPU-only tool: one process owns an accelerator,
+    and spawned workers that import JAX would fight the parent for it
+    (fail or hang).  Raise before any child starts."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise ValueError(
+            f"ExecPlan(jobs={jobs}) starts worker processes, but this "
+            f"process holds the {backend} device and a worker cannot share "
+            "it; use jobs=1 (the bucketed engine batches the whole sweep "
+            "on the device)")
+
+
 @dataclasses.dataclass(frozen=True)
 class ExecPlan:
     """How to execute a spec.  ``None`` fields resolve to env defaults.
@@ -45,7 +59,10 @@ class ExecPlan:
                 (default: env ``REPRO_ENGINE``; legacy ``REPRO_FUSED=0``
                 means "host"; else "auto")
     jobs:       process-pool width for the host fallback (default 1;
-                ignored by the bucketed engine, which batches on device)
+                ignored by the bucketed engine, which batches on device).
+                ``jobs > 1`` needs the CPU backend: every worker process
+                would need the accelerator this process already holds,
+                so ``resolve`` raises on any other backend
     devices:    device count for ``shard_map`` over buckets (default:
                 all visible devices)
     cache:      read/write the sim disk result cache (default True)
@@ -98,6 +115,9 @@ class ExecPlan:
         if fit not in _FIT_ENGINES:
             raise ValueError(f"unknown fit_engine {fit!r} from "
                              f"REPRO_LERN_FIT (expected one of {_FIT_ENGINES})")
+        jobs = max(1, int(self.jobs if self.jobs is not None else 1))
+        if jobs > 1:
+            _check_pool_backend(jobs)
         from repro.core import sweep  # deferred: exp layers above core
         # mirrors fused.PIPELINE_DEFAULT without importing the (heavy)
         # fused module here — plan resolution must stay light
@@ -105,7 +125,7 @@ class ExecPlan:
                     if self.pipeline is None else bool(self.pipeline))
         return dataclasses.replace(
             self, engine=engine,
-            jobs=max(1, int(self.jobs if self.jobs is not None else 1)),
+            jobs=jobs,
             devices=self.devices,
             cache=True if self.cache is None else bool(self.cache),
             fit_engine=fit,

@@ -16,8 +16,9 @@ INTERPRET: bool = jax.default_backend() != "tpu"
 # Row granularity of the flat-segmented k-means layout: every segment's
 # point run is padded to a multiple of this, so each SEG_BLOCK-row block
 # belongs to exactly one segment and the segmented assignment kernel can
-# map block -> centroid slab with one prefetched id per block.
-SEG_BLOCK = 8
+# map block -> centroid slab with one prefetched id per block.  The
+# kernel's rank-1 output block must be a multiple of the 128-lane width.
+SEG_BLOCK = 128
 
 
 def round_up(n: int, multiple: int) -> int:

@@ -56,7 +56,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.exp import faults
 
@@ -297,7 +296,7 @@ def _feed_scheduler(sched: HydraKVScheduler, trace: SessionTrace,
 
 def _replay_batched(trace: SessionTrace, sched: HydraKVScheduler,
                     dims: _Dims) -> ReplayResult:
-    with enable_x64():
+    with jax.enable_x64():
         consts = {
             "arrival": jnp.asarray(trace.arrival, jnp.int64),
             "turns": jnp.asarray(trace.turns, jnp.int64),
@@ -314,7 +313,7 @@ def _replay_batched(trace: SessionTrace, sched: HydraKVScheduler,
     while True:
         faults.fire("serve_step", key=f"e{epoch}")
         faults.fire("serve_admission", key=f"e{epoch}")
-        with enable_x64():
+        with jax.enable_x64():
             carry, comp = _superstep(
                 dims, consts, carry,
                 jnp.asarray(rc_cl), jnp.asarray(ri_cl),

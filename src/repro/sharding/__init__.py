@@ -1,3 +1,2 @@
-from .compat import abstract_mesh, shard_map  # noqa: F401
 from .rules import (batch_specs, decode_state_specs, param_specs,
                     shard_tree)  # noqa: F401

@@ -56,37 +56,9 @@ class Trainer:
                             lr_warmup=tcfg.lr_warmup,
                             lr_total=max(tcfg.steps, 10 * tcfg.lr_warmup)),
             donate_argnums=(0, 1))
-        self._compiled = None
         self._stop = False
         self.history: List[Dict] = []
         self.straggler_steps = 0
-
-    def _compile_step(self, params, opt, batch):
-        """AOT-compile the train step with the persistent XLA compilation
-        cache bypassed.
-
-        The sim/llc modules enable jax's persistent compilation cache at
-        import, and on jax 0.4.x CPU *executing a deserialized executable
-        with donated buffers corrupts the heap* (the input-output aliasing
-        is dropped on reload).  A fresh Trainer in a process that already
-        ran a simulation — e.g. tests/test_system.py before
-        tests/test_integration.py — would otherwise get a poisoned cache
-        hit here.  Compiling fresh (cache dir unset) sidesteps it; repeat
-        steps reuse the compiled object, so only startup pays.
-
-        ``reset_cache`` is required around the config flip: jax memoizes
-        the is-cache-used decision at the first compile of the process, so
-        updating the config alone would not bypass anything.
-        """
-        from jax.experimental.compilation_cache import compilation_cache as cc
-        prev = jax.config.jax_compilation_cache_dir
-        jax.config.update("jax_compilation_cache_dir", None)
-        cc.reset_cache()
-        try:
-            return self.step_fn.lower(params, opt, batch).compile()
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-            cc.reset_cache()
 
     def _install_signals(self):
         def handler(signum, frame):
@@ -122,9 +94,7 @@ class Trainer:
                 break
             batch = self.pipe.batch(step)
             t0 = time.time()
-            if self._compiled is None:
-                self._compiled = self._compile_step(params, opt, batch)
-            params, opt, metrics = self._compiled(params, opt, batch)
+            params, opt, metrics = self.step_fn(params, opt, batch)
             loss = float(metrics["loss"])
             dt = time.time() - t0
             durations.append(dt)

@@ -8,7 +8,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.optim.compress import (dequantize, quantize, quantized_psum,
                                   quantized_psum_tree)
-from repro.sharding.compat import shard_map
 
 
 def test_quantize_roundtrip_error_bound():
@@ -25,7 +24,7 @@ def test_quantized_psum_matches_psum():
     mesh = jax.make_mesh((1,), ("pod",))
     x = jnp.asarray(np.random.default_rng(1).normal(size=(64,)), jnp.float32)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P(),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
                        out_specs=P())
     def f(v):
         return quantized_psum(v, "pod")
@@ -53,7 +52,7 @@ def test_tree_version():
     tree = {"a": jnp.ones((8,)), "b": {"c": jnp.full((4,), -2.0)}}
     mesh = jax.make_mesh((1,), ("pod",))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P(),),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(),),
                        out_specs=P())
     def f(t):
         return quantized_psum_tree(t, "pod")

@@ -64,25 +64,21 @@ def test_queue_delay_consts_golden():
 
 
 def test_fused_queue_delay_matches_host():
-    """fused._queue_delay over staged SharedConsts-style scalars must agree
-    with DramModel.queue_delay exactly — both derive from
-    queue_delay_consts and apply the same op order."""
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
+    """fused._queue_delay over staged SharedConsts-style scalars (float64
+    bits, as the fused engine's host callback reads them) must agree with
+    DramModel.queue_delay exactly — both derive from queue_delay_consts
+    and apply the same op order."""
     from repro.core import fused
 
     et = 50_000.0
-    with enable_x64():
-        for m in (DDR3_1600, DDR4_2400_SQUASH):
-            denom, cap = queue_delay_consts(m, et)
-            sh = SimpleNamespace(zero=jnp.float64(0.0),
-                                 dram_denom=jnp.float64(denom),
-                                 dram_rate=jnp.float64(m.rate),
-                                 w_dram25=jnp.float64(cap))
-            for traffic in (0.0, 17.0, 1234.5, 3e3, 1e7):
-                got = float(fused._queue_delay(sh, jnp.float64(traffic)))
-                assert got == m.queue_delay(traffic, et), (m.name, traffic)
+    for m in (DDR3_1600, DDR4_2400_SQUASH):
+        denom, cap = queue_delay_consts(m, et)
+        f = fused._floats({"dram_denom": fused._bits(denom),
+                           "dram_rate": fused._bits(m.rate),
+                           "w_dram25": fused._bits(cap)})
+        for traffic in (0.0, 17.0, 1234.5, 3e3, 1e7):
+            got = float(fused._queue_delay(f, traffic))
+            assert got == m.queue_delay(traffic, et), (m.name, traffic)
 
 
 # ---------------------------------------------------------------------------

@@ -33,11 +33,11 @@ def _step(xp, model, state, samp, am, cm, pf, urgent, epoch,
           et_i=50_000):
     import contextlib
 
-    from jax.experimental import enable_x64
+    import jax
 
     # the jnp twin runs under scoped x64, exactly as the fused engine
     # wraps its dispatches (the global x64 flag stays off repo-wide)
-    scope = contextlib.nullcontext() if xp is np else enable_x64()
+    scope = contextlib.nullcontext() if xp is np else jax.enable_x64()
     dims = dramsched.sched_dims(model)
     timing = dramsched.timing_tuple(model)
     with scope:

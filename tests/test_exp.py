@@ -233,6 +233,31 @@ def test_execplan_env_defaults_and_validation(monkeypatch):
         exp.ExecPlan().engine = "host"
 
 
+def test_execplan_jobs_refused_on_accelerator(monkeypatch):
+    """A process pool needs the CPU backend: on an accelerator the parent
+    holds the device, so ExecPlan(jobs>1) raises before any worker (or
+    any LERN training, or calibration) starts."""
+    import jax
+
+    from repro.core import sweep
+    spec = exp.ExperimentSpec.grid(config="config1", mix="moti1",
+                                   policy=["fifo-nb"], params=TINY)
+    started = []
+    monkeypatch.setattr(sweep, "map_points",
+                        lambda *a, **kw: started.append(a))
+    monkeypatch.setattr(sweep, "_prepare_lern",
+                        lambda *a, **kw: started.append(a))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match=r"jobs=2.*tpu"):
+        exp.ExecPlan(jobs=2).resolve()
+    with pytest.raises(ValueError, match="jobs=1"):
+        exp.run(spec, plan=exp.ExecPlan(engine="host", jobs=2))
+    assert not started
+    assert exp.ExecPlan(jobs=1).resolve().jobs == 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert exp.ExecPlan(jobs=2).resolve().jobs == 2
+
+
 def test_execplan_legacy_kwargs_removed():
     # the one-release deprecation grace for the pre-ExecPlan bare kwargs
     # is over: execution knobs live solely on ExecPlan now
@@ -290,7 +315,6 @@ def test_worker_init_reships_runtime_configs(tmp_path, monkeypatch):
     """Spawn workers re-import workloads.py, losing runtime-registered
     configs; _worker_init must re-register the shipped extras."""
     from repro.core import sweep
-    monkeypatch.setenv("REPRO_JIT_CACHE", "0")   # don't move the XLA cache
     old_cache = sim.CACHE_DIR
     name = "config-unit-test-ephemeral"
     cfg = dataclasses.replace(workloads.CONFIGS["config10"], name=name)

@@ -279,6 +279,57 @@ def test_bucket_demotes_all_the_way_to_host(tmp_path, monkeypatch,
     assert any(r.get("engine") == "host" for r in report.points.values())
 
 
+def test_degradable_rejects_plain_runtime_errors():
+    """Only exhausted device memory and injected faults walk the engine
+    ladder.  Any other runtime error — a Mosaic compile refusal is one —
+    is a bug: absorbing it would finish the run on the host and hide that
+    the device path never ran."""
+    from jax.errors import JaxRuntimeError
+    assert not faults.degradable(JaxRuntimeError(
+        "INVALID_ARGUMENT: Mosaic failed to compile TPU kernel"))
+    assert not faults.degradable(ValueError("logic error"))
+    assert faults.degradable(JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer"))
+    assert faults.degradable(faults.InjectedFault("injected"))
+    assert faults.degradable(faults.InjectedResourceExhausted(
+        "RESOURCE_EXHAUSTED: injected"))
+
+
+def test_bucket_runtime_error_propagates(tmp_path, monkeypatch):
+    """A non-degradable failure inside a bucket is raised, not demoted."""
+    from jax.errors import JaxRuntimeError
+    monkeypatch.setattr(sim, "CACHE_DIR", str(tmp_path))
+
+    def refuse(*a, **kw):
+        raise JaxRuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(fused, "drive_lanes_bucketed", refuse)
+    demoted = []
+    monkeypatch.setattr(sweep, "_demote_batch",
+                        lambda *a, **kw: demoted.append(a))
+    with pytest.raises(JaxRuntimeError, match="Mosaic"):
+        sweep.run_bucketed(_points(("moti1",)), report=faults.RunReport())
+    assert not demoted
+
+
+def test_inline_task_error_not_degradable_propagates(tmp_path, monkeypatch):
+    """jobs<=1: a non-degradable task error is raised at once — no retry
+    and no final attempt on the host engine."""
+    monkeypatch.setattr(sim, "CACHE_DIR", str(tmp_path))
+    calls = []
+
+    def broken(task, engine="auto"):
+        calls.append(engine)
+        raise ValueError("logic error")
+
+    monkeypatch.setattr(sweep, "_group_task", broken)
+    report = faults.RunReport()
+    with pytest.raises(ValueError, match="logic error"):
+        sweep.map_points(_points(("moti1",)), jobs=1, report=report)
+    assert calls == ["auto"]
+    assert not any(e["kind"] == "task_retry" for e in report.events)
+
+
 def test_forced_bucket_overflow_demotion_bitwise(tmp_path, monkeypatch,
                                                  clean_baseline):
     """The ``bucket_overflow`` site forces the bucketed driver's real

@@ -1,14 +1,16 @@
 """Device-resident LERN training: the batched pipeline must reproduce the
-host-numpy reference bitwise, and the flat-segmented fit engine must be
+host-numpy reference (integer tables bitwise, cluster labels exactly,
+centres within ``CENTER_RTOL``), and the flat-segmented fit engine must be
 cluster-assignment-equal to that bucketed oracle.
 
 Layers of parity:
 * jitted ``reuse_features_jax`` == numpy oracle, for any padding amount
   and ragged layer batches (hypothesis property; integer-exact);
 * ``kmeans_fit_batched`` row == single ``kmeans_fit_masked`` at the same
-  padded shape (the vmap-vs-single bitwise claim the trainer rests on);
+  padded shape (assignments exact, centres within tolerance);
 * ``train_model_batched(fit_engine="bucketed")`` == ``train`` on a
-  multi-layer trace (cluster tables, centers, uniq sets — all bitwise),
+  multi-layer trace (uniq sets and cluster tables exact, centres within
+  tolerance),
   plus packed L-RPT images == per-layer ``load_layer`` tables;
 * ``train_model_batched(fit_engine="segmented")`` == the bucketed oracle
   on the semantic cluster-label tables (the annotation step's
@@ -86,9 +88,18 @@ def _synthetic_trace(n_layers: int = 3, seed: int = 0) -> Trace:
                  compute_cycles=len(line))
 
 
+# Centre tolerance of the label-equality contract.  The label tables are
+# the model LERN hands the simulator and must match exactly; centres are
+# float32 sums whose association order XLA may change (vmap vs a single
+# fit, segmented vs bucketed reductions, one jax release to the next) —
+# observed differences are a few ulp (<= 2.4e-7 relative), so 1e-4 is
+# loose enough to be stable and tight enough to catch a wrong cluster.
+CENTER_RTOL = CENTER_ATOL = 1e-4
+
+
 def _assert_labels_equal(a, b, centers_exact=True):
     """a (oracle) and b agree on every cluster-label table; centers are
-    bitwise when ``centers_exact`` else allclose (FP reassociation)."""
+    bitwise when ``centers_exact`` else within CENTER_RTOL/CENTER_ATOL."""
     assert a.n_layers == b.n_layers
     np.testing.assert_array_equal(a.n_uniq, b.n_uniq)
     for li in range(a.n_layers):
@@ -106,26 +117,21 @@ def _assert_labels_equal(a, b, centers_exact=True):
                                           b.ri_centers[li])
         else:
             np.testing.assert_allclose(a.rc_centers[li], b.rc_centers[li],
-                                       rtol=1e-4, atol=1e-4)
+                                       rtol=CENTER_RTOL, atol=CENTER_ATOL)
             np.testing.assert_allclose(a.ri_centers[li], b.ri_centers[li],
-                                       rtol=1e-4, atol=1e-4)
+                                       rtol=CENTER_RTOL, atol=CENTER_ATOL)
 
 
 def test_train_batched_matches_host_bitwise():
+    """The batched (vmapped) trainer reproduces the per-layer host
+    reference: integer features and uniq sets bitwise, cluster-label
+    tables exactly, centres within the contract's tolerance — XLA CPU
+    may reassociate the vmapped fit's float sums (a 1-ulp centre drift
+    appeared with jax 0.9), which must never move a label."""
     tr = _synthetic_trace()
     a = lern.train(tr, seed=3)
     b = lern.train_model_batched(tr, seed=3, fit_engine="bucketed")
-    np.testing.assert_array_equal(a.n_uniq, b.n_uniq)
-    for li in range(a.n_layers):
-        n = int(a.n_uniq[li])
-        np.testing.assert_array_equal(a.uniq[li, :n], b.uniq[li, :n])
-        np.testing.assert_array_equal(a.rc_cluster[li, :n],
-                                      b.rc_cluster[li, :n])
-        np.testing.assert_array_equal(a.ri_cluster[li, :n],
-                                      b.ri_cluster[li, :n])
-        np.testing.assert_array_equal(a.rc_centers[li], b.rc_centers[li])
-        np.testing.assert_array_equal(a.ri_centers[li], b.ri_centers[li])
-        np.testing.assert_array_equal(a.features_ri[li], b.features_ri[li])
+    _assert_labels_equal(a, b, centers_exact=False)
 
 
 def test_train_segmented_matches_bucketed_labels():

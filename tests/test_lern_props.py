@@ -1,7 +1,8 @@
 """Hypothesis property tests for the device-resident LERN pipeline:
-padded/ragged batches of the jitted feature extractor and the batched
-masked k-means must match their single-problem references bitwise, and
-the flat-segmented fit engine must stay cluster-assignment-equal to the
+padded/ragged batches of the jitted feature extractor must match the
+numpy oracle bitwise, the batched masked k-means must assign exactly as
+the single-problem fit (centres within tolerance), and the flat-segmented
+fit engine must stay cluster-assignment-equal to the
 bucketed oracle over random ragged layer sets.
 (Whole module skips where hypothesis is absent; CI installs it.)"""
 import jax
@@ -14,8 +15,8 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import kmeans as km, lern  # noqa: E402
 from repro.core.tracegen import Trace  # noqa: E402
-from test_lern_batched import (_assert_labels_equal,  # noqa: E402
-                               _features_match_oracle)
+from test_lern_batched import (CENTER_ATOL, CENTER_RTOL,  # noqa: E402
+                               _assert_labels_equal, _features_match_oracle)
 
 
 @settings(max_examples=30, deadline=None)
@@ -30,8 +31,10 @@ def test_features_property_padding_invariant(lines, pad):
 @given(st.lists(st.integers(2, 48), min_size=1, max_size=4),
        st.integers(0, 2 ** 31 - 1))
 def test_batched_kmeans_matches_single(sizes, seed):
-    """Each row of the vmapped fit is bitwise the single masked fit at the
-    same padded shape, for ragged point counts."""
+    """Each row of the vmapped fit assigns every valid point exactly as
+    the single masked fit at the same padded shape does, for ragged point
+    counts; centres agree within the label-equality contract's tolerance
+    (vmap may reassociate float sums — a few ulp under jax 0.9 CPU)."""
     rng = np.random.default_rng(seed)
     cap = max(sizes)
     b = len(sizes)
@@ -47,8 +50,9 @@ def test_batched_kmeans_matches_single(sizes, seed):
     for i in range(b):
         rs = km.kmeans_fit_masked(jnp.asarray(x[i]), jnp.asarray(mask[i]),
                                   keys[i], k=4, iters=8)
-        np.testing.assert_array_equal(np.asarray(rs.centers),
-                                      np.asarray(rb.centers[i]))
+        np.testing.assert_allclose(np.asarray(rs.centers),
+                                   np.asarray(rb.centers[i]),
+                                   rtol=CENTER_RTOL, atol=CENTER_ATOL)
         np.testing.assert_array_equal(np.asarray(rs.assign)[mask[i]],
                                       np.asarray(rb.assign[i])[mask[i]])
 

@@ -4,14 +4,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.configs import ARCHS
 from repro.sharding import rules
-from repro.sharding.compat import abstract_mesh
 from repro.train import step as step_mod
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
