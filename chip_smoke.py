@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -70,26 +71,57 @@ LERN_DIGEST = "0f513d5fce5120b84e505d65610328a4878270dfadbe55c519a885a0aa21a8dc"
 
 class CompileClock:
     """Seconds JAX spent tracing, lowering and compiling (or fetching from
-    the persistent cache), and persistent-cache hits, from JAX's own
-    monitoring events."""
+    the persistent cache), backend compiles and persistent-cache hits, from
+    JAX's own monitoring events.
+
+    A nested jit's trace is reported inside its parent's, so the durations
+    overlap: the clock measures the union of the reported intervals, which
+    never exceeds the wall time it is read over."""
     DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
                  "/jax/core/compile/backend_compile_duration")
 
     def __init__(self):
         import jax
-        self.seconds = 0.0
+        self._spans = []              # (start, end) on time.perf_counter()
+        self.compiles = 0             # backend compiles, cache fetches included
         self.cache_hits = 0
         jax.monitoring.register_event_duration_secs_listener(self._dur)
         jax.monitoring.register_event_listener(self._event)
 
     def _dur(self, event, duration, **_):
+        # JAX reports a duration when the timed block ends
         if event in self.DURATIONS:
-            self.seconds += duration
+            end = time.perf_counter()
+            self._spans.append((end - duration, end))
+            self.compiles += event == self.DURATIONS[2]
 
     def _event(self, event, **_):
         if event == "/jax/compilation_cache/cache_hits":
             self.cache_hits += 1
+
+    def seconds(self, since: float) -> float:
+        """Compile-side seconds after ``since`` (a perf_counter reading)."""
+        total, cur = 0.0, None
+        for s, e in sorted((max(s, since), e) for s, e in self._spans
+                           if e > since):
+            if cur is not None and s <= cur[1]:
+                cur[1] = max(cur[1], e)
+                continue
+            if cur is not None:
+                total += cur[1] - cur[0]
+            cur = [s, e]
+        return total + (cur[1] - cur[0] if cur is not None else 0.0)
+
+    def mark(self):
+        return time.perf_counter(), self.compiles, self.cache_hits
+
+    def since(self, mark) -> dict:
+        t0, n0, h0 = mark
+        return {"wall_s": time.perf_counter() - t0,
+                "compile_s": self.seconds(t0),
+                "backend_compiles": self.compiles - n0,
+                "cache_hits": self.cache_hits - h0}
 
 
 def _peak_device_bytes():
@@ -101,16 +133,12 @@ def _peak_device_bytes():
 def _run_phase(name, fn, clock, **kw) -> bool:
     """Run one phase and print its line (timings, or the error); return
     whether it passed.  A failed phase does not stop the later ones."""
-    c0, h0 = clock.seconds, clock.cache_hits
-    t0 = time.perf_counter()
+    mark = clock.mark()
     try:
         out, err = fn(**kw), None
     except Exception as e:  # reported, and the run fails at the end
         out, err = {}, f"{type(e).__name__}: {e}"
-    wall = time.perf_counter() - t0
-    line = {"phase": name, "ok": err is None, "wall_s": wall,
-            "compile_s": clock.seconds - c0,
-            "cache_hits": clock.cache_hits - h0,
+    line = {"phase": name, "ok": err is None, **clock.since(mark),
             "peak_device_bytes": _peak_device_bytes()}
     line.update(out.get("timing", {}))
     if err is not None:
@@ -314,12 +342,29 @@ def serve_phase(sessions=SERVE_SESSIONS):
     return {"timing": timing}
 
 
-def shard_phase(params, n_devices):
+def shard_phase(params, n_devices, clock=None):
     """One bucket of four groups sharded over ``n_devices`` chips against
-    the same bucket on one device: every group bitwise equal."""
+    the same bucket on one device: every group bitwise equal.  With a
+    ``clock`` it prints one progress line after the set-up and after each
+    leg."""
     from repro import exp
-    from repro.core import fused
+    from repro.core import fused, sim
+    from repro.core.dram import default_model
 
+    def progress(step, mark):
+        if clock is not None:
+            print(json.dumps({"shard_step": step, **clock.since(mark)}),
+                  flush=True)
+
+    mark = clock.mark() if clock else None
+    t0 = time.perf_counter()
+    for config in SHARD_CONFIGS:
+        sim.load_artifacts(config, "moti1", params, True)
+        sim.calibrated_deadline(config, params, default_model())
+    sim.load_lern_family(list(SHARD_CONFIGS), "full",
+                         params.subsample_target, family_only=True)
+    timing = {"setup_s": time.perf_counter() - t0}
+    progress("setup", mark)
     spec = exp.ExperimentSpec.grid(config=list(SHARD_CONFIGS), mix="moti1",
                                    policy=list(ORACLE_POLICIES),
                                    params=params)
@@ -336,14 +381,15 @@ def shard_phase(params, n_devices):
         return carry, ys
 
     runs = {}
-    timing = {}
     fused._superstep_bucket = spy
     try:
         for dev in (n_devices, 1):
+            mark = clock.mark() if clock else None
             t0 = time.perf_counter()
             runs[dev] = exp.run(spec, plan=exp.ExecPlan(
                 engine="bucketed", devices=dev, cache=False))
             timing[f"devices{dev}_s"] = time.perf_counter() - t0
+            progress(f"devices{dev}", mark)
     finally:
         fused._superstep_bucket = orig
     assert len(placement) == len(SHARD_CONFIGS), \
@@ -390,6 +436,7 @@ def main(argv=None) -> int:
         return 2
     _fresh_cache()                       # before repro reads REPRO_CACHE
     sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
+    from repro.core import sim  # noqa: F401  (places the compile cache)
     from repro.kernels import common
     if common.INTERPRET is not False:
         print("chip_smoke: Pallas kernels would run in interpret mode",
@@ -399,11 +446,14 @@ def main(argv=None) -> int:
     clock = CompileClock()
     kind = devices[0].device_kind
     print(json.dumps({"platform": devices[0].platform, "kind": kind,
-                      "count": len(devices), "jax": jax.__version__}),
+                      "count": len(devices), "jax": jax.__version__,
+                      "compile_cache_dir":
+                          jax.config.jax_compilation_cache_dir}),
           flush=True)
     if args.chips == 4:
-        ok = _run_phase("shard", shard_phase, clock,
-                        params=_params(max_epochs=ORACLE_EPOCHS), n_devices=4)
+        ok = _run_phase("shard", functools.partial(shard_phase, clock=clock),
+                        clock, params=_params(max_epochs=ORACLE_EPOCHS),
+                        n_devices=4)
     else:
         full = _params()
         ok = all([

@@ -45,10 +45,20 @@ class SegmentedKMeansResult(NamedTuple):
 # kernel that depends on it: repro.kernels.common.SEG_BLOCK).
 from repro.kernels.common import SEG_BLOCK  # noqa: E402
 
-# Full-f32 contraction for every k-means dot: a TPU's default precision
-# rounds f32 operands to bf16, which moves centres and flips labels (RC
-# counts above 256 are not bf16-exact).  The CPU computes f32 either way.
+# Full-f32 contraction for the centre sums (``one_hot.T @ x``): a TPU's
+# default precision rounds f32 operands to bf16, which moves centres and
+# flips labels (RC counts above 256 are not bf16-exact).  The CPU computes
+# f32 either way.
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _xc(x: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
+    """x [N, D] . c ([K, D], or [N, K, D] per point) -> [N, K], as an
+    elementwise product summed over the feature axis: the same f32
+    rounding in every fit engine.  A dot or einsum orders its sums by its
+    own lowering, and integer features make exact distance ties common —
+    the bucketed and segmented engines then broke them differently."""
+    return jnp.sum(x[:, None, :] * c, -1)
 
 
 def assign_jnp(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
@@ -56,7 +66,7 @@ def assign_jnp(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
     row-constant ||x||^2 term is dropped from the argmin — exactly the
     decomposition the Pallas kernel computes, so both paths agree)."""
     c2 = jnp.sum(centers * centers, -1)
-    d2 = c2[None, :] - 2.0 * jnp.dot(x, centers.T, precision=_HIGHEST)
+    d2 = c2[None, :] - 2.0 * _xc(x, centers)
     return jnp.argmin(d2, axis=1).astype(jnp.int32)
 
 
@@ -129,12 +139,11 @@ def kmeans_fit_masked(x: jnp.ndarray, mask: jnp.ndarray, key: jnp.ndarray,
 
     def step(carry, _):
         centers = carry
-        # scores via the matmul decomposition (no [N, K, D] broadcast):
+        # scores via the product decomposition:
         # d2 = ||x||^2 - 2 x.c + ||c||^2; the ||x||^2 term only matters for
         # the farthest-point reseed, not the argmin
         c2 = jnp.sum(centers * centers, -1)
-        sc = c2[None, :] - 2.0 * jnp.dot(x, centers.T,
-                                         precision=_HIGHEST)  # [N, K]
+        sc = c2[None, :] - 2.0 * _xc(x, centers)            # [N, K]
         if use_kernel:
             a = assign_fn(x, centers)
         else:
@@ -237,7 +246,7 @@ def assign_segmented_jnp(x: jnp.ndarray, centers: jnp.ndarray,
     segc = jnp.minimum(seg, centers.shape[0] - 1)
     cg = centers[segc]                              # [P, K, D]
     c2 = jnp.sum(cg * cg, -1)                       # [P, K]
-    d2 = c2 - 2.0 * jnp.einsum("pd,pkd->pk", x, cg, precision=_HIGHEST)
+    d2 = c2 - 2.0 * _xc(x, cg)
     return jnp.argmin(d2, axis=1).astype(jnp.int32)
 
 
@@ -279,8 +288,7 @@ def _lloyd_segmented(x: jnp.ndarray, seg: jnp.ndarray,
         else:
             cg = centers[segc]                       # [P, K, D]
             c2 = jnp.sum(cg * cg, -1)
-            sc = c2 - 2.0 * jnp.einsum("pd,pkd->pk", x, cg,
-                                       precision=_HIGHEST)
+            sc = c2 - 2.0 * _xc(x, cg)
             a = jnp.argmin(sc, axis=1).astype(jnp.int32)
             min_sc = jnp.min(sc, 1)
         # two-stage segment reduction: dense per-block partial sums (the

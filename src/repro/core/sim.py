@@ -51,8 +51,8 @@ CACHE_DIR = os.environ.get("REPRO_CACHE", _CHECKOUT_CACHE)
 # Persistent XLA compilation cache: the round-engine compiles once per
 # round-bucket; share them across benchmark processes.  An outside
 # JAX_COMPILATION_CACHE_DIR (which JAX reads itself) places it; otherwise
-# it sits at a fixed path in the checkout.  The directory is part of
-# every entry's key, so it deliberately does not follow REPRO_CACHE.
+# it sits at a fixed path in the checkout.  It deliberately does not
+# follow REPRO_CACHE: a fresh artifact cache keeps the compiled programs.
 XLA_CACHE_DIR = os.path.join(_CHECKOUT_CACHE, "xla")
 if os.environ.get("REPRO_JIT_CACHE", "1") == "1":
     import jax

@@ -65,3 +65,25 @@ def test_lern_phase_reproduces_committed_cpu_digest(fresh_cache):
                                 expect_digest=chip_smoke.LERN_DIGEST,
                                 require_kernel=False)
     assert out["digest"] == chip_smoke.LERN_DIGEST
+
+
+def test_compile_clock_counts_overlapping_spans_once():
+    """Nested traces report durations inside their parent's: the clock
+    takes the union of the spans, so compile seconds never exceed wall."""
+    import jax
+    import jax.numpy as jnp
+    clock = chip_smoke.CompileClock()
+    clock._spans[:] = [(0.0, 10.0), (2.0, 5.0), (8.0, 12.0), (20.0, 21.0)]
+    assert clock.seconds(0.0) == 13.0
+    assert clock.seconds(9.0) == 4.0
+    clock._spans.clear()
+    mark = clock.mark()
+
+    @jax.jit
+    def outer(x):
+        return jax.jit(lambda y: y * 3)(x) + 1
+
+    outer(jnp.arange(5.0)).block_until_ready()
+    t = clock.since(mark)
+    assert 0.0 < t["compile_s"] <= t["wall_s"]
+    assert t["backend_compiles"] >= 1
