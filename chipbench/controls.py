@@ -1,0 +1,164 @@
+#!/usr/bin/env python3
+"""Readings that the limits of ``correct`` are set from, for one cell, in
+one process (the set-up is paid once):
+
+- sound: the program's own answers on ``--seeds`` seeds, each compared
+  with the reference exactly as a run compares them;
+- control: ``--controls`` seeds on which the reference itself, computed in
+  the precision below the one the configuration states, is put in the
+  program's place: a plain k-means fitted in bfloat16 (the fit states
+  float32).
+
+    python3 chipbench/controls.py --workload <cell> --seed <n> [--seeds 12] [--controls 3]
+
+Prints one JSON line per reading and, last, the largest sound and the
+smallest control reading of each number.  The benchmark's runs never run
+this; it needs the chip like a run does.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+from types import SimpleNamespace
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from chipbench import common  # noqa: E402
+
+
+def _plus_plus(x: np.ndarray, k: int, rng) -> np.ndarray:
+    c = [x[rng.integers(x.shape[0])]]
+    for _ in range(1, k):
+        d = np.min(((x[:, None, :] - np.array(c)[None]) ** 2).sum(-1), 1)
+        p = d / d.sum() if d.sum() > 0 else None
+        c.append(x[rng.choice(x.shape[0], p=p)])
+    return np.array(c)
+
+
+def kmeans_low(x: np.ndarray, seed: int, dtype, k: int = 4,
+               iters: int = 50):
+    """Plain Lloyd k-means computed in ``dtype`` (points, centres,
+    distances and means); returns (each point's cluster, the centres)."""
+    import jax.numpy as jnp
+    xs = jnp.asarray(x, dtype)
+    c = jnp.asarray(_plus_plus(x, k, np.random.default_rng(seed)), dtype)
+    a = None
+    for _ in range(iters):
+        d = ((xs[:, None, :] - c[None]) ** 2).sum(-1)
+        a = jnp.argmin(d, 1)
+        oh = (a[:, None] == jnp.arange(k)[None]).astype(dtype)
+        cnt = oh.sum(0)
+        new = jnp.where(cnt[:, None] > 0,
+                        (oh.T @ xs) / jnp.maximum(cnt, 1)[:, None], c)
+        if bool(jnp.all(new == c)):
+            break
+        c = new
+    return np.asarray(a), c
+
+
+def lern_control_model(ref, seed: int, dtype):
+    """A model with the reference's feature tables and labels fitted by
+    ``kmeans_low`` in ``dtype``, annotated in the paper's label order."""
+    import jax.numpy as jnp
+    from chipbench.reference.compare import _expected_bin
+    n_l = len(ref.layers)
+    n_tab = max(u.size for u, _, _ in ref.layers)
+    uniq = np.full((n_l, n_tab), -1, np.int64)
+    rc_t = np.full((n_l, n_tab), -1, np.int64)
+    ri_t = np.full((n_l, n_tab), -1, np.int64)
+    rc_c = np.zeros((n_l, 4))
+    ri_c = np.zeros((n_l, 4, 4))
+    feats, n_uniq = [], []
+    for li, (u, f_ri, count) in enumerate(ref.layers):
+        n = u.size
+        uniq[li, :n] = u
+        n_uniq.append(n)
+        multi = count > 1
+        feats.append(f_ri[multi])
+        if multi.sum() < ref.MIN_MULTI:
+            continue
+        xrc = np.log1p(count[multi].astype(np.float64))
+        xrc = ((xrc - xrc.min()) / max(xrc.max() - xrc.min(), 1e-9))[:, None]
+        raw = f_ri[multi].astype(np.float64)
+        xri = raw / np.maximum(raw.sum(1, keepdims=True), 1e-9)
+        lo, hi = np.log1p(count[multi].min()), np.log1p(count[multi].max())
+        a, c = kmeans_low(xrc, seed + 2 * li, dtype)
+        cm = np.array([xrc[a == j, 0].mean() if (a == j).any() else np.inf
+                       for j in range(4)])
+        rank = np.empty(4, np.int64)
+        rank[np.argsort(cm)] = np.arange(4)
+        rc_t[li, :n][multi] = rank[a]
+        # de-normalised in the same precision
+        den = jnp.expm1(c[:, 0] * jnp.asarray(hi - lo, dtype)
+                        + jnp.asarray(lo, dtype))
+        rc_c[li, rank] = np.asarray(den, np.float64)
+        a, _ = kmeans_low(xri, seed + 2 * li + 1, dtype)
+        eb = np.nan_to_num(_expected_bin(raw, a), nan=np.inf)
+        rank[np.argsort(eb)] = np.arange(4)
+        ri_t[li, :n][multi] = rank[a]
+        rw = jnp.asarray(raw, dtype)
+        for j in range(4):
+            m = a == j
+            if m.any():
+                ri_c[li, rank[j]] = np.asarray(
+                    rw[m].sum(0) / jnp.asarray(m.sum(), dtype), np.float64)
+    return SimpleNamespace(uniq=uniq, rc_cluster=rc_t, ri_cluster=ri_t,
+                           n_uniq=np.asarray(n_uniq), features_ri=feats,
+                           rc_centers=rc_c, ri_centers=ri_c)
+
+
+def lern_readings(job, seeds, controls, emit):
+    import jax.numpy as jnp
+    from chipbench.reference.compare import LernReference
+    tr = job.trace
+    lines = np.asarray(tr.line, np.int64)
+    if job.hash_fn is not None:
+        lines = job.hash_fn(lines)
+    ref = LernReference(lines, np.asarray(tr.layer),
+                        max(len(tr.layer_names), 1))
+    for i in range(seeds):
+        seed = common.derive_seed(job.seed, i)
+        emit("sound", seed, ref.compare(job._train(seed)))
+    for i in range(controls):
+        seed = common.derive_seed(job.seed, 500_000 + i)
+        emit("control", seed,
+             ref.compare(lern_control_model(ref, seed, jnp.bfloat16)))
+
+
+def main(argv=None) -> dict:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seeds", type=int, default=12)
+    ap.add_argument("--controls", type=int, default=3)
+    args = ap.parse_args(argv)
+    from chipbench import run
+    run._environment()
+    c = common.cell(args.workload)
+    run.device_info(c["workload"]["chips"])
+    job = common.load_module("jobs", c["traffic"]["job"] + ".py").Job(
+        c["config"], c["traffic"], args.seed)
+    job.setup()
+    out = {"sound": {}, "control": {}}
+
+    def emit(kind, seed, numbers, **extra):
+        print(json.dumps({"kind": kind, "seed": seed, **numbers, **extra}),
+              flush=True)
+        agg = out[kind]
+        for k, v in numbers.items():
+            agg[k] = (max if kind == "sound" else min)(agg.get(k, v), v)
+
+    lern_readings(job, args.seeds, args.controls, emit)
+    print(json.dumps({"largest_sound": out["sound"],
+                      "smallest_control": out["control"]}), flush=True)
+    return out
+
+
+if __name__ == "__main__":
+    main()
