@@ -52,6 +52,7 @@ groups here.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -95,9 +96,22 @@ PIPELINE_DEFAULT = os.environ.get("REPRO_BUCKET_PIPELINE", "1") != "0"
 # stage_s (host->device staging + carry init), dispatch_s (tracing,
 # compilation and enqueue of super-steps), device_s (blocked fetching
 # StepOut), writeback_s (host history/carry sync).  bench_sim resets
-# before a leg and reports the split per kind="sweep" entry.
+# before a leg and reports the split per kind="sweep" entry.  Each timed
+# block is also a profiler span (``_SPANS``), on the device trace's clock.
 _PHASES = {"stage_s": 0.0, "dispatch_s": 0.0, "device_s": 0.0,
            "writeback_s": 0.0}
+_SPANS = {"stage_s": "fused.stage", "dispatch_s": "fused.dispatch",
+          "device_s": "fused.device_wait", "writeback_s": "fused.writeback"}
+
+
+@contextlib.contextmanager
+def _phase(key: str):
+    """Add the block's wall time to ``_PHASES[key]`` and trace it as the
+    span ``_SPANS[key]``."""
+    t = time.perf_counter()
+    with jax.profiler.TraceAnnotation(_SPANS[key]):
+        yield
+    _PHASES[key] += time.perf_counter() - t
 
 
 def reset_phase_times() -> None:
@@ -1220,10 +1234,8 @@ def stage_group(lanes: List[Lane], k_epochs: int = DEFAULT_SUPERSTEP,
                 pads: Optional[Tuple[int, int, int]] = None) -> _Staged:
     """Build one group's staged device constants (the unit sweep's
     staging cache holds); time lands in the stage_s phase bucket."""
-    t0 = time.perf_counter()
-    with jax.enable_x64():
+    with _phase("stage_s"), jax.enable_x64():
         staged = _Staged(lanes, k_epochs, max_rounds, pads=pads)
-    _PHASES["stage_s"] += time.perf_counter() - t0
     return staged
 
 
@@ -1675,9 +1687,8 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
         pads = bucket_pads(groups)
         staged = [stage_group(g, k_epochs, max_rounds, pads=pads)
                   for g in groups]
-    t0 = time.perf_counter()
     dims = staged[0].dims
-    with jax.enable_x64():
+    with _phase("stage_s"), jax.enable_x64():
         # Groups in one bucket agree on every static field except the
         # incidental choice of lane0's LLCConfig for ``cfg`` — behaviour
         # knobs ride as LaneKnobs data, so only geometry_key must match
@@ -1695,7 +1706,6 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
                       for _ in groups]
         carry = _stack_trees([_init_carry(g, st, dims.n_inputs)
                               for g, st in zip(groups, states)])
-    _PHASES["stage_s"] += time.perf_counter() - t0
     n_dev = devices if devices else len(jax.devices())
     n_shards = n_dev if (n_dev > 1 and n_groups % n_dev == 0) else 1
     if n_shards > 1:
@@ -1744,8 +1754,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
         nonlocal carry
         stops = [next_stop(i) for i in range(n_groups)]
         before = [list(e) for e in epochs]
-        t = time.perf_counter()
-        with jax.enable_x64():
+        with _phase("dispatch_s"), jax.enable_x64():
             step = _superstep_bucket_donated if donate else _superstep_bucket
             stop_g = jnp.asarray(stops, jnp.int64)
             if n_shards > 1:
@@ -1756,7 +1765,6 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
             carry, ys = step(dims, n_shards, sh_g, lc_g, carry, stop_g)
             for leaf in jax.tree.leaves(ys):
                 leaf.copy_to_host_async()
-        _PHASES["dispatch_s"] += time.perf_counter() - t
         return ys, before
 
     inflight: list = []
@@ -1825,25 +1833,23 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
             overflow_pending.clear()
             continue
         ys, before = inflight.pop(0)
-        t = time.perf_counter()
-        host_ys = _to_host(ys, _STEP_FLOATS)
-        _PHASES["device_s"] += time.perf_counter() - t
-        t = time.perf_counter()
-        for i in range(n_groups):
-            if not live[i]:
-                continue
-            y_i = jax.tree.map(lambda y: y[:, i], host_ys)
-            _write_back_steps(groups[i], y_i)
-            for j in range(dims.n_lanes):
-                epochs[i][j] += int(y_i.active[:, j].sum())
-                alive[i][j] = bool(y_i.alive[-1, j])
-                r = groups[i][j]._retrain_every
-                if (r is not None and epochs[i][j] > before[i][j]
-                        and epochs[i][j] % r == 0):
-                    due[i].add(j)
-            if y_i.ovf[-1].any():
-                overflow_pending.add(i)
-        _PHASES["writeback_s"] += time.perf_counter() - t
+        with _phase("device_s"):
+            host_ys = _to_host(ys, _STEP_FLOATS)
+        with _phase("writeback_s"):
+            for i in range(n_groups):
+                if not live[i]:
+                    continue
+                y_i = jax.tree.map(lambda y: y[:, i], host_ys)
+                _write_back_steps(groups[i], y_i)
+                for j in range(dims.n_lanes):
+                    epochs[i][j] += int(y_i.active[:, j].sum())
+                    alive[i][j] = bool(y_i.alive[-1, j])
+                    r = groups[i][j]._retrain_every
+                    if (r is not None and epochs[i][j] > before[i][j]
+                            and epochs[i][j] % r == 0):
+                        due[i].add(j)
+                if y_i.ovf[-1].any():
+                    overflow_pending.add(i)
         # online-LERN boundaries land at the super-step edge per group
         # (next_stop): run the host refit hooks and re-upload that
         # group's tables into its slot of the stacked constants.  A
@@ -1855,20 +1861,17 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
             for j in sorted(due[i]):
                 groups[i][j]._online_retrain()
             due[i].clear()
-            t = time.perf_counter()
-            with jax.enable_x64():
+            with _phase("stage_s"), jax.enable_x64():
                 staged[i].refresh_clusters(groups[i])
                 lc_g = jax.tree.map(
                     lambda full, new: full.at[i].set(new),
                     lc_g, staged[i].lc)
-            _PHASES["stage_s"] += time.perf_counter() - t
     # one final scalar sync per lane — everything epoch-by-epoch already
     # landed via _write_back_steps, and demoted groups were synced at
     # demotion (then driven to completion by the per-group driver)
-    t = time.perf_counter()
-    host_c = _to_host(carry._replace(st=None), _CARRY_FLOATS)
-    for i in range(n_groups):
-        if live[i]:
-            _write_back_carry(groups[i],
-                              jax.tree.map(lambda x: x[i], host_c))
-    _PHASES["writeback_s"] += time.perf_counter() - t
+    with _phase("writeback_s"):
+        host_c = _to_host(carry._replace(st=None), _CARRY_FLOATS)
+        for i in range(n_groups):
+            if live[i]:
+                _write_back_carry(groups[i],
+                                  jax.tree.map(lambda x: x[i], host_c))

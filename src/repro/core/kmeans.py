@@ -349,7 +349,8 @@ def kmeans_fit_segmented(x: jnp.ndarray, seg: jnp.ndarray,
     settles most segments at their (bitwise) Lloyd fixed point, then the
     host compacts the unconverged segments' rows — block-aligned, so their
     FP trajectory is untouched — and only those re-dispatch for the
-    remaining sweeps.  Seeding and update math mirror
+    remaining sweeps, under the profiler span ``kmeans.stragglers``
+    (present only when it runs).  Seeding and update math mirror
     ``kmeans_fit_masked`` per segment, so the result is
     cluster-assignment-equal to the bucketed oracle (same labels up to
     centroid permutation; centroids agree to FP reassociation).
@@ -375,28 +376,29 @@ def kmeans_fit_segmented(x: jnp.ndarray, seg: jnp.ndarray,
     total = int(n1)
     conv_np = np.asarray(conv)
     if it1 < iters and not conv_np.all():
-        # compact the stragglers: copy each unconverged segment's padded
-        # block run verbatim (block-aligned → bitwise-identical sweeps)
-        stragglers = np.flatnonzero(~conv_np)
-        xh = np.asarray(x)
-        counts = np.asarray(seg_cnt)[stragglers]
-        sub_off, sub_total = segment_layout(counts)
-        n_sub = stragglers.shape[0]
-        sub_p = max(((sub_total + 2047) // 2048) * 2048, SEG_BLOCK)
-        xs = np.zeros((sub_p, xh.shape[1]), xh.dtype)
-        segs = np.full(sub_p, n_sub, np.int32)
-        for si, s in enumerate(stragglers):
-            run = ((int(counts[si]) + SEG_BLOCK - 1)
-                   // SEG_BLOCK) * SEG_BLOCK
-            o = int(np.asarray(seg_off)[s])
-            xs[sub_off[si]:sub_off[si] + run] = xh[o:o + run]
-            segs[sub_off[si]:sub_off[si] + int(counts[si])] = si
-        sub_centers, n2, _ = _lloyd_segmented(
-            jnp.asarray(xs), jnp.asarray(segs),
-            jnp.asarray(np.asarray(centers)[stragglers]),
-            n_sub, k, iters - it1, use_kernel)
-        total += int(n2)
-        centers = centers.at[jnp.asarray(stragglers)].set(sub_centers)
+        with jax.profiler.TraceAnnotation("kmeans.stragglers"):
+            # compact the stragglers: copy each unconverged segment's padded
+            # block run verbatim (block-aligned → bitwise-identical sweeps)
+            stragglers = np.flatnonzero(~conv_np)
+            xh = np.asarray(x)
+            counts = np.asarray(seg_cnt)[stragglers]
+            sub_off, sub_total = segment_layout(counts)
+            n_sub = stragglers.shape[0]
+            sub_p = max(((sub_total + 2047) // 2048) * 2048, SEG_BLOCK)
+            xs = np.zeros((sub_p, xh.shape[1]), xh.dtype)
+            segs = np.full(sub_p, n_sub, np.int32)
+            for si, s in enumerate(stragglers):
+                run = ((int(counts[si]) + SEG_BLOCK - 1)
+                       // SEG_BLOCK) * SEG_BLOCK
+                o = int(np.asarray(seg_off)[s])
+                xs[sub_off[si]:sub_off[si] + run] = xh[o:o + run]
+                segs[sub_off[si]:sub_off[si] + int(counts[si])] = si
+            sub_centers, n2, _ = _lloyd_segmented(
+                jnp.asarray(xs), jnp.asarray(segs),
+                jnp.asarray(np.asarray(centers)[stragglers]),
+                n_sub, k, iters - it1, use_kernel)
+            total += int(n2)
+            centers = centers.at[jnp.asarray(stragglers)].set(sub_centers)
     if use_kernel:
         from repro.kernels.kmeans_assign import ops as _kops
         a = _kops.assign_segmented(x, centers, seg)

@@ -481,31 +481,31 @@ def _extract_flat(lines_all: np.ndarray, layer_all: np.ndarray, n_l: int):
     return uniq_f, f_ri_f, f_rc_f, n_uniq, offs, per_layer, elig
 
 
-def _fit_flat(lines_all: np.ndarray, layer_all: np.ndarray, n_l: int,
-              key_seeds: List[int], use_kernel: Optional[bool],
+def _fit_flat(extracted, key_seeds: List[int], use_kernel: Optional[bool],
               fit_engine: Optional[str] = None):
-    """Shared flat-trace fit core of the batched trainers.
+    """Shared fit core of the batched trainers, under the ``lern.fit``
+    span.
 
-    One ``reuse_features_flat`` extraction over the concatenated trace
-    (``layer_all`` non-decreasing, 0..n_l-1), then every eligible layer's
-    k-means fits in one device dispatch — either the padded capacity-bucket
-    path (``_fit_groups``, the oracle) or the flat-segmented path
+    From ``_extract_flat``'s result (the flat trace's per-layer feature
+    tables and eligibility), every eligible layer's k-means fits
+    in one device dispatch — either the padded capacity-bucket path
+    (``_fit_groups``, the oracle) or the flat-segmented path
     (``_fit_segmented``) per ``fit_engine``; ``key_seeds[li]`` seeds layer
     li's k-means draws either way.  Returns everything the assembly step
     needs: (uniq_f, f_ri_f, f_rc_f, n_uniq, offs, per_layer, layer_fits)
     where ``layer_fits[li]`` is the host-side fit dict ``_annotate``
     consumes (absent for ineligible layers)."""
     engine = resolve_engine(fit_engine)
-    uniq_f, f_ri_f, f_rc_f, n_uniq, offs, per_layer, elig = \
-        _extract_flat(lines_all, layer_all, n_l)
-
-    # --- device program 2: all fits in one jitted call ---------------------
-    if engine == "segmented":
-        layer_fits = _fit_flat_segmented(f_ri_f, f_rc_f, offs, per_layer,
-                                         elig, key_seeds, use_kernel)
-    else:
-        layer_fits = _fit_flat_bucketed(f_ri_f, f_rc_f, offs, per_layer,
-                                        elig, key_seeds, use_kernel)
+    uniq_f, f_ri_f, f_rc_f, n_uniq, offs, per_layer, elig = extracted
+    with jax.profiler.TraceAnnotation("lern.fit"):
+        # --- device program 2: all fits in one jitted call -----------------
+        if engine == "segmented":
+            layer_fits = _fit_flat_segmented(f_ri_f, f_rc_f, offs,
+                                             per_layer, elig, key_seeds,
+                                             use_kernel)
+        else:
+            layer_fits = _fit_flat_bucketed(f_ri_f, f_rc_f, offs, per_layer,
+                                            elig, key_seeds, use_kernel)
     return uniq_f, f_ri_f, f_rc_f, n_uniq, offs, per_layer, layer_fits
 
 
@@ -583,33 +583,35 @@ def _fit_flat_segmented(f_ri_f, f_rc_f, offs, per_layer, elig, key_seeds,
 
 def _assemble(flat, lo: int, hi: int,
               hash_fn: Optional[Callable]) -> LernModel:
-    """Build the LernModel for layer range [lo, hi) of a flat fit."""
-    uniq_f, f_ri_f, f_rc_f, n_uniq_all, offs, per_layer, layer_fits = flat
-    n_l = hi - lo
-    n_uniq = n_uniq_all[lo:hi]
-    n_tab = _bucket(int(n_uniq.max(initial=1)))
-    uniq = np.full((n_l, n_tab), int(PAD_LINE), np.int64)
-    rc = np.full((n_l, n_tab), -1, np.int8)
-    ri = np.full((n_l, n_tab), -1, np.int8)
-    rc_c = np.zeros((n_l, 4), np.float32)
-    ri_c = np.zeros((n_l, 4, NUM_RI_BINS), np.float32)
-    features: List[np.ndarray] = []
-    for li in range(lo, hi):
-        k = li - lo
-        nu = int(n_uniq_all[li])
-        multi, nm = per_layer[li]
-        sl = slice(offs[li], offs[li + 1])
-        uniq[k, :nu] = uniq_f[sl]
-        features.append(f_ri_f[sl][multi].astype(np.int64))
-        if li not in layer_fits:
-            continue
-        ann = _annotate(layer_fits[li], nm)
-        rc[k, :nu][multi] = ann["rc_label"].astype(np.int8)
-        ri[k, :nu][multi] = ann["ri_label"].astype(np.int8)
-        rc_c[k], ri_c[k] = ann["rc_centers"], ann["ri_centers"]
-    return LernModel(uniq=uniq, rc_cluster=rc, ri_cluster=ri,
-                     n_uniq=n_uniq, rc_centers=rc_c, ri_centers=ri_c,
-                     features_ri=features, hash_fn=hash_fn)
+    """Build the LernModel for layer range [lo, hi) of a flat fit (host
+    annotation and tables, under the ``lern.assemble`` span)."""
+    with jax.profiler.TraceAnnotation("lern.assemble"):
+        uniq_f, f_ri_f, f_rc_f, n_uniq_all, offs, per_layer, layer_fits = flat
+        n_l = hi - lo
+        n_uniq = n_uniq_all[lo:hi]
+        n_tab = _bucket(int(n_uniq.max(initial=1)))
+        uniq = np.full((n_l, n_tab), int(PAD_LINE), np.int64)
+        rc = np.full((n_l, n_tab), -1, np.int8)
+        ri = np.full((n_l, n_tab), -1, np.int8)
+        rc_c = np.zeros((n_l, 4), np.float32)
+        ri_c = np.zeros((n_l, 4, NUM_RI_BINS), np.float32)
+        features: List[np.ndarray] = []
+        for li in range(lo, hi):
+            k = li - lo
+            nu = int(n_uniq_all[li])
+            multi, nm = per_layer[li]
+            sl = slice(offs[li], offs[li + 1])
+            uniq[k, :nu] = uniq_f[sl]
+            features.append(f_ri_f[sl][multi].astype(np.int64))
+            if li not in layer_fits:
+                continue
+            ann = _annotate(layer_fits[li], nm)
+            rc[k, :nu][multi] = ann["rc_label"].astype(np.int8)
+            ri[k, :nu][multi] = ann["ri_label"].astype(np.int8)
+            rc_c[k], ri_c[k] = ann["rc_centers"], ann["ri_centers"]
+        return LernModel(uniq=uniq, rc_cluster=rc, ri_cluster=ri,
+                         n_uniq=n_uniq, rc_centers=rc_c, ri_centers=ri_c,
+                         features_ri=features, hash_fn=hash_fn)
 
 
 def _layer_sorted(trace: Trace):
@@ -642,15 +644,21 @@ def train_model_batched(trace: Trace, hash_fn: Optional[Callable] = None,
     it is label-equal to ``train`` (the float pipeline is the shared
     ``_fit_layer`` at identical padded shapes); the default segmented
     engine is cluster-assignment-equal to that oracle (same label tables,
-    centers to FP reassociation) with no capacity padding."""
-    lines_all, layer_all = _layer_sorted(trace)
-    if hash_fn is not None:
-        lines_all = hash_fn(lines_all)
-    n_l = max(len(trace.layer_names), 1)
-    flat = _fit_flat(lines_all, layer_all, n_l,
-                     [seed + li for li in range(n_l)], use_kernel,
-                     fit_engine)
-    return _assemble(flat, 0, n_l, hash_fn)
+    centers to FP reassociation) with no capacity padding.
+
+    Profiler spans: ``lern.train`` around the whole training, tiled by
+    ``lern.extract`` (host sort and padding, program 1 and its read-back),
+    ``lern.fit`` (``_fit_flat``) and ``lern.assemble`` (``_assemble``)."""
+    with jax.profiler.TraceAnnotation("lern.train"):
+        n_l = max(len(trace.layer_names), 1)
+        with jax.profiler.TraceAnnotation("lern.extract"):
+            lines_all, layer_all = _layer_sorted(trace)
+            if hash_fn is not None:
+                lines_all = hash_fn(lines_all)
+            extracted = _extract_flat(lines_all, layer_all, n_l)
+        flat = _fit_flat(extracted, [seed + li for li in range(n_l)],
+                         use_kernel, fit_engine)
+        return _assemble(flat, 0, n_l, hash_fn)
 
 
 def train_family_batched(traces: List[Trace],
@@ -671,25 +679,29 @@ def train_family_batched(traces: List[Trace],
     bucket rows are independent under vmap at the same capacity, and
     each layer keeps its own-config k-means key ``seed + local_layer``
     (tests/test_lern_batched.py pins this), so the per-config caches are
-    interchangeable."""
-    n_ls = [max(len(tr.layer_names), 1) for tr in traces]
-    bounds = np.concatenate([[0], np.cumsum(n_ls)])
-    lines_parts, layer_parts, seeds = [], [], []
-    for ci, tr in enumerate(traces):
-        lines, layer = _layer_sorted(tr)
-        lines_parts.append(lines)
-        layer_parts.append(layer + bounds[ci])
-        seeds.extend(seed + li for li in range(n_ls[ci]))
-    lines_all = np.concatenate(lines_parts) if traces else np.zeros(0,
-                                                                    np.int64)
-    layer_all = np.concatenate(layer_parts) if traces else np.zeros(0,
-                                                                    np.int64)
-    if hash_fn is not None and lines_all.size:
-        lines_all = hash_fn(lines_all)
-    flat = _fit_flat(lines_all, layer_all, int(bounds[-1]), seeds,
-                     use_kernel, fit_engine)
-    return [_assemble(flat, int(bounds[ci]), int(bounds[ci + 1]), hash_fn)
-            for ci in range(len(traces))]
+    interchangeable.  The same profiler spans as ``train_model_batched``,
+    with one ``lern.assemble`` per model."""
+    with jax.profiler.TraceAnnotation("lern.train"):
+        n_ls = [max(len(tr.layer_names), 1) for tr in traces]
+        bounds = np.concatenate([[0], np.cumsum(n_ls)])
+        seeds = [seed + li for n_l in n_ls for li in range(n_l)]
+        with jax.profiler.TraceAnnotation("lern.extract"):
+            lines_parts, layer_parts = [], []
+            for ci, tr in enumerate(traces):
+                lines, layer = _layer_sorted(tr)
+                lines_parts.append(lines)
+                layer_parts.append(layer + bounds[ci])
+            lines_all = (np.concatenate(lines_parts) if traces
+                         else np.zeros(0, np.int64))
+            layer_all = (np.concatenate(layer_parts) if traces
+                         else np.zeros(0, np.int64))
+            if hash_fn is not None and lines_all.size:
+                lines_all = hash_fn(lines_all)
+            extracted = _extract_flat(lines_all, layer_all, int(bounds[-1]))
+        flat = _fit_flat(extracted, seeds, use_kernel, fit_engine)
+        return [_assemble(flat, int(bounds[ci]), int(bounds[ci + 1]),
+                          hash_fn)
+                for ci in range(len(traces))]
 
 
 def train_host_numpy(trace: Trace, hash_fn: Optional[Callable] = None,

@@ -97,6 +97,24 @@ def test_bucket_single_group_degenerate():
         assert_bitwise(lane.result(), want, pol.name)
 
 
+def test_phase_times_are_also_profiler_spans(tmp_path):
+    """Each block ``phase_times`` times is a profiler span too, so a trace
+    places the bucketed engine's phases against the device; the split keeps its
+    keys."""
+    from test_lern_spans import _traced
+    _, groups = _synthetic_group(3, 64)
+    fused.reset_phase_times()
+    _, events = _traced(lambda: fused.drive_lanes_bucketed(
+        [groups], k_epochs=4, max_rounds=32), tmp_path)
+    phases = fused.phase_times()
+    assert sorted(phases) == ["device_s", "dispatch_s", "stage_s",
+                              "writeback_s"]
+    assert all(v > 0 for v in phases.values())
+    assert {ev[0] for ev in events} == {"fused.stage", "fused.dispatch",
+                                        "fused.device_wait",
+                                        "fused.writeback"}
+
+
 def test_bucket_sched_dram_mixed_policy_parity():
     """Scheduled-dram groups: the bank/rank geometry rides in bucket_key
     (the arbitration kind is SharedConsts data), so SQUASH and FR-FCFS
