@@ -264,28 +264,38 @@ def _fit_layer(f_ri: jnp.ndarray, f_rc: jnp.ndarray, n_multi: jnp.ndarray,
             "ri_assign": ri_res.assign, "ri_centers": ri_centers}
 
 
+def _seed_keys(seeds: jnp.ndarray) -> jnp.ndarray:
+    """[n] int32 seeds -> [n, 2] uint32 k-means keys, built inside the
+    calling jitted program (no eager dispatch or read-back per key).  A
+    32-bit seed's key words are ``[0, seed]``, so each row is bitwise
+    ``jax.random.PRNGKey(seed)`` for 0 <= seed < 2**31."""
+    return jax.vmap(jax.random.PRNGKey)(seeds)
+
+
 @functools.partial(jax.jit, static_argnames=("use_kernel",))
 def _fit_groups(groups, use_kernel: Optional[bool] = None):
     """All layers' k-means fits as one jitted device program.
 
     ``groups`` is a tuple of capacity buckets — each a
-    ``(f_ri [G, cap, 4], f_rc [G, cap], n_multi [G], keys [G, 2])`` tuple
-    of layers padded to the same power-of-two point count.  Each bucket is
-    vmapped; the whole tuple compiles (and dispatches) as a single XLA
-    program, so there is no per-layer Python k-means loop and small layers
-    don't pay the largest layer's padding."""
+    ``(f_ri [G, cap, 4], f_rc [G, cap], n_multi [G], seeds [G])`` tuple
+    of layers padded to the same power-of-two point count, ``seeds`` the
+    int32 k-means seed of each layer.  Each bucket is vmapped; the whole
+    tuple compiles (and dispatches) as a single XLA program, so there is
+    no per-layer Python k-means loop and small layers don't pay the
+    largest layer's padding."""
     fit = functools.partial(_fit_layer, use_kernel=use_kernel)
-    return tuple(jax.vmap(fit)(f_ri, f_rc, nm, keys)
-                 for f_ri, f_rc, nm, keys in groups)
+    return tuple(jax.vmap(fit)(f_ri, f_rc, nm, _seed_keys(seeds))
+                 for f_ri, f_rc, nm, seeds in groups)
 
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
 def _seg_prep(f_ri: jnp.ndarray, f_rc: jnp.ndarray, seg: jnp.ndarray,
-              keys: jnp.ndarray, n_seg: int) -> Dict:
+              seeds: jnp.ndarray, n_seg: int) -> Dict:
     """Normalize the flat feature rows into the combined 2*n_seg-segment
     point array (RC half zero-padded to the RI feature width — distances
     are unchanged).  Elementwise-identical to ``_fit_layer``'s
-    normalization (log1p + per-segment min-max for RC, row L1 for RI)."""
+    normalization (log1p + per-segment min-max for RC, row L1 for RI).
+    ``seeds`` [n_seg] int32 become the segments' keys here, in-program."""
     p = f_rc.shape[0]
     valid = seg < n_seg
     segc = jnp.minimum(seg, n_seg - 1)
@@ -303,6 +313,7 @@ def _seg_prep(f_ri: jnp.ndarray, f_rc: jnp.ndarray, seg: jnp.ndarray,
     xx = jnp.concatenate([x_rc, x_ri], axis=0)
     seg2 = jnp.concatenate([jnp.where(valid, seg, 2 * n_seg),
                             jnp.where(valid, seg + n_seg, 2 * n_seg)])
+    keys = _seed_keys(seeds)
     keys2 = jnp.concatenate([
         jax.vmap(lambda kk: jax.random.fold_in(kk, 0))(keys),
         jax.vmap(lambda kk: jax.random.fold_in(kk, 1))(keys)])
@@ -339,14 +350,16 @@ def _seg_post(assign2: jnp.ndarray, centers2: jnp.ndarray,
 
 def _fit_segmented(f_ri: jnp.ndarray, f_rc: jnp.ndarray, seg: jnp.ndarray,
                    seg_off: np.ndarray, seg_cnt: np.ndarray,
-                   keys: jnp.ndarray, n_seg: int,
+                   seeds: jnp.ndarray, n_seg: int,
                    use_kernel: Optional[bool] = None) -> Dict:
     """All eligible layers' RC + RI fits as one flat segmented dispatch.
 
     ``f_ri`` [P, 4] / ``f_rc`` [P] hold every layer's multi-occurrence
     feature rows in the flat-segmented layout (layer s's rows contiguous at
     ``seg_off[s]``, ``seg_cnt[s]`` real rows, runs padded to SEG_BLOCK
-    multiples with ``seg == n_seg``).  The two per-layer fits of
+    multiples with ``seg == n_seg``; both host arrays, so the layout is
+    never read back from the device).  ``seeds`` [n_seg] int32 is each
+    layer's k-means seed.  The two per-layer fits of
     ``_fit_layer`` become 2*n_seg segments of one
     ``kmeans.kmeans_fit_segmented`` call: the RC points under
     ``fold_in(key, 0)``, the RI points under ``fold_in(key, 1)``, matching
@@ -356,7 +369,7 @@ def _fit_segmented(f_ri: jnp.ndarray, f_rc: jnp.ndarray, seg: jnp.ndarray,
     itself compacts unconverged segments between dispatches.)
     """
     p = int(f_rc.shape[0])
-    prep = _seg_prep(f_ri, f_rc, seg, keys, n_seg)
+    prep = _seg_prep(f_ri, f_rc, seg, seeds, n_seg)
     off2 = np.concatenate([np.asarray(seg_off, np.int32),
                            np.asarray(seg_off, np.int32) + p])
     cnt2 = np.concatenate([np.asarray(seg_cnt, np.int32)] * 2)
@@ -522,19 +535,19 @@ def _fit_flat_bucketed(f_ri_f, f_rc_f, offs, per_layer, elig, key_seeds,
         g_ri = np.zeros((len(members), cap, NUM_RI_BINS), np.int32)
         g_rc = np.zeros((len(members), cap), np.int32)
         g_nm = np.zeros(len(members), np.int32)
-        keys = np.zeros((len(members), 2), np.uint32)
+        g_seed = np.zeros(len(members), np.int32)
         for gi, li in enumerate(members):
             multi, nm = per_layer[li]
             sl = slice(offs[li], offs[li + 1])
             g_ri[gi, :nm] = f_ri_f[sl][multi]
             g_rc[gi, :nm] = f_rc_f[sl][multi]
             g_nm[gi] = nm
-            keys[gi] = np.asarray(jax.random.PRNGKey(key_seeds[li]))
+            g_seed[gi] = key_seeds[li]
             group_of[li] = (len(groups), gi)
         groups.append((jnp.asarray(g_ri), jnp.asarray(g_rc),
-                       jnp.asarray(g_nm), jnp.asarray(keys)))
+                       jnp.asarray(g_nm), jnp.asarray(g_seed)))
     fits = _fit_groups(tuple(groups), use_kernel=use_kernel)
-    fits_np = jax.tree.map(np.asarray, fits)
+    fits_np = jax.device_get(fits)
     return {li: {k: v[gi] for k, v in fits_np[g].items()}
             for li, (g, gi) in group_of.items()}
 
@@ -554,7 +567,7 @@ def _fit_flat_segmented(f_ri_f, f_rc_f, offs, per_layer, elig, key_seeds,
     f_ri_m = np.zeros((p, NUM_RI_BINS), np.int32)
     f_rc_m = np.zeros(p, np.int32)
     seg = np.full(p, n_seg, np.int32)
-    keys = np.zeros((n_seg, 2), np.uint32)
+    seeds = np.zeros(n_seg, np.int32)
     for si, li in enumerate(elig):
         multi, nm = per_layer[li]
         sl = slice(offs[li], offs[li + 1])
@@ -562,13 +575,13 @@ def _fit_flat_segmented(f_ri_f, f_rc_f, offs, per_layer, elig, key_seeds,
         f_ri_m[o:o + nm] = f_ri_f[sl][multi]
         f_rc_m[o:o + nm] = f_rc_f[sl][multi]
         seg[o:o + nm] = si
-        keys[si] = np.asarray(jax.random.PRNGKey(key_seeds[li]))
+        seeds[si] = key_seeds[li]
     fit = _fit_segmented(jnp.asarray(f_ri_m), jnp.asarray(f_rc_m),
-                         jnp.asarray(seg), jnp.asarray(seg_off),
-                         jnp.asarray(np.asarray(counts, np.int32)),
-                         jnp.asarray(keys), n_seg=n_seg,
+                         jnp.asarray(seg), seg_off,
+                         np.asarray(counts, np.int32),
+                         jnp.asarray(seeds), n_seg=n_seg,
                          use_kernel=use_kernel)
-    fit_np = {k: np.asarray(v) for k, v in fit.items()}
+    fit_np = jax.device_get(fit)
     out: Dict[int, Dict] = {}
     for si, li in enumerate(elig):
         nm = per_layer[li][1]

@@ -16,13 +16,16 @@ Layers of parity:
   on the semantic cluster-label tables (the annotation step's
   centroid-sort IS the permutation canonicalization), with centers equal
   to FP reassociation — across ragged, empty, single-point, same-size,
-  and one-giant-layer shapes.
+  and one-giant-layer shapes;
+* both fit engines build their k-means keys inside the fit program,
+  bitwise ``jax.random.PRNGKey(seed)``, and none on the host.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
+from hypothesis import given, settings, strategies as st
 
-from repro.core import lern, lrpt
+from repro.core import kmeans as km, lern, lrpt
 from repro.core.reuse import (PAD_LINE, lines_to_device, reuse_features_jax,
                               reuse_signature_np, ri_histogram_np)
 from repro.core.tracegen import Trace
@@ -281,3 +284,96 @@ def test_replace_layers_swaps_tables():
     np.testing.assert_array_equal(merged.rc_centers[1], b.rc_centers[1])
 
 
+# The largest seed ``derive_seed`` gives, plus a layer offset.
+TOP_SEED = 2 ** 31 - 1025 + 8
+
+_seed_keys_jit = jax.jit(lern._seed_keys)
+_fit_layers_jit = jax.jit(jax.vmap(lern._fit_layer))
+
+
+def _eager_keys(seeds) -> np.ndarray:
+    return np.stack([np.asarray(jax.random.PRNGKey(int(s))) for s in seeds])
+
+
+def _assert_in_program_keys(seeds) -> None:
+    """``_seed_keys`` inside a jitted program equals the eager keys, and
+    each fit engine's program consumes exactly those keys: the segmented
+    prep's per-segment ``fold_in`` keys, and the bucketed fit's outputs
+    against the same vmapped fit fed the eager keys."""
+    seeds = np.asarray(seeds, np.int32)
+    want = _eager_keys(seeds)
+    got = _seed_keys_jit(jnp.asarray(seeds))
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+    n = seeds.shape[0]
+    rng = np.random.default_rng(int(seeds[-1]))
+    cap = 16
+    f_ri = rng.integers(0, 9, (n, cap, 4)).astype(np.int32)
+    f_rc = rng.integers(2, 40, (n, cap)).astype(np.int32)
+    nm = np.full(n, cap, np.int32)
+
+    # segmented: one SEG_BLOCK run per segment
+    off, total = km.segment_layout([cap] * n)
+    flat_ri = np.zeros((total, 4), np.int32)
+    flat_rc = np.zeros(total, np.int32)
+    seg = np.full(total, n, np.int32)
+    for s, o in enumerate(off):
+        flat_ri[o:o + cap], flat_rc[o:o + cap], seg[o:o + cap] = \
+            f_ri[s], f_rc[s], s
+    prep = lern._seg_prep(jnp.asarray(flat_ri), jnp.asarray(flat_rc),
+                          jnp.asarray(seg), jnp.asarray(seeds), n_seg=n)
+    fold = [np.stack([np.asarray(jax.random.fold_in(jnp.asarray(k), d))
+                      for k in want]) for d in (0, 1)]
+    np.testing.assert_array_equal(np.asarray(prep["keys2"]),
+                                  np.concatenate(fold))
+
+    # bucketed: the same rows through the eager-keyed vmapped fit
+    args = (jnp.asarray(f_ri), jnp.asarray(f_rc), jnp.asarray(nm))
+    fit, = lern._fit_groups(((*args, jnp.asarray(seeds)),))
+    ref = _fit_layers_jit(*args, jnp.asarray(want))
+    for k in ("rc_assign", "ri_assign"):
+        np.testing.assert_array_equal(np.asarray(fit[k]), np.asarray(ref[k]))
+    for k in ("rc_centers", "rc_centers_norm", "ri_centers"):
+        np.testing.assert_allclose(np.asarray(fit[k]), np.asarray(ref[k]),
+                                   rtol=CENTER_RTOL, atol=CENTER_ATOL)
+
+
+def test_in_program_keys_match_eager_edges():
+    _assert_in_program_keys([0, 1, TOP_SEED, TOP_SEED - 1])
+
+
+@settings(max_examples=12, deadline=None)
+@given(st.lists(st.integers(0, TOP_SEED), min_size=4, max_size=4))
+def test_in_program_keys_match_eager_drawn(seeds):
+    _assert_in_program_keys(seeds)
+
+
+def test_trainers_build_no_key_on_host(monkeypatch):
+    """Both engines of ``train_model_batched`` and ``train_family_batched``
+    call ``jax.random.PRNGKey`` only on traced seeds, inside a program —
+    and give the same tables as without the guard."""
+    traces = [_synthetic_trace(n_layers=3, seed=41),
+              _synthetic_trace(n_layers=2, seed=42)]
+    seed = TOP_SEED - 4
+    runs = [(engine, family) for engine in ("bucketed", "segmented")
+            for family in (False, True)]
+
+    def train(engine, family):
+        if family:
+            return lern.train_family_batched(traces, seed=seed,
+                                             fit_engine=engine)
+        return [lern.train_model_batched(traces[0], seed=seed,
+                                         fit_engine=engine)]
+
+    want = {run: train(*run) for run in runs}
+    eager = jax.random.PRNGKey
+
+    def traced_only(seed, *args, **kwargs):
+        if not isinstance(seed, jax.core.Tracer):
+            raise AssertionError(f"k-means key built on the host: {seed!r}")
+        return eager(seed, *args, **kwargs)
+
+    monkeypatch.setattr(jax.random, "PRNGKey", traced_only)
+    for run in runs:
+        for a, b in zip(want[run], train(*run), strict=True):
+            _assert_labels_equal(a, b, centers_exact=True)
