@@ -4,10 +4,12 @@ one process (the set-up is paid once):
 
 - sound: the program's own answers on ``--seeds`` seeds, each compared
   with the reference exactly as a run compares them;
-- control: ``--controls`` seeds on which the reference itself, computed in
-  the precision below the one the configuration states, is put in the
-  program's place: a plain k-means fitted in bfloat16 (the fit states
-  float32).
+- control: ``--controls`` seeds on which the reference itself, with one
+  part deliberately wrong, is put in the program's place.  For a LERN cell
+  that is a plain k-means fitted in bfloat16 (the fit states float32); for
+  a sweep cell each of three faults of the reference lane in turn: float32
+  timing (the configuration states float64), the SHiP counter threshold
+  off by one, and FIFO in place of LRU replacement.
 
     python3 chipbench/controls.py --workload <cell> --seed <n> [--seeds 12] [--controls 3]
 
@@ -131,6 +133,65 @@ def lern_readings(job, seeds, controls, emit):
              ref.compare(lern_control_model(ref, seed, jnp.bfloat16)))
 
 
+SWEEP_FAULTS = {
+    "float32_timing": dict(timing_dtype=np.float32),
+    "ship_threshold_off_by_one": dict(dead_max=1),
+    "fifo_replacement": dict(fifo=True),
+}
+
+
+def sweep_readings(job, seeds, controls, emit):
+    """Sound: the program's groups on ``seeds`` fresh stream seeds against
+    the reference, as a run compares them.  Control: each fault of the
+    reference lane on ``controls`` seeds against the sound reference."""
+    import dataclasses
+    from repro import exp
+    from repro.core import sim
+    from chipbench.reference import sweep_lane
+    as_lane = common.load_module("jobs", "sweep.py").as_lane
+    lanes = list(job.traffic["lanes"])
+    name, mix = job.config["name"], job.traffic["mix"]
+    trace, model, lern, _ = job.reference_inputs()
+    soc, cores = job.soc_and_cores()
+    deadline = sweep_lane.standalone_deadline(soc, trace)
+    occ = bool(job.config["params"]["record_occupancy"])
+
+    def reference(seed, fault=sweep_lane.SOUND, deadline=None):
+        p = dataclasses.replace(job.params[0], seed=seed)
+        streams = sim.load_artifacts(name, mix, p).streams
+        return sweep_lane.run_group(soc, lanes, trace, cores, streams, seed,
+                                    model, deadline, fault), p
+
+    def worst(pairs):
+        m, g = 0, 0.0
+        for got, want in pairs:
+            mi, gi = sweep_lane.compare_lane(got, want, occ)
+            m, g = m + mi, max(g, gi)
+        return {"int_mismatch": m, "float_rel_gap": g}
+
+    emit("sound", job.seed, {"lern_" + k: v for k, v in lern.items()})
+    for i in range(seeds):
+        seed = common.derive_seed(job.seed, 1000 + i)
+        ref, p = reference(seed, deadline=deadline)
+        rs = exp.run(exp.ExperimentSpec.grid(config=name, mix=mix,
+                                             policy=lanes, params=p),
+                     plan=job.plan)
+        got = {n: rs.filter(policy=n).one()["result"] for n in lanes}
+        emit("sound", seed, worst(
+            (as_lane(got[n]), ref[n]) for n in lanes))
+    for fname, kw in SWEEP_FAULTS.items():
+        fault = sweep_lane.Fault(**kw)
+        for i in range(controls):
+            seed = common.derive_seed(job.seed, 500_000 + i)
+            ref, _ = reference(seed, deadline=deadline)
+            ctl, _ = reference(seed, fault)
+            emit("control:" + fname, seed,
+                 worst((ctl[n], ref[n]) for n in lanes))
+
+
+READINGS = {"lern": lern_readings, "sweep": sweep_readings}
+
+
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -145,18 +206,19 @@ def main(argv=None) -> dict:
     job = common.load_module("jobs", c["traffic"]["job"] + ".py").Job(
         c["config"], c["traffic"], args.seed)
     job.setup()
-    out = {"sound": {}, "control": {}}
+    out = {"sound": {}}
 
     def emit(kind, seed, numbers, **extra):
         print(json.dumps({"kind": kind, "seed": seed, **numbers, **extra}),
               flush=True)
-        agg = out[kind]
+        agg = out.setdefault(kind, {})
         for k, v in numbers.items():
             agg[k] = (max if kind == "sound" else min)(agg.get(k, v), v)
 
-    lern_readings(job, args.seeds, args.controls, emit)
+    READINGS[c["traffic"]["job"]](job, args.seeds, args.controls, emit)
     print(json.dumps({"largest_sound": out["sound"],
-                      "smallest_control": out["control"]}), flush=True)
+                      "smallest_control": {k: v for k, v in out.items()
+                                           if k != "sound"}}), flush=True)
     return out
 
 

@@ -253,12 +253,28 @@ def test_lern_work_counts_fit_the_recorded_calls(env):
     assert 0 < work["ri_intervals_per_call"] < job.trace.num_accesses
 
 
-def test_nothing_compiles_inside_a_window(env):
+@pytest.mark.parametrize("workload,overrides", [
+    ("lern.config4", {"traffic": {"kmeans_seeds": 3}}),
+    ("sweep.config1.moti1",
+     {"params": {"n_inputs": 1, "max_epochs": 60, "subsample_target": 50_000},
+      "traffic": {"stream_seeds": 2}}),
+])
+def test_nothing_compiles_inside_a_window(env, workload, overrides):
+    """After set-up, two passes over the job's seed pool compile
+    nothing."""
     from chipbench import run
-    out = io.StringIO()
-    run.run_cell("lern.config4", 2 ** 40 + 3, 0.5, trace=False,
-                 require_tpu=False, overrides={"traffic": {"kmeans_seeds": 3}},
-                 out=out, err=io.StringIO())
-    window = [json.loads(ln)["window"] for ln in out.getvalue().splitlines()
-              if ln.startswith('{"window"')][0]
-    assert window["iterations"] >= 3 and window["backend_compiles"] == 0
+    run._environment()
+    c = common.cell(workload, BENCH)
+    c["config"]["params"].update(overrides.get("params", {}))
+    c["traffic"].update(overrides["traffic"])
+    job = common.load_module("jobs", c["traffic"]["job"] + ".py").Job(
+        c["config"], c["traffic"], 2 ** 40 + 3)
+    clock = common.CompileClock()
+    job.setup()
+    mark = clock.mark()
+    pool = max(c["traffic"].get(k, 0) for k in ("kmeans_seeds",
+                                                 "stream_seeds"))
+    for i in range(2 * pool):
+        job.iteration(i)
+    assert job.attempted == 2 * pool
+    assert clock.since(mark)["backend_compiles"] == 0
