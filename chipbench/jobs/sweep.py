@@ -50,7 +50,6 @@ def as_lane(res) -> dict:
         "epochs", "completion_cycles", "history", "dram_accesses")}
     out["requests"] = requests(res)
     out["occupancy"] = [tuple(o) for o in res.occupancy]
-    out["occupancy"] = [tuple(o) for o in res.occupancy]
     return out
 
 

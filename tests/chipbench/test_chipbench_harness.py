@@ -72,6 +72,30 @@ def test_cell_resolves_its_files(workload):
         assert callable(mod.read)
 
 
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_every_configuration_has_what_each_job_reads(env, config):
+    """Each job kind's checks and its reference take this configuration's
+    file as it stands, whichever cell names it next."""
+    file = {c["name"]: c["file"] for c in BENCH["configs"]}[config]
+    with open(os.path.join(ROOT, file)) as f:
+        cfg = json.load(f)
+    assert cfg["name"] == config
+    common.check_cuts(cfg)
+    for tname in sorted(os.listdir(os.path.join(common.HERE, "traffic"))):
+        traffic = common.load_json("traffic", tname)
+        mod = common.load_module("jobs", traffic["job"] + ".py")
+        if traffic["job"] == "lern":
+            mod.check_config(cfg)
+            assert cfg["params"]["subsample_target"] > 0
+        else:
+            assert traffic["job"] == "sweep", traffic["job"]
+            job = mod.Job(cfg, traffic, 1)
+            job.prepare()
+            soc, cores = job.soc_and_cores()
+            assert soc.sets > 0 and len(cores) == cfg["cores"]
+            assert isinstance(cfg["params"]["record_occupancy"], bool)
+
+
 def test_roofline_counts_on_known_shapes():
     assert roofline.ri_histogram_work(1000) == (5000, 8000)
     # 10 points, K=4 centres of D=4: 4*4*3 + 3 = 51 operations per point
@@ -253,11 +277,15 @@ def test_lern_work_counts_fit_the_recorded_calls(env):
     assert 0 < work["ri_intervals_per_call"] < job.trace.num_accesses
 
 
+SWEEP_SMOKE = {"params": {"n_inputs": 1, "max_epochs": 60,
+                          "subsample_target": 50_000},
+               "traffic": {"stream_seeds": 2}}
+
+
 @pytest.mark.parametrize("workload,overrides", [
     ("lern.config4", {"traffic": {"kmeans_seeds": 3}}),
-    ("sweep.config1.moti1",
-     {"params": {"n_inputs": 1, "max_epochs": 60, "subsample_target": 50_000},
-      "traffic": {"stream_seeds": 2}}),
+    ("sweep.config1.moti1", SWEEP_SMOKE),
+    ("sweep.config4.moti1", SWEEP_SMOKE),
 ])
 def test_nothing_compiles_inside_a_window(env, workload, overrides):
     """After set-up, two passes over the job's seed pool compile

@@ -22,6 +22,9 @@ from chipbench import common, fused_spans, trace_reduce  # noqa: E402
 from chipbench.reference import sweep_lane  # noqa: E402
 
 CELL = "sweep.config1.moti1"
+# every cell whose traffic drives the sweep job
+SWEEP_CELLS = [w["name"] for w in common.benchmark()["workloads"]
+               if common.cell(w["name"])["traffic"]["job"] == "sweep"]
 SMOKE = {"n_inputs": 1, "max_epochs": 60, "subsample_target": 50_000}
 SEED = 2 ** 33 + 21
 READERS = ("fused.stage_ms", "fused.dispatch_ms", "fused.device_wait_ms",
@@ -29,11 +32,11 @@ READERS = ("fused.stage_ms", "fused.dispatch_ms", "fused.device_wait_ms",
            "device.callback_wait_share.sweep")
 
 
-@pytest.fixture(scope="module")
-def job():
+@pytest.fixture(scope="module", params=SWEEP_CELLS)
+def job(request):
     """The cell's job at the smoke preset, with its reference inputs."""
     sys.path.insert(0, os.path.join(ROOT, "src"))
-    c = common.cell(CELL)
+    c = common.cell(request.param)
     c["config"]["params"].update(SMOKE)
     c["traffic"]["stream_seeds"] = 1
     mod = common.load_module("jobs", "sweep.py")
@@ -41,6 +44,7 @@ def job():
     j.prepare()
     j.inputs = j.reference_inputs({0})
     j.as_lane = mod.as_lane
+    j.occupancy = bool(c["config"]["params"]["record_occupancy"])
     return j
 
 
@@ -57,16 +61,20 @@ def test_the_reference_matches_the_simulator(job, reference, engine):
     from repro import exp
     lanes = list(job.traffic["lanes"])
     rs = exp.run(exp.ExperimentSpec.grid(
-        config="config1", mix="moti1", policy=lanes, params=job.params[0]),
+        config=job.config["name"], mix=job.traffic["mix"], policy=lanes,
+        params=job.params[0]),
         plan=exp.ExecPlan(engine=engine, cache=False))
     lim = job.traffic["limits"]
     for name in lanes:
         got = rs.filter(policy=name).one()["result"]
         mism, gap = sweep_lane.compare_lane(job.as_lane(got),
-                                            reference[name], False)
+                                            reference[name], job.occupancy)
         assert mism == 0 and gap <= lim["float_rel_gap"], (name, mism, gap)
         assert got.epochs == reference[name]["epochs"] > 0
         assert job.as_lane(got)["requests"] == reference[name]["requests"]
+        # a cell that records occupancy compares it, epoch by epoch
+        assert len(job.as_lane(got)["occupancy"]) == (
+            got.epochs if job.occupancy else 0)
     _, _, lern, _ = job.inputs
     for k, v in lern.items():
         assert v <= lim["lern_" + k], (k, v)
@@ -84,7 +92,7 @@ def test_each_control_reads_over_its_limit(job, reference, fault):
     lim = job.traffic["limits"]
     mism = gap = 0
     for name, want in reference.items():
-        m, g = sweep_lane.compare_lane(ctl[name], want, False)
+        m, g = sweep_lane.compare_lane(ctl[name], want, job.occupancy)
         mism, gap = mism + m, max(gap, g)
     assert mism > lim["int_mismatch"] or gap > lim["float_rel_gap"]
 
