@@ -25,12 +25,16 @@ Parity contract (tests/test_fused.py):
   exact operation order, including numpy's pairwise summation tree for
   the 8-core IPC sum — through one ``jax.pure_callback`` per epoch half
   (``_begin_host``/``_finish_host``), for the whole lane batch at once.
-  No device float64 is involved: a TPU has none (XLA emulates it with
-  f32 pairs and rounds even a plain copy), so every float64 leaf of the
-  consts, the carry and the step outputs holds IEEE bits as a trailing
-  pair of uint32 words (``f64b``), which only the host interprets.  The
-  device keeps the integer work: event build, round loop, LLC state,
-  scheduled-DRAM bank model.  The public guarantee is rtol=1e-6 (the
+  Each call's operands and its results cross as one packed uint32 word
+  row per lane each way (``_host_call``): a TPU host transfer costs the
+  same 0.1-0.6 ms whether it holds 3 bytes or 3 KB, and a call's
+  transfers run one after another, so their count, not their bytes, is
+  the cost.  No device float64 is involved: a TPU has none (XLA emulates
+  it with f32 pairs and rounds even a plain copy), so every float64 leaf
+  of the consts, the carry and the step outputs holds IEEE bits as a
+  trailing pair of uint32 words (``f64b``), which only the host
+  interprets.  The device keeps the integer work: event build, round
+  loop, LLC state, scheduled-DRAM bank model.  The public guarantee is rtol=1e-6 (the
   acceptance bar); tests assert bitwise float equality.
 
 Fallback contract: the per-epoch round matrix has a static round
@@ -322,34 +326,76 @@ def _words(x) -> jnp.ndarray:
                       (u >> np.uint64(32)).astype(jnp.uint32)], axis=-1)
 
 
+def _pack(args: dict):
+    """Device operands -> one uint32 word row and its static layout
+    ``((key, shape, dtype), ...)``: bool as 0/1, int32 bitcast, uint32
+    (``f64b`` pairs included) as is, 64-bit integers as ``_words`` pairs."""
+    layout, parts = [], []
+    for k, v in args.items():
+        v = jnp.asarray(v)
+        dt = np.dtype(v.dtype)
+        if dt == np.bool_:
+            w = v.astype(jnp.uint32)
+        elif dt in (np.int32, np.uint32):
+            w = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        elif dt in (np.int64, np.uint64):
+            w = _words(v)
+        else:
+            raise TypeError(f"host-call operand {k!r}: unsupported {dt}")
+        layout.append((k, tuple(v.shape), dt))
+        parts.append(w.reshape(-1))
+    return jnp.concatenate(parts), tuple(layout)
+
+
+def _unpack(rows: np.ndarray, layout) -> dict:
+    """Host inverse of ``_pack`` for a batch of rows ``[n, words]``: each
+    operand back in its dtype, as ``[n, *shape]``."""
+    n, a, off = rows.shape[0], {}, 0
+    for k, shape, dt in layout:
+        size = math.prod(shape) * max(dt.itemsize // 4, 1)
+        w = np.ascontiguousarray(rows[:, off:off + size])
+        off += size
+        a[k] = (w != 0 if dt == np.bool_ else w.view(dt)).reshape(
+            (n,) + shape)
+    return a
+
+
 def _host_call(fn, out: dict, args: dict) -> dict:
     """Run ``fn`` — float64 math in numpy — on the host from inside the
     traced epoch step.  Under the lane vmap the callback sees the whole
-    lane batch at once (leading axis; size 1 for unbatched inputs) and
-    returns every ``out`` leaf (bool, int32 or uint32) with the lane axis.
+    lane batch at once (leading axis) and returns every ``out`` leaf
+    (bool, int32 or uint32) with the lane axis.
 
-    The results come back packed as one uint32 word row per lane: one
-    host transfer per call instead of one per leaf, and the only form a
-    callback inside ``shard_map`` lowers to on a TPU (jax 0.9 annotates
-    every result's receive with the first result's rank)."""
+    Operands and results each cross as ONE packed uint32 word row per
+    lane (``_pack``/``_unpack``; the layout is static, from the operands'
+    shapes and dtypes).  On a TPU every host transfer costs 0.1-0.6 ms
+    whatever its size, and a call's transfers run one after another, so
+    their count, not their bytes, is what a step pays: one each way, not
+    one per leaf.  Packed results are also the only form a callback inside
+    ``shard_map`` lowers to on a TPU (jax 0.9 annotates every result's
+    receive with the first result's rank).  Operands that are not
+    batched travel broadcast to the lane batch: bytes, not transfers."""
     sizes = [math.prod(sd.shape) for sd in out.values()]
+    row, layout = _pack(args)
 
-    def call(a):
-        # the callback receives jax arrays: numpy from here on, so every
+    def call(row):
+        # the callback receives a jax array: numpy from here on, so every
         # operation is the host's float64 (not jnp under 32-bit mode)
-        a = {k: np.asarray(v) for k, v in a.items()}
+        row = np.asarray(row)
+        rows = row.reshape(-1, row.shape[-1])
+        n = rows.shape[0]
         with np.errstate(all="ignore"):  # unselected branches may divide by 0
-            res = fn(a)
-        n = a["lane"].shape[0]
+            res = fn(_unpack(rows, layout))
         words = []
         for (k, sd), size in zip(out.items(), sizes):
             v = np.broadcast_to(np.asarray(res[k], sd.dtype), (n,) + sd.shape)
             v = v.astype(np.uint32) if sd.dtype == bool else v.view(np.uint32)
             words.append(v.reshape(n, size))
-        return np.concatenate(words, axis=1)
+        return np.concatenate(words, axis=1).reshape(
+            row.shape[:-1] + (sum(sizes),))
 
     words = jax.pure_callback(
-        call, jax.ShapeDtypeStruct((sum(sizes),), jnp.uint32), args,
+        call, jax.ShapeDtypeStruct((sum(sizes),), jnp.uint32), row,
         vmap_method="expand_dims")
     res, off = {}, 0
     for (k, sd), size in zip(out.items(), sizes):
@@ -780,7 +826,7 @@ def _begin_lane(dims: FusedDims, sh: SharedConsts, stop_epoch, lc, cy,
          "n_c": _sds((dims.n_cores,), i32), "shed": _f64(),
          "ri_th": _sds((), i32), "rc_th": _sds((), i32),
          "special": _sds((), bool), "req_out": _f64()},
-        dict(args, lane=args["pos"]))
+        args)
     n_a = fl["n_a"].astype(jnp.int64)
     n_c = fl["n_c"].astype(jnp.int64)
     ri_th = fl["ri_th"].astype(jnp.int64)
@@ -935,8 +981,7 @@ def _finish_lane(dims: FusedDims, sh: SharedConsts, lc, cy, bg: _Begin,
              stats=stats.astype(jnp.int32),
              percore=percore.astype(jnp.int32),
              accel_prio=bg.accel_prio, n_a=n_a.astype(jnp.int32),
-             shed=bg.shed, sched_nd=sched_nd, completed=completed,
-             lane=cy.pos))
+             shed=bg.shed, sched_nd=sched_nd, completed=completed))
     totals = cy.totals + jnp.stack([ch, cm, cb_, ah, am, ab, n_a])
 
     # ---- progress bookkeeping -----------------------------------------

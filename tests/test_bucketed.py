@@ -359,3 +359,45 @@ def test_execplan_bucketed_end_to_end(tmp_path, monkeypatch):
         assert (got["mix"], got["policy"]) == (want["mix"], want["policy"])
         assert_bitwise(got["result"], want["result"],
                        (got["mix"], got["policy"]))
+
+
+# ---------------------------------------------------------------------------
+# host callbacks: one packed operand and one packed result per epoch half
+# ---------------------------------------------------------------------------
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_bucket_step_host_calls_take_one_packed_operand():
+    """A bucketed super-step makes exactly two host callbacks per epoch
+    (``_begin_host``, ``_finish_host``), each with ONE operand and ONE
+    result: on a TPU every operand is its own serial host transfer, so
+    per-operand callbacks would cost one transfer latency per leaf."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import llc
+    _, group = _synthetic_group(3, 64)
+    staged = fused.stage_group(group, k_epochs=4, max_rounds=32,
+                               pads=fused.bucket_pads([group]))
+    dims = staged.dims
+    with jax.enable_x64():
+        carry = fused._init_carry(
+            group, llc.stack_states(dims.cfg, dims.n_lanes), dims.n_inputs)
+        sh_g, lc_g, carry_g = (fused._stack_trees([t]) for t in
+                               (staged.sh, staged.lc, carry))
+        jaxpr = jax.make_jaxpr(fused._bucket_run(dims, 1))(
+            sh_g, lc_g, carry_g, jnp.asarray([HP.max_epochs], jnp.int64))
+    calls = [e for e in _eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pure_callback"]
+    assert len(calls) == 2
+    for eqn in calls:
+        assert len(eqn.invars) == 1 and len(eqn.outvars) == 1, eqn
+        assert eqn.invars[0].aval.dtype == jnp.uint32

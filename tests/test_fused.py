@@ -233,3 +233,124 @@ def test_fused_property_random_traces(seed, n_lines, length, pol_idx):
     lane = mk()
     fused.drive_lanes_fused([lane])
     assert_bitwise(lane.result(), want, (seed, n_lines, length, pol.name))
+
+
+# ---------------------------------------------------------------------------
+# host-call operand packing: one uint32 row carries every operand kind
+# ---------------------------------------------------------------------------
+_SPECIALS = np.array([0.0, -0.0, np.inf, -np.inf, np.nan, 5e-324,
+                      np.nextafter(1.0, 2.0), np.nextafter(1.0, 0.0),
+                      -np.nextafter(0.0, 1.0), 2.2250738585072014e-308,
+                      1.0 / 3.0, -7.5])
+
+
+def _host_call_operands(n_lanes: int, n_cores: int = 8):
+    """Operands of every kind ``_begin_lane``/``_finish_lane`` send, as
+    (per-lane batched values, unbatched values)."""
+    rng = np.random.default_rng(7)
+
+    def f64(shape, lead=()):
+        return fused._bits(np.resize(rng.permutation(_SPECIALS),
+                                     lead + shape))
+
+    lanes = (n_lanes,)
+    batched = {
+        "flag": np.array([True, False, True][:n_lanes]),
+        "ri_th": np.array([-1, 3, 0][:n_lanes], np.int32),
+        "rc_sel": np.full(lanes + (n_cores,), -1, np.int32),
+        "now": f64((), lanes),
+        "ipc": f64((n_cores,), lanes),
+        "t_a": f64((4,), lanes),
+        "bands": f64((7,), lanes),
+        "big": np.array([2**40 + 3, -5, 0][:n_lanes], np.int64),
+    }
+    unbatched = {
+        "hydra": np.array(True),
+        "pos": np.array(-1, np.int32),
+        "stats": np.arange(-4, 4, dtype=np.int32).reshape(4, 2),
+        "et": f64(()),
+        "nominal": f64((n_cores,)),
+        "sched_nd": np.asarray(fused._words(np.array(
+            [2**33 + 1, 7, 0, 2**62], np.int64))),
+        "completions": f64((8,)),
+    }
+    return batched, unbatched
+
+
+@pytest.mark.parametrize("batched_lanes", [3, 0])
+def test_host_call_packed_row_round_trips_bitwise(batched_lanes):
+    """``_host_call`` ships a call's operands as one packed uint32 row;
+    ``fn`` must receive exactly what a per-operand ``pure_callback``
+    hands over (broadcast to the lane batch), for bool, int32 (with -1),
+    64-bit integers and ``f64b`` pairs of 0.0, -0.0, +-inf, NaN,
+    subnormals and ``nextafter`` neighbours, batched and unbatched under
+    ``vmap_method="expand_dims"`` — and the results must come back
+    intact."""
+    import jax
+    import jax.numpy as jnp
+
+    n = max(batched_lanes, 1)
+    with jax.enable_x64():
+        batched, unbatched = _host_call_operands(n)
+        if not batched_lanes:        # every operand unbatched, no vmap
+            unbatched.update({k: v[0] for k, v in batched.items()})
+            batched = {}
+        shapes = {k: np.shape(v)[1:] for k, v in batched.items()}
+        shapes.update({k: np.shape(v) for k, v in unbatched.items()})
+        ranks = {k: len(s) for k, s in shapes.items()}
+        got, ref = [], []
+
+        def fn(a):
+            got.append(a)
+            return {"flag": a["hydra"], "ri_th": a["pos"],
+                    "ipc": a["nominal"], "now": a["et"]}
+
+        out = {"flag": jax.ShapeDtypeStruct((), bool),
+               "ri_th": jax.ShapeDtypeStruct((), jnp.int32),
+               "ipc": fused._f64((8,)), "now": fused._f64()}
+
+        def per_operand(a):
+            a = {k: np.asarray(v) for k, v in a.items()}
+            ref.append(a)
+            lead = np.broadcast_shapes(
+                *(v.shape[:v.ndim - ranks[k]] for k, v in a.items()))
+            return np.zeros(lead, np.uint32)
+
+        def step(b, u):
+            args = {**b, **u}
+            zero = jax.pure_callback(
+                per_operand, jax.ShapeDtypeStruct((), jnp.uint32), args,
+                vmap_method="expand_dims")
+            return fused._host_call(fn, out, args), zero
+
+        dev_u = jax.tree.map(jnp.asarray, unbatched)
+        if batched_lanes:
+            res = jax.jit(jax.vmap(step, in_axes=(0, None)))(
+                jax.tree.map(jnp.asarray, batched), dev_u)
+        else:
+            res = jax.jit(step)({}, dev_u)
+        res = jax.tree.map(np.asarray, res[0])
+
+    (a,), (old,) = got, ref
+    assert sorted(a) == sorted(shapes)
+    for k, v in a.items():
+        sent = batched[k] if k in batched else unbatched[k]
+        assert v.shape == (n,) + shapes[k], k
+        assert v.dtype == sent.dtype, k
+        assert v.tobytes() == np.broadcast_to(sent, v.shape).tobytes(), k
+        if sent.dtype.itemsize == 8:
+            # the per-operand path narrows 64-bit operands in a callback
+            # thread where 64-bit mode is off: none is sent that way
+            continue
+        w = np.broadcast_to(old[k].reshape(
+            (-1,) + old[k].shape[old[k].ndim - ranks[k]:]), v.shape)
+        assert v.dtype == w.dtype, k
+        assert v.tobytes() == np.ascontiguousarray(w).tobytes(), k
+
+    lead = (n,) if batched_lanes else ()
+    assert res["flag"].shape == lead and res["flag"].all()
+    assert (res["ri_th"] == -1).all()
+    for k, src in (("ipc", "nominal"), ("now", "et")):
+        want_bits = np.broadcast_to(unbatched[src],
+                                    lead + unbatched[src].shape)
+        assert res[k].tobytes() == want_bits.tobytes(), k
