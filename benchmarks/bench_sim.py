@@ -5,9 +5,10 @@ The perf-trajectory artifact for the device-resident epoch loop
 (schema hydra-bench-sim/v3):
 
 ``kind="engine"`` — for every suite config it times the sequential host
-loop (``sim.drive_lane``, one lane at a time — the oracle the fused
-engine is bitwise-pinned against) and the fused super-step engine on
-the same policy group, at ``lanes`` of 1 and 4, and records epochs/sec.
+loop (``sim.drive_lane``, one lane at a time — the oracle the device
+engine is bitwise-pinned against) and the device super-step engine on
+the same policy group (``simulate_group``, a one-group bucket), at
+``lanes`` of 1 and 4, and records epochs/sec.
 
 ``kind="sweep"`` — sweep-level points/sec: the CI smoke sweep (a
 deadline-factor axis x the 4-policy lane set, i.e. several geometry-
@@ -81,7 +82,7 @@ def _run_host(config: str, mix: str, pols, p: sim.SimParams,
 def _run_fused(config: str, mix: str, pols, p: sim.SimParams,
                deadline: float) -> int:
     rs = sweep.simulate_group(config, mix, list(pols), p,
-                              deadline_cycles=deadline, engine="fused")
+                              deadline_cycles=deadline, engine="auto")
     return sum(r.epochs for r in rs)
 
 

@@ -67,7 +67,7 @@ def main() -> None:
     ap.add_argument("--jobs", type=int, default=1,
                     help="worker processes for uncached sweep points")
     ap.add_argument("--engine", default="auto",
-                    choices=["auto", "host", "fused", "bucketed"],
+                    choices=["auto", "host", "bucketed"],
                     help="sweep engine for every figure (ExecPlan.engine); "
                          "auto = bucketed device program when --jobs 1, "
                          "process pool otherwise")

@@ -32,7 +32,7 @@ def main():
     ap.add_argument("--jobs", type=int, default=1,
                     help="worker processes for uncached points")
     ap.add_argument("--engine", default="auto",
-                    choices=["auto", "host", "fused", "bucketed"],
+                    choices=["auto", "host", "bucketed"],
                     help="sweep engine (auto = bucketed device program "
                          "when --jobs 1, process pool otherwise)")
     args = ap.parse_args()

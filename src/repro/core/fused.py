@@ -34,8 +34,7 @@ Parity contract (tests/test_fused.py):
   of the consts, the carry and the step outputs holds IEEE bits as a
   trailing pair of uint32 words (``f64b``), which only the host
   interprets.  The device keeps the integer work: event build, round
-  loop, LLC state, scheduled-DRAM bank model.  The public guarantee is rtol=1e-6 (the
-  acceptance bar); tests assert bitwise float equality.
+  loop, LLC state, scheduled-DRAM bank model.
 
 Fallback contract: the per-epoch round matrix has a static round
 capacity (``max_rounds``).  A hot set overflowing it — or an
@@ -43,15 +42,14 @@ online-LERN retrain boundary — raises a flag.  An overflowing epoch
 never commits: the lane *freezes in place* on its pre-overflow carry
 (``_finish_lane`` selects the old state, the sticky flag gates further
 steps), so the carry is always valid and the driver can resume from it
-directly — no rollback buffer is needed, which is what lets the
-bucketed driver donate its carry.  ``drive_lanes_fused`` re-dispatches
-the stretch at an escalated capacity (re-jit, doubling up to the host's
-largest round bucket), then replays through the host path (which chunks
-hot sets) and goes host-sticky after two consecutive overflows.
-``drive_lanes_bucketed`` escalates the whole bucket's capacity the same
-way and, once exhausted, demotes only the offending groups to
-``drive_lanes_fused``.  ``sim.drive_lane`` survives unchanged as the
-sequential oracle; ``sweep.simulate_group(engine=...)`` routes eligible
+directly — no rollback buffer is needed, which is what lets the driver
+donate its carry.  ``drive_lanes_bucketed``, the one device driver,
+escalates the bucket's capacity (re-jit, doubling up to the host's
+largest round bucket) and, once that is exhausted, finishes only the
+offending groups on the host: each still-active lane continues from its
+frozen state through ``sim.drive_lane``, which chunks arbitrarily hot
+sets.  ``sim.drive_lane`` survives unchanged as the sequential oracle;
+``sweep.simulate_group`` and ``sweep.simulate_bucket`` route eligible
 groups here.
 """
 from __future__ import annotations
@@ -71,7 +69,7 @@ import numpy as np
 from . import dram as dram_mod
 from . import dramsched
 from . import llc as llc_mod
-from .sim import PF_WHEN_OFF, WHEN_BITS, Lane
+from .sim import PF_WHEN_OFF, WHEN_BITS, Lane, drive_lane
 
 # Super-step length: epochs advanced per device dispatch (one host sync
 # each).  Round capacity: static per-set event bound of the fused round
@@ -91,10 +89,6 @@ MAX_ROUNDS_CAP = llc_mod.ROUND_BUCKETS[-1]
 SPARSE_CAP = int(os.environ.get("REPRO_FUSED_SPARSE_CAP", "256"))
 
 _HUGE_KEY = np.int64(1) << 62
-
-# Donation + double-buffered dispatch for the bucketed driver (off = one
-# undonated dispatch at a time, the reference path the parity tests pin).
-PIPELINE_DEFAULT = os.environ.get("REPRO_BUCKET_PIPELINE", "1") != "0"
 
 # Wall-clock split of the bucketed driver, accumulated across calls:
 # stage_s (host->device staging + carry init), dispatch_s (tracing,
@@ -1031,25 +1025,6 @@ def _finish_lane(dims: FusedDims, sh: SharedConsts, lc, cy, bg: _Begin,
     return out_cy, out
 
 
-def _epoch_batch_step(dims: FusedDims, sh: SharedConsts, stop_epoch, lc, cy):
-    """One epoch of the whole lane batch: vmapped begin halves, one
-    batch-level round loop, vmapped finish halves."""
-    bg = jax.vmap(functools.partial(_begin_lane, dims, sh, stop_epoch)
-                  )(lc, cy)
-    new_st, stats, percore = _run_rounds_batch(dims, lc.knobs, cy.st, bg)
-    return jax.vmap(functools.partial(_finish_lane, dims, sh)
-                    )(lc, cy, bg, new_st, stats, percore)
-
-
-@functools.partial(jax.jit, static_argnums=0)
-def _superstep(dims: FusedDims, sh: SharedConsts, lc: LaneConsts,
-               carry: FusedCarry, stop_epoch):
-    """K epochs of the whole lane batch as one compiled device program."""
-    def body(c, _):
-        return _epoch_batch_step(dims, sh, stop_epoch, lc, c)
-    return jax.lax.scan(body, carry, None, length=dims.k_epochs)
-
-
 # ---------------------------------------------------------------------------
 # staging: host Lane objects -> device constants / carry
 # ---------------------------------------------------------------------------
@@ -1286,9 +1261,7 @@ def stage_group(lanes: List[Lane], k_epochs: int = DEFAULT_SUPERSTEP,
 
 def _init_carry(lanes: List[Lane], states: llc_mod.LLCState,
                 n_inputs: int) -> FusedCarry:
-    """Build the device carry from the lanes' current host state (works
-    mid-run: the overflow fallback replays a stretch on the host and
-    resumes fused from whatever the lanes now hold)."""
+    """Build the device carry from the lanes' current host state."""
     n_l = len(lanes)
     n_c = len(lanes[0].profiles)
     comp = np.zeros((n_l, n_inputs))
@@ -1338,9 +1311,9 @@ def _init_carry(lanes: List[Lane], states: llc_mod.LLCState,
 
 
 # ---------------------------------------------------------------------------
-# write-back / host fallback / driver
+# write-back
 # ---------------------------------------------------------------------------
-def _write_back_carry(lanes: List[Lane], c, skip=None) -> None:
+def _write_back_carry(lanes: List[Lane], c) -> None:
     """Sync per-lane carry scalars into the host Lane objects — the exact
     fields (and python/numpy types) the sequential loop would have
     produced, so ``Lane.result()`` and any later host epochs are
@@ -1349,8 +1322,6 @@ def _write_back_carry(lanes: List[Lane], c, skip=None) -> None:
     writes back its unchanged values), so the bucketed driver can call
     it once at the end of the run and again at a demotion."""
     for i, lane in enumerate(lanes):
-        if skip is not None and skip[i]:
-            continue
         lane.hr_core = float(c.hr_core[i])
         lane.hr_accel = float(c.hr_accel[i])
         lane.amal = float(c.amal[i])
@@ -1408,129 +1379,6 @@ def _write_back_steps(lanes: List[Lane], y: StepOut) -> None:
                 lane._win_ranges.append(
                     (int(y.pos_before[t, i]),
                      int(y.pos_before[t, i] + y.n_a[t, i])))
-
-
-def _write_back(lanes: List[Lane], carry: FusedCarry, ys: StepOut) -> None:
-    """Sync an accepted super-step's results into the host Lane objects
-    (per-group driver: carry scalars + history rows in one call)."""
-    c = _to_host(carry._replace(st=None), _CARRY_FLOATS)
-    y = _to_host(ys, _STEP_FLOATS)
-    _write_back_carry(
-        lanes, c, skip=[int(y.active[:, i].sum()) == 0
-                        for i in range(len(lanes))])
-    _write_back_steps(lanes, y)
-
-
-def _host_stretch(lanes: List[Lane], states: llc_mod.LLCState,
-                  n_epochs: Optional[int]) -> llc_mod.LLCState:
-    """Advance the batch ``n_epochs`` epochs (None = to completion) on the
-    host path — per-lane event build + ``build_rounds`` chunking + the
-    static round engine, i.e. exactly ``sim.drive_lane``'s loop body
-    against the shared batched LLC states."""
-    e = 0
-    while (n_epochs is None or e < n_epochs) and \
-            any(lane.active for lane in lanes):
-        for i, lane in enumerate(lanes):
-            if not lane.active:
-                continue
-            st_i = jax.tree.map(lambda x: x[i], states)
-            ev = lane.begin_epoch()
-            stats = np.zeros(len(llc_mod.STAT_NAMES), np.int64)
-            percore = np.zeros((llc_mod.NUM_CORES, 2), np.int64)
-            if ev is not None:
-                line, meta = ev
-                for lm, mm in llc_mod.build_rounds(lane.llc_cfg, line, meta):
-                    st_i, st_c, pc_c = llc_mod.simulate_epoch(
-                        lane.llc_cfg, st_i, jnp.asarray(lm), jnp.asarray(mm))
-                    stats = stats + np.asarray(st_c)
-                    percore = percore + np.asarray(pc_c)
-            lane.finish_epoch(stats, percore, llc_state=st_i)
-            states = jax.tree.map(
-                lambda full, v: full.at[i].set(v), states, st_i)
-        e += 1
-    return states
-
-
-def _next_stop(lanes: List[Lane], max_epochs: int) -> int:
-    """First epoch the fused scan must not cross: the nearest online-LERN
-    retrain boundary of any lane (the refit runs on the host).  Computed
-    from each lane's own epoch — lanes run in lockstep here, but a group
-    resuming after a demotion replay may hold heterogeneous epochs."""
-    stop = max_epochs
-    for lane in lanes:
-        r = lane._retrain_every
-        if lane.active and r is not None:
-            e = lane.epoch
-            stop = min(stop, e + r - e % r)
-    return stop
-
-
-def drive_lanes_fused(lanes: List[Lane], states=None,
-                      k_epochs: int = DEFAULT_SUPERSTEP,
-                      max_rounds: int = DEFAULT_MAX_ROUNDS) -> None:
-    """Drive a geometry-compatible batch of lanes to completion through
-    the fused device engine, super-step by super-step.
-
-    Bitwise-equivalent to ``sim.drive_lane`` per lane on the integer LLC
-    stats (and float-identical on the timing metrics in practice); falls
-    back to the host path for super-steps that overflow the static round
-    capacity, going host-sticky after two consecutive overflows.
-    """
-    assert all(lane_supported(lane) for lane in lanes)
-    max_epochs = int(lanes[0].p.max_epochs)
-    with jax.enable_x64():
-        staged = _Staged(lanes, k_epochs, max_rounds)
-        if states is None:
-            states = llc_mod.stack_states(staged.dims.cfg, len(lanes))
-        carry = _init_carry(lanes, states, staged.dims.n_inputs)
-    overflows = 0
-    while any(lane.active for lane in lanes):
-        stop = _next_stop(lanes, max_epochs)
-        epochs_before = [lane.epoch for lane in lanes]
-        with jax.enable_x64():
-            new_carry, ys = _superstep(staged.dims, staged.sh, staged.lc,
-                                       carry, jnp.int64(stop))
-            overflowed = bool(np.asarray(new_carry.overflow).any())
-        if overflowed:
-            # roll the whole super-step back — the lanes were not
-            # touched and the old carry is still live.  First escalate
-            # the static round capacity (a re-jit, amortized over the
-            # rest of the run); past the host's largest bucket, replay
-            # the stretch on the host path, which chunks arbitrarily
-            # hot sets, and go host-sticky if that keeps happening.
-            if staged.dims.max_rounds < MAX_ROUNDS_CAP:
-                staged.dims = dataclasses.replace(
-                    staged.dims,
-                    max_rounds=min(staged.dims.max_rounds * 2,
-                                   MAX_ROUNDS_CAP))
-                continue
-            overflows += 1
-            e = max((lane.epoch for lane in lanes if lane.active),
-                    default=0)
-            n_host = None if overflows >= 2 else min(k_epochs, stop - e)
-            states = _host_stretch(lanes, carry.st, n_host)
-            if not any(lane.active for lane in lanes):
-                return
-            with jax.enable_x64():
-                staged.refresh_clusters(lanes)
-                carry = _init_carry(lanes, states, staged.dims.n_inputs)
-            continue
-        overflows = 0
-        _write_back(lanes, new_carry, ys)
-        carry = new_carry._replace(
-            overflow=jnp.zeros(len(lanes), bool))
-        # online-LERN boundaries land exactly at the super-step edge
-        # (_next_stop): run the host refit hook and re-upload the tables
-        retrained = False
-        for i, lane in enumerate(lanes):
-            r = lane._retrain_every
-            if (r is not None and lane.epoch > epochs_before[i]
-                    and lane.epoch % r == 0):
-                lane._online_retrain()
-                retrained = True
-        if retrained:
-            with jax.enable_x64():
-                staged.refresh_clusters(lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -1677,31 +1525,28 @@ def _stack_trees(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
-def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
+def drive_lanes_bucketed(groups: List[List[Lane]],
                          k_epochs: int = DEFAULT_SUPERSTEP,
                          max_rounds: int = DEFAULT_MAX_ROUNDS,
                          devices: Optional[int] = None,
-                         staged: Optional[List[_Staged]] = None,
-                         pipeline: Optional[bool] = None) -> None:
+                         staged: Optional[List[_Staged]] = None) -> None:
     """Drive several static-compatible lane groups (equal ``bucket_key``)
     to completion as ONE flat fused program with a leading group axis.
 
-    Per-group results are bitwise-identical to ``drive_lanes_fused`` on
-    each group alone (tests/test_bucketed.py): the flat (G*L) program
-    computes the same per-lane values (see ``_bucket_run``), and the
-    driver commits exactly the epochs the per-group driver would.
+    Every lane is bitwise-identical to ``sim.drive_lane`` on that lane
+    alone (tests/test_fused.py, tests/test_bucketed.py): the flat (G*L)
+    program computes the same per-lane values (see ``_bucket_run``), and
+    a group of one is the plain per-group case.
 
     The driver tracks progress from the fetched ``StepOut`` alone
     (per-lane committed-epoch counts and alive flags ride the scan
     outputs), so between super-steps only the K-epoch history rows cross
     the device boundary — the carry stays on device until the run ends
-    (or a group demotes), when its scalars sync once.  With ``pipeline``
-    (default ``REPRO_BUCKET_PIPELINE``, on) the carry is donated to the
-    next super-step, and when no lane has an online-LERN retrain
-    boundary (stop epochs constant) super-step N+1 is dispatched before
-    N's write-back runs, double-buffering host work against device work.
-    ``pipeline=False`` is the undonated, one-dispatch-at-a-time
-    reference path the parity tests pin against.
+    (or a group demotes), when its scalars sync once.  On one shard the
+    carry is donated to the next super-step, and when no lane has an
+    online-LERN retrain boundary (stop epochs constant) super-step N+1
+    is dispatched before N's write-back runs, double-buffering host work
+    against device work.
 
     Overflow handling demotes surgically and never rolls back: an
     overflowing lane freezes on its pre-overflow carry (see
@@ -1710,9 +1555,9 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
     the round loop's trip count follows the data, so shallow groups
     don't pay for the new depth), and once the capacity is exhausted
     only the *offending* groups leave — each syncs its carry scalars and
-    is replayed through ``drive_lanes_fused`` (host fallback and all)
-    from its frozen state while its batch slot freezes, so one
-    pathological group never knocks the whole bucket off the device.
+    finishes lane by lane on the host (``sim.drive_lane``, which chunks
+    hot sets) from its frozen LLC state while its batch slot freezes, so
+    one pathological group never knocks the whole bucket off the device.
 
     ``devices`` bounds the ``shard_map`` shard count for the group axis
     (None = all visible devices); sharding engages when more than one
@@ -1726,8 +1571,6 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
         assert all(lane_supported(lane) for lane in g)
     n_groups = len(groups)
     max_epochs = [int(g[0].p.max_epochs) for g in groups]
-    if pipeline is None:
-        pipeline = PIPELINE_DEFAULT
     if staged is None:
         pads = bucket_pads(groups)
         staged = [stage_group(g, k_epochs, max_rounds, pads=pads)
@@ -1746,11 +1589,9 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
             for s in staged)
         sh_g = _stack_trees([s.sh for s in staged])
         lc_g = _stack_trees([s.lc for s in staged])
-        if states is None:
-            states = [llc_mod.stack_states(dims.cfg, dims.n_lanes)
-                      for _ in groups]
-        carry = _stack_trees([_init_carry(g, st, dims.n_inputs)
-                              for g, st in zip(groups, states)])
+        carry = _stack_trees([
+            _init_carry(g, llc_mod.stack_states(dims.cfg, dims.n_lanes),
+                        dims.n_inputs) for g in groups])
     n_dev = devices if devices else len(jax.devices())
     n_shards = n_dev if (n_dev > 1 and n_groups % n_dev == 0) else 1
     if n_shards > 1:
@@ -1762,22 +1603,22 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
     # donation needs the one-device path: under shard_map the stacked
     # inputs are resharded on the way in, and donating a buffer that is
     # about to be resharded is not aliasing-safe on every backend
-    donate = pipeline and n_shards == 1
+    donate = n_shards == 1
     # speculative double-buffering needs constant stop epochs: an
     # online-LERN boundary requires a host refit (and table re-upload)
     # before the next super-step may start
-    speculate = pipeline and not any(
+    speculate = not any(
         lane._retrain_every is not None for g in groups for lane in g)
 
     # driver-local progress tracking, fed by the fetched StepOut — the
     # host Lane objects' scalars are stale until the final carry sync
     epochs = [[lane.epoch for lane in g] for g in groups]
     alive = [[lane.active for lane in g] for g in groups]
-    live = [True] * n_groups       # False once demoted to its own driver
+    live = [True] * n_groups       # False once demoted to the host
     # lanes that committed up to a retrain boundary whose refit hasn't
     # run yet (deferred while their group has an overflow to resolve —
     # the frozen lane must re-attempt its epoch under the OLD tables,
-    # exactly as drive_lanes_fused's rollback replays it)
+    # exactly as the sequential loop runs it)
     due = [set() for _ in range(n_groups)]
 
     def group_active(i: int) -> bool:
@@ -1822,7 +1663,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
         # before dispatch so it bites even on tiny workloads that finish
         # inside the first super-step.  Bitwise-safe by the same argument
         # as real overflow demotion — each group leaves from its
-        # committed carry and finishes under the per-group driver.
+        # committed carry and finishes on the host.
         if any(group_active(i) for i in range(n_groups)):
             from repro.exp import faults as _flt
             if _flt.fire("bucket_overflow", key=f"g{n_groups}") is not None:
@@ -1850,8 +1691,9 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
                 overflow_pending.clear()
                 continue
             # ... and past the cap, demote only the offending groups:
-            # sync their carry scalars and hand them to the per-group
-            # driver (host fallback and all) from their frozen state
+            # sync their carry scalars and finish each still-active lane
+            # on the host from its frozen LLC state (lanes share no LLC
+            # state, so lane by lane is the group's own result)
             host_c = _to_host(carry._replace(st=None), _CARRY_FLOATS)
             for i in sorted(overflow_pending):
                 if not live[i]:
@@ -1861,15 +1703,14 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
                                   jax.tree.map(lambda x: x[i], host_c))
                 # a deferred refit only touches the due lane's own
                 # tables (it holds at its boundary), so fire it before
-                # the replay picks the group up
+                # the host loop picks the lane up
                 for j in sorted(due[i]):
                     groups[i][j]._online_retrain()
                 due[i].clear()
-                with jax.enable_x64():     # f64 leaves: slice under x64
-                    st_i = jax.tree.map(lambda x: x[i], carry.st)
-                drive_lanes_fused(groups[i], states=st_i,
-                                  k_epochs=dims.k_epochs,
-                                  max_rounds=dims.max_rounds)
+                for j, lane in enumerate(groups[i]):
+                    if lane.active:
+                        drive_lane(lane, state=jax.tree.map(
+                            lambda x: x[i, j], carry.st))
             with jax.enable_x64():
                 dead = jnp.asarray(np.asarray([not a for a in live]))
                 carry = carry._replace(
@@ -1913,7 +1754,7 @@ def drive_lanes_bucketed(groups: List[List[Lane]], states=None,
                     lc_g, staged[i].lc)
     # one final scalar sync per lane — everything epoch-by-epoch already
     # landed via _write_back_steps, and demoted groups were synced at
-    # demotion (then driven to completion by the per-group driver)
+    # demotion (then driven to completion on the host)
     with _phase("writeback_s"):
         host_c = _to_host(carry._replace(st=None), _CARRY_FLOATS)
         for i in range(n_groups):

@@ -17,11 +17,11 @@ batches that cross-product at three levels:
   reference (tests/test_sweep.py).
 
 * **Across groups, on device** ``run_bucketed``/``simulate_bucket``
-  bucket whole groups by fused-engine static shape (``fused.bucket_key``)
-  and drive each bucket as ONE vmapped device program with a leading
-  group axis (``fused.drive_lanes_bucketed``) — thousands of sweep
-  points become a handful of dispatch chains.  Bitwise-equal to
-  per-group ``simulate_group`` (tests/test_bucketed.py).
+  bucket whole groups by device-engine static shape (``fused.bucket_key``)
+  and drive each bucket as ONE device program with a leading group axis
+  (``fused.drive_lanes_bucketed``) — thousands of sweep points become a
+  handful of dispatch chains.  Bitwise-equal to per-group
+  ``simulate_group`` (tests/test_bucketed.py).
 
 Online-LERN lanes (``*-ol`` policies) ride the same batching: their
 retrain hook lives inside ``Lane.finish_epoch`` (refit on the observed
@@ -134,14 +134,14 @@ def simulate_group(config: str, mix: str, pols: Sequence[Policy],
     each point alone through the sequential ``sim.drive_lane`` loop —
     this is the sweep-level oracle ``simulate_bucket`` is pinned against.
 
-    ``engine`` selects the epoch loop: ``"fused"`` forces the
-    device-resident super-step engine (core/fused.py), ``"host"`` the
-    per-epoch host loop, and ``"auto"`` (default) routes every eligible
-    geometry batch through the fused engine — integer LLC stats are
-    bitwise-identical either way (tests/test_fused.py), so this is purely
-    a performance switch.  ``REPRO_FUSED=0`` pins ``auto`` to the host
-    path globally.
+    ``engine`` selects the epoch loop: ``"host"`` the per-epoch host
+    loop, and ``"auto"`` (default) runs every device-eligible geometry
+    batch as a one-group bucket of ``fused.drive_lanes_bucketed`` —
+    results are bitwise-identical either way (tests/test_fused.py), so
+    this is purely a performance switch.
     """
+    if engine not in ("auto", "host"):
+        raise ValueError(f"unknown engine {engine!r}")
     p = params or sim.SimParams()
     if dram is None:
         dram = default_model()
@@ -155,30 +155,13 @@ def simulate_group(config: str, mix: str, pols: Sequence[Policy],
     for lane in lanes:
         batches.setdefault(llc.geometry_key(lane.llc_cfg), []).append(lane)
     for batch in batches.values():
-        if _use_fused(batch, engine):
-            from . import fused  # deferred: keep pool workers light
-            fused.drive_lanes_fused(batch)
-        else:
-            _drive_lanes(batch)
+        if engine == "auto":
+            from . import fused  # deferred: keep host pool workers light
+            if all(fused.lane_supported(lane) for lane in batch):
+                fused.drive_lanes_bucketed([batch])
+                continue
+        _drive_lanes(batch)
     return [lane.result() for lane in lanes]
-
-
-def _use_fused(batch: List[sim.Lane], engine: str) -> bool:
-    if engine == "host":
-        return False
-    if engine == "auto":
-        # opt-out before the fused import: REPRO_FUSED=0 pool workers
-        # stay light (core/fused.py pulls in the x64 jit machinery)
-        if os.environ.get("REPRO_FUSED", "1") == "0":
-            return False
-    elif engine != "fused":
-        raise ValueError(f"unknown engine {engine!r}")
-    from . import fused
-    eligible = all(fused.lane_supported(lane) for lane in batch)
-    if engine == "fused" and not eligible:
-        raise ValueError("engine='fused' requested for a lane batch "
-                         "the fused engine does not support")
-    return eligible
 
 
 def _drive_lanes(lanes: List[sim.Lane]) -> None:
@@ -295,38 +278,18 @@ def _make_task_lanes(task) -> List[sim.Lane]:
             for pol in pols]
 
 
-def _demote_batch(task, poss: List[int], devices: Optional[int] = None
-                  ) -> Tuple[List[sim.Lane], str]:
-    """Degradation ladder, rungs two and three: re-run the ``poss``
-    policy lanes of ``task`` on the per-group fused engine, and if that
-    also fails degradably, on the host loop.  Always starts from fresh
-    lanes — recomputation is deterministic, so the bitwise contract
-    holds no matter which rung finishes the group."""
-    flt = _faults()
-    from . import fused
-    config, mix, pols = task[0], task[1], task[2]
-
-    def fresh():
-        lanes = _make_task_lanes(task)
-        return [lanes[j] for j in poss]
-
-    try:
-        sel = fresh()
-        flt.fire("fused", key=f"{config}|{mix}")
-        fused.drive_lanes_fused(sel)
-        return sel, "fused"
-    except Exception as e:
-        if not flt.degradable(e):
-            raise
-        flt.log_event("degrade", ladder="fused->host",
-                      task=f"{config}|{mix}", error=str(e)[:200])
-        sel = fresh()
-        _drive_lanes(sel)
-        return sel, "host"
+def _demote_batch(task, poss: List[int]) -> List[sim.Lane]:
+    """Degradation ladder, second rung: re-run the ``poss`` policy lanes
+    of ``task`` on the host loop.  Starts from fresh lanes —
+    recomputation is deterministic, so the bitwise contract holds
+    whichever rung finishes the group."""
+    lanes = _make_task_lanes(task)
+    sel = [lanes[j] for j in poss]
+    _drive_lanes(sel)
+    return sel
 
 
 def simulate_bucket(tasks: Sequence[Tuple], devices: Optional[int] = None,
-                    pipeline: Optional[bool] = None,
                     task_keys: Optional[List[List[str]]] = None
                     ) -> List[List[sim.SimResult]]:
     """Simulate many ``(config, mix, pols, params, dram, paths)`` group
@@ -339,17 +302,15 @@ def simulate_bucket(tasks: Sequence[Tuple], devices: Optional[int] = None,
     pinned against (tests/test_bucketed.py).  Geometry batches the fused
     engine can't take fall back to the host loop, exactly like
     ``engine="auto"``; beyond that, a slab that fails **degradably**
-    (XLA compile error, ``RESOURCE_EXHAUSTED``, injected fault) walks
-    the ladder bucketed → per-group fused → host, recomputing the
-    affected groups from fresh lanes so results stay bitwise-identical
-    (docs/resilience.md).  Each finished point is dumped to its
-    ``paths`` entry (pass empty paths to skip the cache).  Staged device
-    constants ride the module staging LRU (``_staged_for``), so repeated
-    sweeps over the same points skip the upload.  ``pipeline`` forwards
-    to ``fused.drive_lanes_bucketed`` (None = ``REPRO_BUCKET_PIPELINE``).
-    ``task_keys`` (parallel to ``tasks``) reports each finished point to
-    the active run report.  Returns per-task result lists in task
-    order."""
+    (``RESOURCE_EXHAUSTED`` or an injected fault) walks the ladder
+    bucketed → host, recomputing the affected groups from fresh lanes so
+    results stay bitwise-identical (docs/resilience.md).  Each finished
+    point is dumped to its ``paths`` entry (pass empty paths to skip the
+    cache).  Staged device constants ride the module staging LRU
+    (``_staged_for``), so repeated sweeps over the same points skip the
+    upload.  ``task_keys`` (parallel to ``tasks``) reports each finished
+    point to the active run report.  Returns per-task result lists in
+    task order."""
     flt = _faults()
     from . import fused
     task_lanes: List[List[sim.Lane]] = []
@@ -382,19 +343,16 @@ def simulate_bucket(tasks: Sequence[Tuple], devices: Optional[int] = None,
             try:
                 flt.fire("bucket", key=f"{len(groups)}g")
                 fused.drive_lanes_bucketed(groups, devices=devices,
-                                           staged=_staged_for(groups),
-                                           pipeline=pipeline)
+                                           staged=_staged_for(groups))
             except Exception as e:
                 if not flt.degradable(e):
                     raise
-                flt.log_event("degrade", ladder="bucketed->fused",
+                flt.log_event("degrade", ladder="bucketed->host",
                               groups=len(groups), error=str(e)[:200])
                 for _batch, ti, poss in slab:
-                    sel, rung = _demote_batch(tasks[ti], poss,
-                                              devices=devices)
-                    for j, lane in zip(poss, sel):
+                    for j, lane in zip(poss, _demote_batch(tasks[ti], poss)):
                         task_lanes[ti][j] = lane
-                    task_engines[ti].add(rung)
+                    task_engines[ti].add("host")
     for batch in host_batches:
         _drive_lanes(batch)
     out: List[List[sim.SimResult]] = []
@@ -402,9 +360,7 @@ def simulate_bucket(tasks: Sequence[Tuple], devices: Optional[int] = None,
         results = [lane.result() for lane in lanes]
         for res, path in zip(results, task[5]):
             sim._atomic_dump(res, path)
-        engs = task_engines[ti]
-        eng = ("host" if "host" in engs else
-               "fused" if "fused" in engs else "bucketed")
+        eng = "host" if "host" in task_engines[ti] else "bucketed"
         if task_keys is not None:
             for key in task_keys[ti]:
                 flt.point_done(key, source="computed", engine=eng)
@@ -764,7 +720,7 @@ def map_points(points: Sequence[SweepPoint], jobs: int = 1,
     (config, mix, params, dram), chunked into <= ``max_lanes`` policy
     lanes, and executed — inline for ``jobs <= 1``, else on a spawn-based
     process pool of ``jobs`` workers.  ``engine`` is the per-group epoch
-    engine (``simulate_group``'s ``auto|host|fused``); ``fit_engine``
+    engine (``simulate_group``'s ``auto|host``); ``fit_engine``
     pins the LERN fit engine inside pool workers.  Every finished point
     is written to the sim disk cache, so concurrent sweeps (and later
     cached runs) are free.  Returns results in ``points`` order.
@@ -810,14 +766,12 @@ def map_points(points: Sequence[SweepPoint], jobs: int = 1,
 
 def run_bucketed(points: Sequence[SweepPoint], max_lanes: int = MAX_LANES,
                  devices: Optional[int] = None, cache: bool = True,
-                 pipeline: Optional[bool] = None,
                  report=None) -> List[sim.SimResult]:
     """Bucketed twin of ``map_points``: the same cache/dedup/grouping
     front half, but every uncached group executes together through
     ``simulate_bucket`` — whole-sweep-on-device instead of a process
-    farm.  ``pipeline`` forwards to the bucketed driver (None =
-    ``REPRO_BUCKET_PIPELINE``); degradable bucket failures walk the
-    bucketed → fused → host ladder inside ``simulate_bucket``.
+    farm.  Degradable bucket failures walk the bucketed → host ladder
+    inside ``simulate_bucket``.
     ``report`` receives per-point records + fault events.  Returns
     results in ``points`` order, bitwise-equal to ``map_points`` on the
     same points."""
@@ -833,8 +787,7 @@ def run_bucketed(points: Sequence[SweepPoint], max_lanes: int = MAX_LANES,
             # only reads the calibration cache
             for t in calib.values():
                 _calibrate_task(t)
-            bucket_rs = simulate_bucket(tasks, devices, pipeline,
-                                        task_keys=task_keys)
+            bucket_rs = simulate_bucket(tasks, devices, task_keys=task_keys)
             for idxs, rs in zip(task_idxs, bucket_rs):
                 for idx, res in zip(idxs, rs):
                     results[idx] = res

@@ -25,7 +25,6 @@ Sites instrumented today (see docs/resilience.md for the full model):
 ``cache_dump``      ``sim._atomic_dump`` — corrupt/truncate/torn writes
 ``stage_evict``     ``sweep._staged_for`` — drops the staging LRU
 ``bucket``          bucketed slab dispatch (simulated compile/OOM)
-``fused``           per-group fused replay (second ladder rung)
 ``bucket_overflow`` forces the bucketed driver's freeze/demote machinery
 ``refit``           ``HydraKVScheduler._online_refit``
 ``serve_step``      once per scheduler epoch in every serve engine
@@ -297,7 +296,7 @@ def fire(site: str, key: str = "") -> Optional[FaultSpec]:
 
 def degradable(exc: BaseException) -> bool:
     """Is this the class of failure the engine ladder may absorb by
-    demoting bucket→fused→host (device memory exhausted, or an injected
+    demoting bucket→host (device memory exhausted, or an injected
     fault), as opposed to a bug that must propagate?  A compile refusal
     or any other runtime error is a bug: demoting it would finish the
     run on the host and hide that the device path never ran."""

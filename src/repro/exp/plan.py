@@ -1,9 +1,9 @@
 """ExecPlan — the one object that says *how* a spec is executed.
 
-Historically execution knobs were scattered: ``REPRO_FUSED`` env var,
-``engine=`` strings on ``sweep.simulate_group``, ``jobs=``/``cache=``
-kwargs on ``exp.run``, ``REPRO_LERN_FIT`` for the k-means fit engine.
-``ExecPlan`` unifies them:
+Historically execution knobs were scattered: ``engine=`` strings on
+``sweep.simulate_group``, ``jobs=``/``cache=`` kwargs on ``exp.run``,
+``REPRO_LERN_FIT`` for the k-means fit engine.  ``ExecPlan`` unifies
+them:
 
     from repro import exp
     rs = exp.run(spec, plan=exp.ExecPlan(engine="bucketed", devices=4))
@@ -15,12 +15,12 @@ valid "just do the right thing" plan.
 Engine names:
 
 * ``"auto"``    — bucketed whole-sweep-on-device when ``jobs <= 1``,
-  else the process-pool host path with per-group fused scans.
+  else the process pool, each group a one-group bucket of the device
+  driver.
 * ``"host"``    — per-epoch host loop (the sequential oracle's engine).
-* ``"fused"``   — per-group fused super-step scan, groups sequential.
-* ``"bucketed"``— geometry-bucketed vmap of the fused engine: every
-  sweep group with the same (sets, ways, rounds-cap, lane-count)
-  geometry runs as one device program (``sweep.run_bucketed``).
+* ``"bucketed"``— the device epoch driver over the whole sweep: every
+  group with the same static shape (``fused.bucket_key``) runs as one
+  device program (``sweep.run_bucketed``).
 
 All engines are bitwise-equal on integer stats and f64 float histories
 (tests/test_sweep.py, tests/test_fused.py, tests/test_bucketed.py).
@@ -33,7 +33,7 @@ from typing import Optional, Union
 
 from .faults import FaultPlan
 
-_ENGINES = ("auto", "host", "fused", "bucketed")
+_ENGINES = ("auto", "host", "bucketed")
 _FIT_ENGINES = ("auto", "bucketed", "segmented")
 
 
@@ -55,9 +55,8 @@ def _check_pool_backend(jobs: int) -> None:
 class ExecPlan:
     """How to execute a spec.  ``None`` fields resolve to env defaults.
 
-    engine:     "auto" | "host" | "fused" | "bucketed"
-                (default: env ``REPRO_ENGINE``; legacy ``REPRO_FUSED=0``
-                means "host"; else "auto")
+    engine:     "auto" | "host" | "bucketed"
+                (default: env ``REPRO_ENGINE``, else "auto")
     jobs:       process-pool width for the host fallback (default 1;
                 ignored by the bucketed engine, which batches on device).
                 ``jobs > 1`` needs the CPU backend: every worker process
@@ -69,10 +68,6 @@ class ExecPlan:
     fit_engine: "auto" | "bucketed" | "segmented" k-means fit engine
                 (default: env ``REPRO_LERN_FIT``, else "auto")
     max_lanes:  lane cap per device batch (default ``sweep.MAX_LANES``)
-    pipeline:   bucketed engine only: donate the super-step carry and
-                double-buffer dispatch (default: env
-                ``REPRO_BUCKET_PIPELINE``, on; ``False`` is the
-                undonated one-dispatch-at-a-time reference path)
     faults:     deterministic fault-injection plan — a
                 :class:`repro.exp.faults.FaultPlan` or its JSON string
                 (default: env ``REPRO_FAULTS``; None = no injection).
@@ -84,7 +79,6 @@ class ExecPlan:
     cache: Optional[bool] = None
     fit_engine: Optional[str] = None
     max_lanes: Optional[int] = None
-    pipeline: Optional[bool] = None
     faults: Optional[Union[str, FaultPlan]] = None
 
     def __post_init__(self):
@@ -103,11 +97,7 @@ class ExecPlan:
         """Fill every ``None`` field from the environment defaults,
         returning a fully-concrete plan (``devices`` may stay ``None`` =
         all visible)."""
-        engine = self.engine or os.environ.get("REPRO_ENGINE")
-        if engine is None:
-            # legacy opt-out: REPRO_FUSED=0 forced the host epoch loop
-            engine = ("host" if os.environ.get("REPRO_FUSED", "1") == "0"
-                      else "auto")
+        engine = self.engine or os.environ.get("REPRO_ENGINE") or "auto"
         if engine not in _ENGINES:  # env var can carry junk
             raise ValueError(f"unknown engine {engine!r} from REPRO_ENGINE "
                              f"(expected one of {_ENGINES})")
@@ -119,10 +109,6 @@ class ExecPlan:
         if jobs > 1:
             _check_pool_backend(jobs)
         from repro.core import sweep  # deferred: exp layers above core
-        # mirrors fused.PIPELINE_DEFAULT without importing the (heavy)
-        # fused module here — plan resolution must stay light
-        pipeline = (os.environ.get("REPRO_BUCKET_PIPELINE", "1") != "0"
-                    if self.pipeline is None else bool(self.pipeline))
         return dataclasses.replace(
             self, engine=engine,
             jobs=jobs,
@@ -131,6 +117,5 @@ class ExecPlan:
             fit_engine=fit,
             max_lanes=(sweep.MAX_LANES if self.max_lanes is None
                        else int(self.max_lanes)),
-            pipeline=pipeline,
             faults=(self.faults if self.faults is not None
                     else os.environ.get("REPRO_FAULTS")))

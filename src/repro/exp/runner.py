@@ -4,7 +4,9 @@ entry point for evaluating anything.
 ``ExecPlan`` routes points to an engine: ``bucketed`` (and the ``auto``
 default when ``jobs <= 1``) batches whole sweeps on device through
 ``sweep.run_bucketed``; otherwise points go through ``sweep.map_points``
-(lane-batched ``simulate_group`` + process pool + disk-cache dedup).
+(``simulate_group`` per group — a one-group device bucket under
+``auto``, the host epoch loop under ``host`` — + process pool +
+disk-cache dedup).
 Every engine is bitwise-identical on integer stats and f64 histories —
 pinned by tests/test_exp.py and tests/test_bucketed.py.
 
@@ -86,7 +88,7 @@ def run_points(points: Sequence[Point], plan: Optional[ExecPlan] = None,
         if rp.engine == "bucketed" or (rp.engine == "auto" and rp.jobs <= 1):
             return sweep.run_bucketed(sps, max_lanes=rp.max_lanes,
                                       devices=rp.devices, cache=rp.cache,
-                                      pipeline=rp.pipeline, report=report)
+                                      report=report)
         if rp.cache:
             return sweep.map_points(sps, jobs=rp.jobs, max_lanes=rp.max_lanes,
                                     engine=rp.engine,

@@ -29,9 +29,8 @@ def run_reference(config: str, mix: str, policy: Policy,
 
 
 def assert_bitwise(got: sim.SimResult, want: sim.SimResult, who):
-    """Full bitwise equality: integer-derived counters exactly, float
-    timing exactly (the engine's guarantee is rtol=1e-6; on the pinned
-    CI stack the fences make it exact, so equality is what we assert)."""
+    """Full bitwise equality, integer-derived counters and float timing
+    alike: the contract every engine keeps with ``sim.drive_lane``."""
     assert got.summary() == want.summary(), who
     assert got.epochs == want.epochs, who
     assert got.completion_cycles == want.completion_cycles, who

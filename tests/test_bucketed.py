@@ -1,12 +1,14 @@
-"""Geometry-bucketed whole-sweep engine vs the per-group oracle.
+"""Geometry-bucketed whole-sweep engine vs the host oracle.
 
 Contract (core/fused.py::drive_lanes_bucketed and sweep.run_bucketed):
 per-group results are bitwise-identical — integer stats and f64 float
-histories — to ``sweep.simulate_group`` on each group alone.  Covers
-mixed-geometry bucketing, the single-group degenerate bucket, surgical
-overflow demotion of one group inside a bucket, `shard_map` over a
-multi-device group axis (subprocess with forced host devices), and the
-ExecPlan ``engine="bucketed"`` end-to-end route.
+histories — to the host epoch loop on each group alone
+(``sweep.simulate_group(engine="host")``, itself pinned to
+``sim.drive_lane``).  Covers mixed-geometry bucketing, the single-group
+degenerate bucket, surgical overflow demotion of one group inside a
+bucket to the host loop, `shard_map` over a multi-device group axis
+(subprocess with forced host devices), and the ExecPlan
+``engine="bucketed"`` end-to-end route.
 """
 import dataclasses
 import os
@@ -35,7 +37,7 @@ def _oracle(config, mix, pols, p, dram=sim.DDR3_1600):
     # dram pinned to match _mk_group's default — an env REPRO_DRAM override
     # must not split the oracle and the bucketed engine onto different models
     return sweep.simulate_group(config, mix, pols, p, dram,
-                                deadline_cycles=DEADLINE)
+                                deadline_cycles=DEADLINE, engine="host")
 
 
 # ---------------------------------------------------------------------------
@@ -88,8 +90,8 @@ def test_bucket_mixed_policy_rosters_share_bucket():
 
 
 def test_bucket_single_group_degenerate():
-    """A one-group bucket (the common tail case) is just the fused engine
-    with a unit group axis — still bitwise."""
+    """A one-group bucket (the common tail case, and every device group
+    of ``simulate_group``) has a unit group axis — still bitwise."""
     groups = [_mk_group("config1", "moti1", POLS, TINY)]
     fused.drive_lanes_bucketed(groups)
     for pol, lane, want in zip(POLS, groups[0],
@@ -153,20 +155,32 @@ def _synthetic_group(seed, n_lines, length=2000, dram=sim.DDR3_1600):
                           DEADLINE, art, True) for pol in POLS]
 
 
+def _spy_demotion(monkeypatch):
+    """Record every lane the bucketed driver demotes to the host loop."""
+    demoted = []
+    orig = fused.drive_lane
+
+    def spy(lane, state=None):
+        demoted.append(lane)
+        return orig(lane, state=state)
+
+    monkeypatch.setattr(fused, "drive_lane", spy)
+    return demoted
+
+
+def _only(demoted, group):
+    """Some lanes were demoted, and every one of them belongs to ``group``."""
+    return bool(demoted) and all(any(d is lane for lane in group)
+                                 for d in demoted)
+
+
 def test_bucket_overflow_demotes_offending_group_only(monkeypatch):
     """One group hammering 8 hot lines blows the round capacity; its
     bucket-mate with a spread-out trace must stay on the vmapped path.
-    The hot group is replayed through per-group ``drive_lanes_fused``
-    (whose own host fallback absorbs the depth) and both still match the
-    sequential oracle."""
-    demoted = []
-    orig = fused.drive_lanes_fused
-
-    def spy(lanes, *a, **kw):
-        demoted.append(tuple(lanes))
-        return orig(lanes, *a, **kw)
-
-    monkeypatch.setattr(fused, "drive_lanes_fused", spy)
+    The hot group's lanes finish on the host loop (``sim.drive_lane``,
+    which chunks the depth) from their frozen state, and both groups
+    still match the sequential oracle."""
+    demoted = _spy_demotion(monkeypatch)
     # measured: the tame trace fits in 64 rounds/set, the hot one needs
     # 128 — capping at 64 forces exactly one group over the edge
     monkeypatch.setattr(fused, "MAX_ROUNDS_CAP", 64)
@@ -174,7 +188,7 @@ def test_bucket_overflow_demotes_offending_group_only(monkeypatch):
     tame_art, tame = _synthetic_group(4, n_lines=6000)
     assert fused.bucket_key(hot) == fused.bucket_key(tame)
     fused.drive_lanes_bucketed([hot, tame], k_epochs=4, max_rounds=32)
-    assert demoted == [tuple(hot)], "exactly the hot group must demote"
+    assert _only(demoted, hot), "exactly the hot group must demote"
     for name, art, group in (("hot", hot_art, hot),
                              ("tame", tame_art, tame)):
         for pol, lane in zip(POLS, group):
@@ -190,21 +204,14 @@ def test_bucket_overflow_demotion_with_sched_bank_state(monkeypatch):
     the vmapped carry) must survive the replay hand-off — both groups
     still match the sequential host oracle bitwise."""
     from repro.core.dram import DDR4_2400_SQUASH
-    demoted = []
-    orig = fused.drive_lanes_fused
-
-    def spy(lanes, *a, **kw):
-        demoted.append(tuple(lanes))
-        return orig(lanes, *a, **kw)
-
-    monkeypatch.setattr(fused, "drive_lanes_fused", spy)
+    demoted = _spy_demotion(monkeypatch)
     monkeypatch.setattr(fused, "MAX_ROUNDS_CAP", 64)
     hot_art, hot = _synthetic_group(3, n_lines=8, dram=DDR4_2400_SQUASH)
     tame_art, tame = _synthetic_group(4, n_lines=6000,
                                       dram=DDR4_2400_SQUASH)
     assert fused.bucket_key(hot) == fused.bucket_key(tame)
     fused.drive_lanes_bucketed([hot, tame], k_epochs=4, max_rounds=32)
-    assert demoted == [tuple(hot)], "exactly the hot group must demote"
+    assert _only(demoted, hot), "exactly the hot group must demote"
     for name, art, group in (("hot", hot_art, hot),
                              ("tame", tame_art, tame)):
         for pol, lane in zip(POLS, group):
@@ -215,13 +222,12 @@ def test_bucket_overflow_demotion_with_sched_bank_state(monkeypatch):
 
 
 def test_bucket_pipeline_donated_parity(monkeypatch):
-    """The donated, double-buffered dispatch (``pipeline=True``) against
-    the undonated one-dispatch-at-a-time reference (``pipeline=False``)
-    over >= 3 super-steps with an overflow-demotion in the middle: the
-    hot group blows the capped round capacity and demotes while its
-    tame bucket-mate keeps running donated super-steps — results must
-    stay bitwise equal, and the donated executable must actually have
-    carried the pipelined leg."""
+    """The donated, double-buffered dispatch against the sequential
+    oracle over >= 3 super-steps with an overflow-demotion in the
+    middle: the hot group blows the capped round capacity and demotes
+    while its tame bucket-mate keeps running donated super-steps —
+    results must stay bitwise equal to ``sim.drive_lane``, and the
+    donated executable must actually have carried the run."""
     monkeypatch.setattr(fused, "MAX_ROUNDS_CAP", 64)
     donated_calls = [0]
     orig_donated = fused._superstep_bucket_donated
@@ -231,34 +237,24 @@ def test_bucket_pipeline_donated_parity(monkeypatch):
         return orig_donated(*a, **kw)
 
     monkeypatch.setattr(fused, "_superstep_bucket_donated", donated_spy)
-    demoted = {}
-    runs = {}
-    for pipeline in (False, True):
-        before = donated_calls[0]
-        demo = []
-        orig_fused = fused.drive_lanes_fused
-        monkeypatch.setattr(
-            fused, "drive_lanes_fused",
-            lambda lanes, *a, **kw: (demo.append(tuple(lanes)),
-                                     orig_fused(lanes, *a, **kw))[1])
-        _, hot = _synthetic_group(3, n_lines=8)
-        _, tame = _synthetic_group(4, n_lines=6000)
-        # max_epochs=12 at k_epochs=4 -> 3 super-steps for the survivor;
-        # devices=1 pins the single-shard path — donation is disabled
-        # under shard_map by design, and this test is about donation
-        fused.drive_lanes_bucketed([hot, tame], k_epochs=4, max_rounds=32,
-                                   devices=1, pipeline=pipeline)
-        monkeypatch.setattr(fused, "drive_lanes_fused", orig_fused)
-        runs[pipeline] = (hot, tame)
-        demoted[pipeline] = demo
-        used = donated_calls[0] - before
-        assert used >= 3 if pipeline else used == 0, (pipeline, used)
-    # the demotion fired mid-run on the same (hot) group in both legs
-    assert [len(d) for d in demoted.values()] == [1, 1]
-    for (ref_g, got_g), name in zip(zip(runs[False], runs[True]),
-                                    ("hot", "tame")):
-        for pol, ref, got in zip(POLS, ref_g, got_g):
-            assert_bitwise(got.result(), ref.result(), (name, pol.name))
+    demoted = _spy_demotion(monkeypatch)
+    hot_art, hot = _synthetic_group(3, n_lines=8)
+    tame_art, tame = _synthetic_group(4, n_lines=6000)
+    # max_epochs=12 at k_epochs=4 -> 3 super-steps for the survivor;
+    # devices=1 pins the single-shard path — donation is disabled
+    # under shard_map by design, and this test is about donation
+    fused.drive_lanes_bucketed([hot, tame], k_epochs=4, max_rounds=32,
+                               devices=1)
+    assert donated_calls[0] >= 3, donated_calls
+    # the demotion fired mid-run on the hot group alone
+    assert _only(demoted, hot)
+    for name, art, group in (("hot", hot_art, hot),
+                             ("tame", tame_art, tame)):
+        for pol, lane in zip(POLS, group):
+            want = sim.drive_lane(
+                sim.Lane("synthetic", "moti2", pol, HP, sim.DDR3_1600,
+                         DEADLINE, art, True))
+            assert_bitwise(lane.result(), want, (name, pol.name))
 
 
 # ---------------------------------------------------------------------------
@@ -318,12 +314,11 @@ def mk(seed):
 groups = [mk(11), mk(12)]
 oracle = [mk(11), mk(12)]
 fused.drive_lanes_bucketed(groups, devices=2)
-for g in oracle:
-    fused.drive_lanes_fused(g)
 for got_g, want_g in zip(groups, oracle):
     for got, want in zip(got_g, want_g):
-        assert got.result().summary() == want.result().summary()
-        assert got.result().history == want.result().history
+        want = sim.drive_lane(want)
+        assert got.result().summary() == want.summary()
+        assert got.result().history == want.history
 print("SHARDED-OK")
 """
 
@@ -353,7 +348,7 @@ def test_execplan_bucketed_end_to_end(tmp_path, monkeypatch):
         policy=["fifo-nb", "arp-cs-as"], params=TINY)
     bucketed = exp.run(spec, plan=exp.ExecPlan(engine="bucketed",
                                                cache=False))
-    oracle = exp.run(spec, plan=exp.ExecPlan(engine="fused", cache=False))
+    oracle = exp.run(spec, plan=exp.ExecPlan(engine="host", cache=False))
     assert len(bucketed) == len(oracle) == 4
     for got, want in zip(bucketed, oracle):
         assert (got["mix"], got["policy"]) == (want["mix"], want["policy"])

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from _reference import assert_bitwise, run_reference
+from test_fused import on_device  # noqa: F401 (fixture)
 from repro.core import dramsched, policies, sim, sweep
 from repro.core.dram import (DDR3_1600_SQUASH, DDR4_2400_FRFCFS,
                              DDR4_2400_SQUASH)
@@ -210,26 +211,28 @@ def test_sample_window_strided_gather():
 
 
 # ---------------------------------------------------------------------------
-# host-vs-fused, end to end
+# host vs the device engine, end to end
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("pol_name", ["fifo-nb", "arp-cs-as", "hydra",
                                       "hydra-v1"])
-def test_host_vs_fused_bitwise_squash(pol_name):
+def test_host_vs_fused_bitwise_squash(pol_name, on_device):
     pol = policies.get(pol_name)
     want = run_reference("config1", "moti1", pol, TINY, DDR4_2400_SQUASH,
                          deadline_cycles=DEADLINE)
     got = sweep.simulate_group("config1", "moti1", [pol], TINY,
                                DDR4_2400_SQUASH, deadline_cycles=DEADLINE,
-                               engine="fused")[0]
+                               engine="auto")[0]
     assert_bitwise(got, want, pol_name)
+    assert on_device
 
 
 @pytest.mark.parametrize("pol_name", ["fifo-nb", "hydra"])
-def test_host_vs_fused_bitwise_frfcfs(pol_name):
+def test_host_vs_fused_bitwise_frfcfs(pol_name, on_device):
     pol = policies.get(pol_name)
     want = run_reference("config1", "moti1", pol, TINY, DDR4_2400_FRFCFS,
                          deadline_cycles=DEADLINE)
     got = sweep.simulate_group("config1", "moti1", [pol], TINY,
                                DDR4_2400_FRFCFS, deadline_cycles=DEADLINE,
-                               engine="fused")[0]
+                               engine="auto")[0]
     assert_bitwise(got, want, pol_name)
+    assert on_device

@@ -202,7 +202,6 @@ def test_exp_run_uncached_matches_cached(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 def test_execplan_env_defaults_and_validation(monkeypatch):
     monkeypatch.delenv("REPRO_ENGINE", raising=False)
-    monkeypatch.delenv("REPRO_FUSED", raising=False)
     monkeypatch.delenv("REPRO_LERN_FIT", raising=False)
     rp = exp.ExecPlan().resolve()
     assert (rp.engine, rp.jobs, rp.cache, rp.fit_engine) == \
@@ -210,19 +209,22 @@ def test_execplan_env_defaults_and_validation(monkeypatch):
     from repro.core import sweep
     assert rp.max_lanes == sweep.MAX_LANES
     # env vars are the defaults...
-    monkeypatch.setenv("REPRO_FUSED", "0")
+    monkeypatch.setenv("REPRO_ENGINE", "host")
     assert exp.ExecPlan().resolve().engine == "host"
     monkeypatch.setenv("REPRO_ENGINE", "bucketed")
     assert exp.ExecPlan().resolve().engine == "bucketed"
     monkeypatch.setenv("REPRO_LERN_FIT", "bucketed")
     assert exp.ExecPlan().resolve().fit_engine == "bucketed"
     # ...and explicit fields beat them
-    assert exp.ExecPlan(engine="fused").resolve().engine == "fused"
+    assert exp.ExecPlan(engine="host").resolve().engine == "host"
     assert exp.ExecPlan(fit_engine="segmented").resolve().fit_engine == \
         "segmented"
-    # junk rejected, eagerly and from the env
-    with pytest.raises(ValueError):
-        exp.ExecPlan(engine="warp")
+    # junk rejected, eagerly and from the env; the per-group "fused"
+    # engine is gone, and the plan has no pipeline switch
+    for junk in ("fused", "warp"):
+        with pytest.raises(ValueError):
+            exp.ExecPlan(engine=junk)
+    assert "pipeline" not in {f.name for f in dataclasses.fields(exp.ExecPlan)}
     with pytest.raises(ValueError):
         exp.ExecPlan(fit_engine="warp")
     monkeypatch.setenv("REPRO_ENGINE", "warp")

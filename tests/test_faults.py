@@ -3,7 +3,7 @@ resilient sweep path.
 
 Every recovery the execution layer takes — quarantining a corrupt cache
 entry, respawning a crashed pool worker, watchdog-killing a hung task,
-demoting a failing bucket down the bucketed→fused→host ladder — must be
+demoting a failing bucket down the bucketed→host ladder — must be
 bitwise-transparent: the results of a faulted run equal a clean run
 exactly.  A hypothesis property randomizes whole fault plans over a
 small sweep to hold that line beyond the hand-picked cases."""
@@ -247,27 +247,13 @@ def test_inline_retry_with_backoff(tmp_path, monkeypatch, clean_baseline):
 
 
 # ---------------------------------------------------------------------------
-# degradation ladder: bucketed -> fused -> host (tentpole)
+# degradation ladder: bucketed -> host (tentpole)
 # ---------------------------------------------------------------------------
-def test_bucket_demotes_to_fused_bitwise(tmp_path, monkeypatch,
-                                         clean_baseline):
+@pytest.mark.parametrize("kind", ["resource", "raise"])
+def test_bucket_demotes_to_host_bitwise(tmp_path, monkeypatch,
+                                        clean_baseline, kind):
     monkeypatch.setattr(sim, "CACHE_DIR", str(tmp_path))
-    plan = _plan({"site": "bucket", "kind": "resource"})
-    report = faults.RunReport()
-    with faults.activate(plan):
-        rs = sweep.run_bucketed(_points(), report=report)
-    for got, want in zip(rs, clean_baseline):
-        assert_bitwise(got, want, got.policy)
-    degr = [e for e in report.events if e["kind"] == "degrade"]
-    assert any(e["ladder"] == "bucketed->fused" for e in degr)
-    assert any(r.get("engine") == "fused" for r in report.points.values())
-
-
-def test_bucket_demotes_all_the_way_to_host(tmp_path, monkeypatch,
-                                            clean_baseline):
-    monkeypatch.setattr(sim, "CACHE_DIR", str(tmp_path))
-    plan = _plan({"site": "bucket", "kind": "resource"},
-                 {"site": "fused", "kind": "resource", "max_fires": 8})
+    plan = _plan({"site": "bucket", "kind": kind})
     report = faults.RunReport()
     with faults.activate(plan):
         rs = sweep.run_bucketed(_points(), report=report)
@@ -275,7 +261,7 @@ def test_bucket_demotes_all_the_way_to_host(tmp_path, monkeypatch,
         assert_bitwise(got, want, got.policy)
     ladders = {e["ladder"] for e in report.events
                if e["kind"] == "degrade"}
-    assert {"bucketed->fused", "fused->host"} <= ladders
+    assert ladders == {"bucketed->host"}
     assert any(r.get("engine") == "host" for r in report.points.values())
 
 
@@ -337,21 +323,21 @@ def test_forced_bucket_overflow_demotion_bitwise(tmp_path, monkeypatch,
     monkeypatch.setattr(sim, "CACHE_DIR", str(tmp_path))
     monkeypatch.setattr(fused, "MAX_ROUNDS_CAP", 64)
     calls = []
-    orig = fused.drive_lanes_fused
+    orig = fused.drive_lane
 
-    def spy(lanes, *a, **kw):
-        calls.append(len(lanes))
-        return orig(lanes, *a, **kw)
+    def spy(lane, state=None):
+        calls.append(lane.policy.name)
+        return orig(lane, state=state)
 
-    monkeypatch.setattr(fused, "drive_lanes_fused", spy)
+    monkeypatch.setattr(fused, "drive_lane", spy)
     plan = _plan({"site": "bucket_overflow", "kind": "demote"})
     report = faults.RunReport()
     with faults.activate(plan):
         rs = sweep.run_bucketed(_points(), report=report)
     for got, want in zip(rs, clean_baseline):
         assert_bitwise(got, want, got.policy)
-    assert calls, "forced overflow must route groups through the " \
-                  "per-group fused driver"
+    assert calls, "forced overflow must finish the groups' lanes on " \
+                  "the host loop"
     assert any(e["kind"] == "fault" and e["site"] == "bucket_overflow"
                for e in report.events)
 
@@ -415,7 +401,7 @@ def test_exec_plan_faults_field(tmp_path, monkeypatch):
     plan_json = _plan({"site": "task", "kind": "raise"}).to_json()
     spec = exp.ExperimentSpec.grid(config="config1", mix="moti1",
                                    policy=list(POLS), params=TINY)
-    rs = exp.run(spec, plan=exp.ExecPlan(engine="fused", faults=plan_json))
+    rs = exp.run(spec, plan=exp.ExecPlan(engine="host", faults=plan_json))
     kinds = [e["kind"] for e in rs.run_report.events]
     assert "fault" in kinds and "task_retry" in kinds
     assert rs.run_report.summary()["points"] == 2
@@ -511,7 +497,7 @@ _FAULT_CHOICES = [
     ("cache_read", "truncate"), ("cache_dump", "corrupt"),
     ("cache_dump", "truncate"), ("stage_evict", "evict"),
     ("bucket", "resource"), ("bucket", "raise"),
-    ("fused", "resource"), ("bucket_overflow", "demote"),
+    ("bucket_overflow", "demote"),
 ]
 
 

@@ -1,13 +1,14 @@
-"""Fused device-resident epoch engine vs the sequential oracle.
+"""Device epoch engine (``fused.drive_lanes_bucketed``) vs the
+sequential oracle.
 
-The contract (core/fused.py): integer LLC stat counters bitwise-equal to
-``sim.drive_lane`` across every policy family, float timing metrics
-within rtol=1e-6 — and in practice bitwise, which is what these tests
-pin (the engine replicates the host's float64 op order exactly; see the
-_div/_mulb fences).  Covers way partitioning, SHIP bypass, DPCP
-prefetch, the deadline switch, HyDRA/APM modulation, online-LERN
-retrain boundaries, the round-capacity overflow fallback, and a
-hypothesis property over random short traces.
+The contract (core/fused.py): every lane's result is bitwise-equal to
+``sim.drive_lane`` — integer LLC stat counters and float64 timing
+histories alike, checked through ``_reference.assert_bitwise`` (the
+timing update runs on the host's own float64, in the host's operation
+order).  Covers way partitioning, SHIP bypass, DPCP prefetch, the
+deadline switch, HyDRA/APM modulation, online-LERN retrain boundaries,
+the round-capacity overflow demotion to the host loop, and a hypothesis
+property over random short traces.
 """
 import dataclasses
 import math
@@ -25,6 +26,22 @@ TINY = dataclasses.replace(sim.SimParams(), n_inputs=1, max_epochs=40,
 DEADLINE = 2.0e6  # explicit: skips the calibration run, keeps tests fast
 
 
+@pytest.fixture
+def on_device(monkeypatch):
+    """Lane groups ``simulate_group(engine="auto")`` hands the device
+    driver: a group it found ineligible would run the host loop and make
+    a parity check against the host oracle vacuous."""
+    groups = []
+    orig = fused.drive_lanes_bucketed
+
+    def spy(gs, *a, **kw):
+        groups.extend(gs)
+        return orig(gs, *a, **kw)
+
+    monkeypatch.setattr(fused, "drive_lanes_bucketed", spy)
+    return groups
+
+
 # ---------------------------------------------------------------------------
 # policy-family parity vs the sequential oracle
 # ---------------------------------------------------------------------------
@@ -39,119 +56,120 @@ POLS = [
 
 
 @pytest.mark.parametrize("mix", ["moti1", "moti2"])
-def test_fused_matches_oracle_across_policies(mix):
+def test_fused_matches_oracle_across_policies(mix, on_device):
     grp = sweep.simulate_group("config1", mix, POLS, TINY,
-                               deadline_cycles=DEADLINE, engine="fused")
+                               deadline_cycles=DEADLINE, engine="auto")
+    assert sum(map(len, on_device)) == len(POLS)
     for pol, got in zip(POLS, grp):
         want = run_reference("config1", mix, pol, TINY,
                              deadline_cycles=DEADLINE)
         assert_bitwise(got, want, (mix, pol.name))
 
 
-def test_fused_multi_input_cycling():
+def test_fused_multi_input_cycling(on_device):
     """Input completions, the inter-input wait, and the periodic arrival
     schedule all live in the scan carry — run several inputs through."""
     p = dataclasses.replace(TINY, n_inputs=3, max_epochs=120)
     pol = policies.get("arp-cas")
     got = sweep.simulate_group("config1", "moti2", [pol], p,
-                               deadline_cycles=DEADLINE, engine="fused")[0]
+                               deadline_cycles=DEADLINE, engine="auto")[0]
     want = run_reference("config1", "moti2", pol, p,
                          deadline_cycles=DEADLINE)
-    assert len(got.completion_cycles) == 3
+    assert len(got.completion_cycles) == 3 and on_device
     assert_bitwise(got, want, "multi-input")
 
 
-def test_fused_online_lern_retrain_boundary():
+def test_fused_online_lern_retrain_boundary(on_device):
     """Finite retrain periods cut super-steps at the refit boundary; the
     host hook runs and the re-uploaded tables must keep the fused lane
     bitwise with the sequential oracle."""
     p = dataclasses.replace(TINY, max_epochs=30)
     pol = dataclasses.replace(policies.get("arp-al-ol"), retrain_period=5)
     got = sweep.simulate_group("config1", "moti1", [pol], p,
-                               deadline_cycles=DEADLINE, engine="fused")[0]
+                               deadline_cycles=DEADLINE, engine="auto")[0]
     want = run_reference("config1", "moti1", pol, p,
                          deadline_cycles=DEADLINE)
     assert_bitwise(got, want, "online-lern")
+    assert on_device
 
 
-def test_fused_online_lern_infinite_period_degenerates():
+def test_fused_online_lern_infinite_period_degenerates(on_device):
     ol_inf = dataclasses.replace(policies.get("arp-al-ol"),
                                  retrain_period=math.inf)
     grp = sweep.simulate_group("config1", "moti1",
                                [policies.get("arp-al"), ol_inf], TINY,
-                               deadline_cycles=DEADLINE, engine="fused")
+                               deadline_cycles=DEADLINE, engine="auto")
     assert_bitwise(grp[1], grp[0], "ol-inf")
+    assert sum(map(len, on_device)) == 2
 
 
 # ---------------------------------------------------------------------------
 # overflow fallback + engine selection
 # ---------------------------------------------------------------------------
 def test_fused_overflow_falls_back_to_host(monkeypatch):
-    """A round-capacity overflow must roll the super-step back and
-    replay that stretch on the host path — exercised deliberately by
-    pinning the capacity below the trace's hot-set depth."""
-    calls = {"n": 0}
-    orig = fused._host_stretch
+    """A round-capacity overflow past the cap must finish the lane on the
+    host loop from its frozen state — exercised deliberately by pinning
+    the capacity below the trace's hot-set depth."""
+    calls = []
+    orig = fused.drive_lane
 
-    def spy(lanes, states, n_epochs):
-        calls["n"] += 1
-        return orig(lanes, states, n_epochs)
+    def spy(lane, state=None):
+        calls.append(lane.epoch)
+        return orig(lane, state=state)
 
-    monkeypatch.setattr(fused, "_host_stretch", spy)
+    monkeypatch.setattr(fused, "drive_lane", spy)
     monkeypatch.setattr(fused, "MAX_ROUNDS_CAP", 16)
     pol = policies.get("arp-cs")
     art = sim.load_artifacts("config1", "moti1", TINY, True)
     lane = sim.Lane("config1", "moti1", pol, TINY, sim.DDR3_1600,
                     DEADLINE, art, True)
-    fused.drive_lanes_fused([lane], k_epochs=4, max_rounds=8)
+    fused.drive_lanes_bucketed([[lane]], k_epochs=4, max_rounds=8)
     got = lane.result()
-    assert calls["n"] > 0, "overflow fallback never fired"
+    assert len(calls) == 1, "overflow demotion never fired"
     want = run_reference("config1", "moti1", pol, TINY, sim.DDR3_1600,
                          deadline_cycles=DEADLINE)
     assert_bitwise(got, want, "overflow-fallback")
 
 
-def test_fused_sparse_and_dense_rounds_agree(monkeypatch):
+def test_fused_sparse_and_dense_rounds_agree(monkeypatch, on_device):
     """The hybrid dense/sparse round branch is internal: forcing every
     round dense must not change anything."""
     pol = policies.get("arp-cs-as")
     got = sweep.simulate_group("config1", "moti1", [pol], TINY,
-                               deadline_cycles=DEADLINE, engine="fused")[0]
+                               deadline_cycles=DEADLINE, engine="auto")[0]
     monkeypatch.setattr(fused, "SPARSE_CAP", 0)
     dense = sweep.simulate_group("config1", "moti1", [pol], TINY,
-                                 deadline_cycles=DEADLINE, engine="fused")[0]
+                                 deadline_cycles=DEADLINE, engine="auto")[0]
     assert_bitwise(dense, got, "sparse-vs-dense")
+    assert len(on_device) == 2
 
 
-def test_fused_occupancy_recording():
+def test_fused_occupancy_recording(on_device):
     """record_occupancy lanes are fused-eligible: the per-epoch [2]
     core/accel occupancy counters ride the scan outputs and must match
     the host loop's llc.occupancy reads exactly."""
     p = dataclasses.replace(TINY, record_occupancy=True, max_epochs=20)
     pol = policies.get("arp-cs-as")
     got = sweep.simulate_group("config1", "moti1", [pol], p,
-                               deadline_cycles=DEADLINE, engine="fused")[0]
+                               deadline_cycles=DEADLINE, engine="auto")[0]
     want = run_reference("config1", "moti1", pol, p,
                          deadline_cycles=DEADLINE)
     assert_bitwise(got, want, "occupancy")
     assert got.occupancy and got.occupancy == want.occupancy
+    assert on_device
 
 
-def test_engine_selection_and_gate(monkeypatch):
-    # REPRO_FUSED=0 pins auto to the host loop
-    monkeypatch.setenv("REPRO_FUSED", "0")
-    called = {"n": 0}
-
-    def boom(*a, **kw):
-        called["n"] += 1
-
-    monkeypatch.setattr(fused, "drive_lanes_fused", boom)
+def test_engine_selection_and_gate(on_device):
+    # engine="host" never enters the device driver
     sweep.simulate_group("config1", "moti1", [policies.get("fifo-nb")],
-                         TINY, deadline_cycles=DEADLINE, engine="auto")
-    assert called["n"] == 0
-    with pytest.raises(ValueError):
-        sweep.simulate_group("config1", "moti1", [policies.get("fifo-nb")],
-                             TINY, deadline_cycles=DEADLINE, engine="nope")
+                         TINY, deadline_cycles=DEADLINE, engine="host")
+    assert on_device == []
+    # the per-group "fused" engine is gone: like any unknown name it raises
+    for engine in ("fused", "nope"):
+        with pytest.raises(ValueError):
+            sweep.simulate_group("config1", "moti1",
+                                 [policies.get("fifo-nb")], TINY,
+                                 deadline_cycles=DEADLINE, engine=engine)
 
 
 def test_occupancy_single_fetch():
@@ -231,7 +249,7 @@ def test_fused_property_random_traces(seed, n_lines, length, pol_idx):
 
     want = sim.drive_lane(mk())
     lane = mk()
-    fused.drive_lanes_fused([lane])
+    fused.drive_lanes_bucketed([[lane]])
     assert_bitwise(lane.result(), want, (seed, n_lines, length, pol.name))
 
 
